@@ -7,7 +7,10 @@ trace via the DAG model, and report the error.
         --json BENCH_calibration.json --assert-error-ceiling 200
 
 Per architecture (two by default), the measurement subprocess
-(:mod:`repro.measure.run`, forced host devices) produces:
+(:func:`repro.measure.run.measure_in_subprocess`: a CPU-platform child
+on forced host devices, so it never contends for a chip this process
+may hold; on a chip host, ``python -m repro.measure`` measures the
+chips in process instead) produces:
 
 * measured seconds/iteration for ``at_end`` / ``wfbp`` / ``bucketed``;
 * a per-layer trace (scan-segmented fwd/bwd, measured collectives);

@@ -64,13 +64,14 @@ import time
 
 import numpy as np
 
-from benchmarks.common import enable_jax_compilation_cache, row
+from benchmarks.common import row
 from repro.core.batched import grid_evaluator
 from repro.core.batched_jax import jax_grid_evaluator
 from repro.core.hardware import COLLECTIVE_ALGORITHMS
 from repro.core.scenarios import (ScenarioGrid, default_grid, frontier_grid,
                                   mixed_grid)
 from repro.core.sweep import sweep
+from repro.launch.compile_cache import enable_compilation_cache
 
 
 def bucketed_priority_grid(smoke: bool = False) -> ScenarioGrid:
@@ -196,7 +197,7 @@ def _time_kernels(grid, repeats: int) -> dict:
 
 
 def run(smoke: bool = False, json_path: str = "BENCH_sweep.json") -> dict:
-    enable_jax_compilation_cache()
+    enable_compilation_cache()
     repeats = 1 if smoke else 5
     grids = {"default_grid": default_grid(), "mixed_grid": mixed_grid(),
              "frontier_grid": frontier_grid(),

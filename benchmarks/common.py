@@ -1,40 +1,10 @@
 """Shared benchmark plumbing: ``name,us_per_call,derived`` CSV rows and
-the persistent jax compilation cache every jax-touching benchmark
-enables (jit compile time would otherwise dwarf the kernels being
-measured on every fresh process — CI pays it once per cache key
-instead)."""
+a median wall-time helper.  (The persistent jax compilation cache is
+:func:`repro.launch.compile_cache.enable_compilation_cache`.)"""
 from __future__ import annotations
 
-import os
 import time
 from typing import Callable
-
-
-def enable_jax_compilation_cache(cache_dir: str | None = None) -> str | None:
-    """Point jax at a persistent on-disk compilation cache (created if
-    missing) so repeated benchmark / CI processes reuse compiled
-    kernels instead of re-tracing them.  Resolution order: explicit
-    argument, ``JAX_COMPILATION_CACHE_DIR`` (the env var CI sets, which
-    jax also reads natively), ``~/.cache/repro-jax``.  Returns the
-    cache directory, or ``None`` when jax is unavailable — callers
-    treat the cache as best-effort."""
-    try:
-        import jax
-    except Exception:                                 # pragma: no cover
-        return None
-    cache_dir = (cache_dir
-                 or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-                 or os.path.join(os.path.expanduser("~"), ".cache",
-                                 "repro-jax"))
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # benchmark kernels compile fast; cache them anyway
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:                                 # pragma: no cover
-        return None
-    return cache_dir
 
 
 def row(name: str, us_per_call: float, derived: str = "") -> str:

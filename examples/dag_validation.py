@@ -4,14 +4,14 @@ measured layer costs via the DAG model, then compare with the measured
 wall-clock — the exact Fig. 4 methodology (paper reports 4.6-9.4%
 error on Caffe-MPI; we run the same loop on forced host devices).
 
-Spawns itself with XLA_FLAGS=--xla_force_host_platform_device_count=8
-so plain `python examples/dag_validation.py` works from a normal
-single-device environment.
+Forces 8 host CPU devices (and the CPU platform) before jax
+initializes, so plain `python examples/dag_validation.py` works from a
+normal single-device environment and never takes a host's chip.
 """
 import json
 import os
-import subprocess
 import sys
+from pathlib import Path
 
 N_DEV = 8
 
@@ -97,14 +97,12 @@ def child():
     # comm cost per layer: measure one psum of that many bytes
     from jax.sharding import PartitionSpec as P
 
-    from repro.comm.ddp import shard_map_compat
-
     def time_psum(nbytes):
         n = max(int(nbytes) // 4, 1)
         arr = jnp.ones((N_DEV, n), jnp.float32)
-        f = jax.jit(shard_map_compat(lambda x: jax.lax.pmean(x, "data"),
-                                     mesh, in_specs=P("data"),
-                                     out_specs=P("data")))
+        f = jax.jit(jax.shard_map(lambda x: jax.lax.pmean(x, "data"),
+                                  mesh=mesh, in_specs=P("data"),
+                                  out_specs=P("data"), check_vma=False))
         jax.block_until_ready(f(arr))
         t0 = time.perf_counter()
         for _ in range(5):
@@ -163,15 +161,12 @@ def child():
 
 
 def main():
-    if os.environ.get("_DAG_VALIDATION_CHILD") == "1":
-        child()
-        return
-    env = dict(os.environ,
-               XLA_FLAGS=f"--xla_force_host_platform_device_count={N_DEV}",
-               _DAG_VALIDATION_CHILD="1")
-    env.setdefault("PYTHONPATH", "src")
-    r = subprocess.run([sys.executable, __file__], env=env)
-    sys.exit(r.returncode)
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
+    from repro.launch.hostdev import force_host_device_count
+
+    force_host_device_count(N_DEV)
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    child()
 
 
 if __name__ == "__main__":

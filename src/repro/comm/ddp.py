@@ -26,17 +26,6 @@ from repro.models.common import ModelConfig
 from repro.optim.sgd import Optimizer, global_norm
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions (top-level API + check_vma on
-    newer jax; jax.experimental.shard_map + check_rep on 0.4.x)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def make_ddp_train_step(cfg: ModelConfig, optimizer: Optimizer, mesh: Mesh,
                         sync_policy: str = "wfbp", dp_axis: str = "data",
                         bucket_bytes: float = S.DEFAULT_BUCKET_BYTES,
@@ -59,18 +48,21 @@ def make_ddp_train_step(cfg: ModelConfig, optimizer: Optimizer, mesh: Mesh,
 
         (total, metrics), grads = jax.value_and_grad(loss, has_aux=True)(params)
         grads = S.sync_gradients(grads, sync_policy, dp_axes, bucket_bytes)
-        # the loss itself is also averaged for reporting
-        total = jax.lax.pmean(total, dp_axes)
+        # the losses are also averaged for reporting, as ONE f32[2]
+        # collective: two scalar pmeans would be CSE'd into one whenever
+        # the aux loss is a constant zero (dense models), so the step's
+        # collective bytes would depend on the architecture
+        total, loss = jax.lax.pmean(jnp.stack([total, metrics["loss"]]),
+                                    dp_axes)
         new_params, new_opt = optimizer.update(grads, opt_state, params)
-        out_metrics = {"loss": jax.lax.pmean(metrics["loss"], dp_axes),
-                       "total_loss": total,
+        out_metrics = {"loss": loss, "total_loss": total,
                        "grad_norm": global_norm(grads)}
         return new_params, new_opt, out_metrics
 
     batch_specs = {"tokens": P(dp_axis), "labels": P(dp_axis)}
-    step = shard_map_compat(local_step, mesh,
-                            in_specs=(P(), P(), batch_specs),
-                            out_specs=(P(), P(), P()))
+    step = jax.shard_map(local_step, mesh=mesh,
+                         in_specs=(P(), P(), batch_specs),
+                         out_specs=(P(), P(), P()), check_vma=False)
     return jax.jit(step, donate_argnums=(0, 1))
 
 

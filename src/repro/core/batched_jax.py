@@ -26,7 +26,7 @@ per-workload prefix/suffix tables (``cumgrad``/``cumcount``, bucket
 suffix sums via :func:`repro.core.bucketsim.suffix_tables`) are the
 NumPy engine's own host-side arrays, shipped in as pytree inputs.
 Numerics run in float64 under a scoped
-``jax.experimental.enable_x64`` (never the global flag, which would
+``jax.enable_x64(True)`` context (never the global flag, which would
 leak into the repo's other jax code), which is what makes the <= 1e-6
 differential agreement against the NumPy oracle achievable; the
 differential suite (``tests/test_batched_jax.py``) pins it on every
@@ -65,7 +65,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core import analytical, batched, bucketsim
 from repro.core.batched import grid_evaluator
@@ -391,7 +390,7 @@ class JaxGridEvaluator:
             mesh = make_dp_mesh(len(jax.devices()))
         self.mesh = mesh
         if mesh is not None and S:
-            with enable_x64():
+            with jax.enable_x64(True):
                 kcodes = _shard_codes(kcodes, mesh)
                 scodes = _shard_codes(scodes, mesh)
         self._kcodes, self._scodes = kcodes, scodes
@@ -406,7 +405,7 @@ class JaxGridEvaluator:
         S = len(self.ev)
         if S == 0:
             return {k: np.empty(0) for k in _NUMERIC_COLS}
-        with enable_x64():
+        with jax.enable_x64(True):
             out = self._traced_columns(params)
             return {k: np.asarray(v)[:S] for k, v in out.items()
                     if k in _NUMERIC_COLS}
@@ -414,7 +413,7 @@ class JaxGridEvaluator:
     def _traced_columns(self, params: dict | None = None) -> dict:
         """The jit call itself — kept separate so the differentiable
         front end (:func:`iteration_time_fn`) can trace through it.
-        Callers are responsible for the ``enable_x64`` scope."""
+        Callers are responsible for the ``jax.enable_x64(True)`` scope."""
         tables = self._tables
         if params:
             unknown = set(params) - set(PARAM_KEYS)
@@ -581,7 +580,7 @@ def eval_scenarios_table_jax(
               "tmul": np.ones(len(uw)) if ut is None else ut}
     scodes = {"pi": polidx, "kidx": np.arange(S, dtype=np.int64)}
     coll_codes = tuple(int(x) for x in np.unique(coll)) or (0,)
-    with enable_x64():
+    with jax.enable_x64(True):
         out = _columns_jax(tables, pflags, kcodes, scodes, ucodes,
                            tl_overlaps, coll_codes)
         cols = {k: np.asarray(v) for k, v in out.items()
@@ -619,7 +618,7 @@ def iteration_time_fn(grid: ScenarioGrid):
     """``(f, params0)``: ``f(params) -> (S,)`` iteration times, jit
     compiled and differentiable w.r.t. every :data:`PARAM_KEYS` entry.
     Call (and differentiate) ``f`` inside a
-    ``jax.experimental.enable_x64()`` scope, or use the
+    ``jax.enable_x64(True)`` scope, or use the
     :func:`grad_iteration_time` convenience wrapper.
 
     The gradient w.r.t. ``bucket_bytes`` is exactly 0: iteration time
@@ -645,7 +644,7 @@ def grad_iteration_time(grid: ScenarioGrid,
     f, p0 = iteration_time_fn(grid)
     if params:
         p0 = {**p0, **params}
-    with enable_x64():
+    with jax.enable_x64(True):
         p = {k: jnp.asarray(v, dtype=jnp.float64) for k, v in p0.items()}
         g = jax.grad(lambda q: f(q).sum())(p)
         return {k: np.asarray(v) for k, v in g.items()}

@@ -128,11 +128,17 @@ def ring_allreduce_time(nbytes, n, bandwidth, latency, worker_axis=None):
     return t * (n > 1)
 
 
+#: ``2**k`` for every ``k`` a worker count can need (exact in float64).
+_POW2 = 2.0 ** np.arange(63)
+
+
 def _ceil_log2(n, xp=np):
-    """Exact ``ceil(log2 n)`` for integer arrays ``n >= 1`` (frexp-based
-    so powers of two never round up a notch)."""
-    m, e = xp.frexp(xp.asarray(n, dtype=xp.float64))
-    return xp.where(m == 0.5, e - 1, e).astype(xp.float64)
+    """Exact ``ceil(log2 n)`` for integer arrays ``n >= 1``, as the count
+    of powers of two below ``n`` — comparisons only, so powers of two
+    never round up a notch and the TPU's emulated float64 (which has
+    no 64-bit bitcast, hence no ``frexp``) compiles it."""
+    n = xp.asarray(n, dtype=xp.float64)
+    return (n[..., None] > _POW2).sum(axis=-1).astype(xp.float64)
 
 
 def tree_allreduce_time(nbytes, n, bandwidth, latency, worker_axis=None):

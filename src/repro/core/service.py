@@ -48,7 +48,9 @@ zero: :func:`parse_query` rejects malformed specs, unknown axis values
 and zero-scenario grids with a structured :class:`QueryError` (a
 stable ``code`` plus the same human-readable message the CLI prints
 before exiting 2), and evaluation failures resolve only the tickets of
-the failing group.
+the failing group — or, for a failure outside any group's evaluation
+(the structure probe, grouping), those of the failing batch; the
+dispatcher keeps serving either way.
 
 The trailer of every query carries the
 :data:`repro.core.sweep.RESULT_META_KEYS` metadata —
@@ -412,7 +414,17 @@ class SweepService:
                     closing = True
                     break
                 batch.append(item)
-            self._serve_batch(batch)
+            try:
+                self._serve_batch(batch)
+            except Exception as exc:
+                # the dispatcher outlives any one batch: fail the
+                # batch's unresolved tickets and keep serving
+                err = QueryError(f"evaluation failed: {exc}",
+                                 code="evaluation-failed")
+                for t in batch:
+                    if not t.done():
+                        self.stats.record_error()
+                        t._resolve(error=err)
         # resolve anything still queued after close
         while True:
             try:

@@ -2,31 +2,32 @@
 
 ``impl``:
   * ``"ref"``     — pure-jnp oracle (:mod:`repro.kernels.ref`)
-  * ``"pallas"``  — Pallas TPU kernel (``interpret=True`` off-TPU)
-  * ``"auto"``    — pallas on TPU backends, ref elsewhere (CPU dry-runs
-    lower the jnp path; the TPU deployment takes the kernel path)
+  * ``"pallas"``  — Pallas TPU kernel; raises ``ValueError`` on shapes
+    the kernel does not cover (never a silent substitute)
+  * ``"auto"``    — pallas on TPU backends where the shapes fit, the jnp
+    paths elsewhere (CPU dry-runs lower the jnp path; the TPU
+    deployment takes the kernel path)
+
+The kernels' own ``interpret=True`` (Python execution of a kernel
+body off-TPU) is for tests that call them directly; dispatch here
+always compiles the kernel for the chip.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import ref
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
-
-
 def _resolve(impl: str) -> str:
     if impl == "auto":
-        return "pallas" if _on_tpu() else "ref"
+        return "pallas" if jax.default_backend() == "tpu" else "ref"
     return impl
+
+
+def _uncovered(kernel: str, why: str):
+    return ValueError(f"impl='pallas': the {kernel} kernel does not cover "
+                      f"{why}; use impl='auto' or impl='ref'")
 
 
 # Self-attention sequences at or above this length route to the
@@ -42,11 +43,15 @@ def attention(q, k, v, *, q_positions=None, kv_positions=None, causal=True,
     if _resolve(impl) == "pallas":
         from repro.kernels import flash_attention as fa
         # The Pallas kernel covers self-attention with equal q/kv lengths
-        # and row-aligned positions; fall back otherwise.
+        # and row-aligned positions.
         if (aligned_self and S % fa.DEFAULT_BLOCK_Q == 0
                 and q.shape[-1] % 128 == 0):
-            return fa.flash_attention(q, k, v, causal=causal, window=window,
-                                      interpret=not _on_tpu())
+            return fa.flash_attention(q, k, v, causal=causal, window=window)
+        if impl == "pallas":
+            raise _uncovered(
+                "flash attention",
+                f"q {q.shape} / kv {k.shape} (needs causal self-attention, "
+                f"S % {fa.DEFAULT_BLOCK_Q} == 0, head_dim % 128 == 0)")
     if (impl in ("auto", "chunked") and aligned_self
             and S >= CHUNKED_ATTENTION_MIN_SEQ):
         from repro.kernels import chunked_attention as ca
@@ -70,8 +75,10 @@ def wkv6(r, k, v, w, u, state=None, impl: str = "auto"):
     if _resolve(impl) == "pallas":
         from repro.kernels import wkv6 as wk
         if r.shape[1] % wk.DEFAULT_BLOCK_T == 0:
-            return wk.wkv6(r, k, v, w, u, state=state,
-                           interpret=not _on_tpu())
+            return wk.wkv6(r, k, v, w, u, state=state)
+        if impl == "pallas":
+            raise _uncovered("wkv6", f"sequence length {r.shape[1]} "
+                             f"(needs a multiple of {wk.DEFAULT_BLOCK_T})")
     return ref.wkv6(r, k, v, w, u, state=state)
 
 
@@ -79,6 +86,8 @@ def rglru(x, r_gate, i_gate, lam, h0=None, impl: str = "auto"):
     if _resolve(impl) == "pallas":
         from repro.kernels import rglru as rg
         if x.shape[1] % rg.DEFAULT_BLOCK_T == 0 and x.shape[2] % 128 == 0:
-            return rg.rglru(x, r_gate, i_gate, lam, h0=h0,
-                            interpret=not _on_tpu())
+            return rg.rglru(x, r_gate, i_gate, lam, h0=h0)
+        if impl == "pallas":
+            raise _uncovered("rglru", f"x {x.shape} (needs S % "
+                             f"{rg.DEFAULT_BLOCK_T} == 0, width % 128 == 0)")
     return ref.rglru(x, r_gate, i_gate, lam, h0=h0)

@@ -28,12 +28,13 @@ DEFAULT_BLOCK_W = 128
 
 
 def _rglru_kernel(x_ref, r_ref, i_ref, lam_ref, h0_ref, o_ref, hout_ref,
-                  h_scr, *, block_t: int, num_t_blocks: int):
+                  h_scr, a_scr, g_scr, out_scr, *, block_t: int,
+                  num_t_blocks: int):
     it = pl.program_id(2)
 
     @pl.when(it == 0)
     def _init():
-        h_scr[...] = h0_ref[...].astype(jnp.float32)
+        h_scr[...] = h0_ref[0].astype(jnp.float32)
 
     x = x_ref[0].astype(jnp.float32)             # (T, W)
     r = r_ref[0].astype(jnp.float32)
@@ -42,20 +43,21 @@ def _rglru_kernel(x_ref, r_ref, i_ref, lam_ref, h0_ref, o_ref, hout_ref,
 
     log_a_base = -RGLRU_C * jax.nn.softplus(lam)     # (1, W)
     log_a = log_a_base * jax.nn.sigmoid(r)           # (T, W)
-    a = jnp.exp(log_a)
+    a_scr[...] = jnp.exp(log_a)
     mult = jnp.sqrt(jnp.maximum(1.0 - jnp.exp(2.0 * log_a), 1e-12))
-    gated = jax.nn.sigmoid(i) * x * mult             # (T, W)
+    g_scr[...] = jax.nn.sigmoid(i) * x * mult        # (T, W)
 
-    def body(t, carry):
-        h, out = carry
-        h = a[t] * h + gated[t]
-        out = out.at[t].set(h)
-        return h, out
+    # the sequential recurrence walks the block's rows through VMEM
+    # refs (a TPU kernel indexes refs, not values, at a traced step)
+    def body(t, h):
+        row = pl.ds(t, 1)
+        h = a_scr[row, :] * h + g_scr[row, :]        # (1, W)
+        out_scr[row, :] = h
+        return h
 
-    h, out = jax.lax.fori_loop(
-        0, block_t, body, (h_scr[0], jnp.zeros_like(x)))
-    o_ref[0] = out.astype(o_ref.dtype)
-    h_scr[0] = h
+    h = jax.lax.fori_loop(0, block_t, body, h_scr[...])
+    o_ref[0] = out_scr[...].astype(o_ref.dtype)
+    h_scr[...] = h
 
     @pl.when(it == num_t_blocks - 1)
     def _final():
@@ -89,17 +91,20 @@ def rglru(x, r_gate, i_gate, lam, h0=None, *,
             pl.BlockSpec((1, block_t, block_w), lambda b, iw, it: (b, it, iw)),
             pl.BlockSpec((1, block_t, block_w), lambda b, iw, it: (b, it, iw)),
             pl.BlockSpec((1, block_w), lambda b, iw, it: (0, iw)),
-            pl.BlockSpec((1, block_w), lambda b, iw, it: (b, iw)),
+            # per-row state as (B, 1, W): a (1, block_w) slab is
+            # tile-aligned, a row of a (B, W) array is not
+            pl.BlockSpec((1, 1, block_w), lambda b, iw, it: (b, 0, iw)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_t, block_w), lambda b, iw, it: (b, it, iw)),
-            pl.BlockSpec((1, block_w), lambda b, iw, it: (b, iw)),
+            pl.BlockSpec((1, 1, block_w), lambda b, iw, it: (b, 0, iw)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, S, W), x.dtype),
-            jax.ShapeDtypeStruct((B, W), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, W), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)]
+        + [pltpu.VMEM((block_t, block_w), jnp.float32)] * 3,
         interpret=interpret,
-    )(x, r_gate, i_gate, lam2, h0)
-    return out, hout
+    )(x, r_gate, i_gate, lam2, h0.reshape(B, 1, W))
+    return out, hout.reshape(B, W)

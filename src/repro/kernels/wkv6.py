@@ -50,11 +50,17 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     w = w_ref[0, 0].astype(jnp.float32)
-    u = u_ref[0].astype(jnp.float32)             # (hd,)
+    u = u_ref[0].astype(jnp.float32)             # (1, hd)
     S = state_scr[...]                           # (hd, hd)
 
     logw = jnp.log(jnp.maximum(w, 1e-38))
-    L = jnp.cumsum(logw, axis=0)                 # (T, hd), L_t = sum_{j<=t}
+    ti = jax.lax.broadcasted_iota(jnp.int32, (block_t, block_t), 0)
+    tj = jax.lax.broadcasted_iota(jnp.int32, (block_t, block_t), 1)
+    # L_t = sum_{j<=t} log w_j, as a lower-triangular matmul (a TPU
+    # kernel has no cumsum); full f32 precision, it feeds exp()
+    L = jax.lax.dot_general((tj <= ti).astype(jnp.float32), logw,
+                            (((1,), (0,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST)  # (T, hd)
     L_prev = L - logw                            # L_{t-1}
     r_scaled = r * jnp.exp(L_prev)
     k_scaled = k * jnp.exp(-L)
@@ -62,20 +68,22 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
     # intra-block strict-lower attention + diagonal u-bonus
     scores = jax.lax.dot_general(r_scaled, k_scaled,
                                  (((1,), (1,)), ((), ())))      # (T, T)
-    ti = jax.lax.broadcasted_iota(jnp.int32, (block_t, block_t), 0)
-    tj = jax.lax.broadcasted_iota(jnp.int32, (block_t, block_t), 1)
     scores = jnp.where(tj < ti, scores, 0.0)
-    diag = jnp.sum(r * u[None, :] * k, axis=-1)                 # (T,)
+    diag = jnp.sum(r * u * k, axis=-1, keepdims=True)           # (T, 1)
     o = (jax.lax.dot_general(scores, v, (((1,), (0,)), ((), ())))
-         + diag[:, None] * v
+         + diag * v
          + jax.lax.dot_general(r * jnp.exp(L_prev), S,
                                (((1,), (0,)), ((), ()))))
     o_ref[0, 0] = o.astype(o_ref.dtype)
 
-    # state to next block
-    decay_all = jnp.exp(L[-1])                                  # (hd,)
-    k_tail = k * jnp.exp(L[-1][None, :] - L)                    # (T, hd)
-    S_new = (decay_all[:, None] * S
+    # state to next block: the block's total log-decay as a row (static
+    # slice) and as a column (transposed matmul; no vector relayout)
+    L_last = L[block_t - 1:, :]                                 # (1, hd)
+    L_last_col = jax.lax.dot_general(
+        logw, jnp.ones((block_t, 1), jnp.float32), (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST)                    # (hd, 1)
+    k_tail = k * jnp.exp(L_last - L)                            # (T, hd)
+    S_new = (jnp.exp(L_last_col) * S
              + jax.lax.dot_general(k_tail, v, (((0,), (0,)), ((), ()))))
     state_scr[...] = S_new
 
@@ -109,7 +117,9 @@ def wkv6(r, k, v, w, u, state=None, *, block_t: int = DEFAULT_BLOCK_T,
             pl.BlockSpec((1, 1, block_t, hd), lambda b, h, it: (b, h, it, 0)),
             pl.BlockSpec((1, 1, block_t, hd), lambda b, h, it: (b, h, it, 0)),
             pl.BlockSpec((1, 1, block_t, hd), lambda b, h, it: (b, h, it, 0)),
-            pl.BlockSpec((1, hd), lambda b, h, it: (h, 0)),
+            # u as (H, 1, hd): a (1, hd) slab is tile-aligned, a row
+            # of an (H, hd) array is not
+            pl.BlockSpec((1, 1, hd), lambda b, h, it: (h, 0, 0)),
             pl.BlockSpec((1, 1, hd, hd), lambda b, h, it: (b, h, 0, 0)),
         ],
         out_specs=[
@@ -122,5 +132,5 @@ def wkv6(r, k, v, w, u, state=None, *, block_t: int = DEFAULT_BLOCK_T,
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
         interpret=interpret,
-    )(tr(r), tr(k), tr(v), tr(w), u, state)
+    )(tr(r), tr(k), tr(v), tr(w), u.reshape(H, 1, hd), state)
     return jnp.moveaxis(out, 1, 2), sout

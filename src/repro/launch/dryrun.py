@@ -6,7 +6,7 @@ MUST set the host-device flag before any jax import (jax locks the
 device count on first init); the shared helper appends to any
 user-provided ``XLA_FLAGS`` instead of clobbering them.
 """
-from repro.launch.hostdev import force_host_device_count
+from repro.launch.hostdev import child_env, force_host_device_count
 
 force_host_device_count(512)
 
@@ -28,7 +28,7 @@ from repro.configs import ARCH_IDS, SHAPES, dryrun_matrix, get_config
 from repro.core import archcost
 from repro.launch import hlo as hlo_mod
 from repro.launch import steps as steps_mod
-from repro.launch.mesh import activate_mesh, make_production_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import sharding as shd
 from repro.optim.sgd import sgd
 
@@ -102,7 +102,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool,
                 donate_argnums=(1,) if donate else ())
             args = (pshape, specs)
 
-        with activate_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             t0 = time.time()
             lowered = jitted.lower(*args)
             t1 = time.time()
@@ -190,7 +190,7 @@ def main(argv=None) -> int:
             print(f"[{i + 1}/{len(combos)}] {a} x {s} x "
                   f"{'2x16x16' if mp else '16x16'}", flush=True)
             r = subprocess.run(cmd, capture_output=True, text=True,
-                               timeout=3600)
+                               timeout=3600, env=child_env(512))
             if r.returncode != 0:
                 failures += 1
                 print(r.stdout[-2000:], r.stderr[-2000:], flush=True)

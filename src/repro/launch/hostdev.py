@@ -13,7 +13,9 @@ subprocess.  This module is the one place that flag is spelled:
 * :func:`force_host_device_count` — apply it to ``os.environ`` (call
   before importing jax);
 * :func:`child_env` — an environment dict for spawning a measurement /
-  dry-run subprocess.
+  dry-run subprocess on the CPU platform (``JAX_PLATFORMS=cpu``: forced
+  host devices are CPU devices, and a child must never race its parent
+  for a host's chip).
 
 Deliberately jax-free: importing this module must never initialize the
 backend the flag is trying to configure.
@@ -60,8 +62,10 @@ def force_host_device_count(n_devices: int,
 def child_env(n_devices: int,
               base: MutableMapping[str, str] | None = None) -> dict[str, str]:
     """A copy of ``base`` (default ``os.environ``) with ``XLA_FLAGS``
-    forcing ``n_devices`` host devices — for ``subprocess.run(env=...)``
-    when the current process already initialized jax."""
+    forcing ``n_devices`` host devices and ``JAX_PLATFORMS=cpu`` — for
+    ``subprocess.run(env=...)`` when the current process already
+    initialized jax."""
     env = dict(os.environ if base is None else base)
     env["XLA_FLAGS"] = host_device_flags(n_devices, env.get("XLA_FLAGS"))
+    env["JAX_PLATFORMS"] = "cpu"
     return env

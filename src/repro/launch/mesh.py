@@ -8,40 +8,21 @@ jax import; tests and benches see the real single device.
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType landed after jax 0.4.x; older jax implies Auto axes.
-    from jax.sharding import AxisType
-
-    def _axis_kwargs(n_axes: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
-
-    def _axis_kwargs(n_axes: int) -> dict:
-        return {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """TPU v5e: one pod = 16x16 chips; two pods add a leading DCN axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_cpu_mesh(n_data: int = 1, n_model: int = 1):
     """Small host mesh for tests / CPU validation runs."""
-    axes = ("data", "model")
-    return jax.make_mesh((n_data, n_model), axes, **_axis_kwargs(2))
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_dp_mesh(n: int):
-    return jax.make_mesh((n,), ("data",), **_axis_kwargs(1))
-
-
-def activate_mesh(mesh):
-    """Context manager making ``mesh`` the ambient mesh across jax
-    versions: ``jax.sharding.set_mesh`` where it exists, else the
-    legacy global-mesh context (``with mesh:``) of jax 0.4.x."""
-    if hasattr(jax.sharding, "set_mesh"):
-        return jax.sharding.set_mesh(mesh)
-    return mesh
+    return jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))

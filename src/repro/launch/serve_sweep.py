@@ -242,6 +242,8 @@ def main(argv=None) -> int:
                    help="log each request")
     args = p.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compilation_cache
+    enable_compilation_cache()
     service = SweepService(window_s=args.window_ms / 1e3,
                            max_coalesce=args.max_coalesce)
     if not args.no_warm:

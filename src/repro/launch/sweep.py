@@ -198,6 +198,9 @@ def main(argv: list[str] | None = None) -> int:
         print("error: --stream requires --csv and/or --json",
               file=sys.stderr)
         return 2
+    if args.backend == "jax":
+        from repro.launch.compile_cache import enable_compilation_cache
+        enable_compilation_cache()
     if args.backend == "jax" and args.per_scenario:
         print("error: --backend jax is the batched kernel; --per-scenario "
               "pins the per-scenario NumPy reference paths (drop one)",

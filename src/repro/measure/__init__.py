@@ -16,10 +16,10 @@ stack:
   bytes against the HLO analysis (:mod:`repro.launch.hlo`) and the
   workload table's ``grad_bytes``, and fit an alpha-beta collective
   model to the measured all-reduces;
-* :mod:`repro.measure.run` — the CLI / subprocess runner
-  (``python -m repro.measure --arch <id>``): spawns itself with the
-  forced-host-platform flag (shared helper
-  :mod:`repro.launch.hostdev`), writes ``<arch>.trace`` +
+* :mod:`repro.measure.run` — the CLI runner
+  (``python -m repro.measure --arch <id>``): measures in process on a
+  chip host's chips, or on forced host CPU devices (shared helper
+  :mod:`repro.launch.hostdev`), and writes ``<arch>.trace`` +
   ``<arch>.json`` into the measurement directory, from which the
   ``jax:`` workload provider (:mod:`repro.core.workloads`) serves
   sweepable tables.
@@ -28,9 +28,10 @@ stack:
 model-predicted vs measured iteration time per sync policy, gated in
 CI.
 """
-from repro.measure.calibrate import (HOST_CLUSTER_NAME, BytesCrossCheck,
+from repro.measure.calibrate import (BytesCrossCheck,
                                      comm_scale_from_fit,
                                      crosscheck_collective_bytes,
+                                     device_cluster_name,
                                      expected_collective_bytes,
                                      fit_alpha_beta, grad_payload_bytes)
 from repro.measure.harness import (MEASURED_SYNC_POLICIES, MeasuredRun,
@@ -40,5 +41,5 @@ __all__ = [
     "MEASURED_SYNC_POLICIES", "MeasuredRun", "measure_model",
     "segment_from_depths", "grad_payload_bytes", "fit_alpha_beta",
     "comm_scale_from_fit", "expected_collective_bytes",
-    "crosscheck_collective_bytes", "BytesCrossCheck", "HOST_CLUSTER_NAME",
+    "crosscheck_collective_bytes", "BytesCrossCheck", "device_cluster_name",
 ]

@@ -29,12 +29,17 @@ import numpy as np
 from repro.models import transformer as T
 from repro.models.common import ModelConfig
 
-#: Cluster-name prefix recorded in measured traces (suffixed with the
-#: device count, e.g. ``jax-host-cpu-x8``).
-HOST_CLUSTER_NAME = "jax-host-cpu"
 
-#: f32 scalar collectives the ddp step issues besides the gradient
-#: sync: ``pmean(total_loss)`` + ``pmean(loss)``.
+def device_cluster_name(devices) -> str:
+    """Cluster label of a measured trace: the devices' kind as jax
+    reports it, slugged, and their count — ``tpu-v5-lite-x4`` on four
+    TPU v5e chips, ``cpu-x2`` on two forced host devices."""
+    devices = list(devices)
+    kind = "-".join(devices[0].device_kind.lower().split())
+    return f"{kind}-x{len(devices)}"
+
+#: Bytes of the one metrics collective the ddp step issues besides the
+#: gradient sync: ``pmean`` of the f32[2] vector ``(total_loss, loss)``.
 METRIC_COLLECTIVE_BYTES = 8.0
 
 
@@ -68,7 +73,7 @@ def expected_collective_bytes(cfg: ModelConfig, sync_policy: str) -> float:
       mix dtypes (bf16 weights, f32 norms), so rescaling a
       dtype-weighted total would miscount;
 
-    plus the two scalar metric pmeans every policy issues.
+    plus the f32[2] metrics pmean every policy issues.
     """
     if sync_policy not in ("at_end", "wfbp", "bucketed"):
         raise ValueError(f"unknown sync policy {sync_policy!r}")

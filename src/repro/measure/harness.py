@@ -1,7 +1,8 @@
 """Per-layer timing harness over the repo's *real* jax train steps.
 
 Runs the explicit data-parallel S-SGD step (:mod:`repro.comm.ddp`)
-under each gradient-sync policy on forced host devices and harvests
+under each gradient-sync policy on the visible devices — the chips of
+a TPU host, or forced host CPU devices for rehearsals — and harvests
 everything the DAG model needs:
 
 * **whole-step wall time per policy** — the "measured" side of the
@@ -26,10 +27,10 @@ The result is emitted as a paper-format
 through the exact machinery the published traces use, and the ``jax:``
 workload provider serves them to the sweep engine.
 
-Requires the host platform to expose enough devices — spawn through
-:mod:`repro.measure.run` (or set
+Requires ``n_devices`` visible devices: the chips of the host, or on
+a CPU host forced host devices — :mod:`repro.measure.run` sets
 :func:`repro.launch.hostdev.force_host_device_count` before the first
-jax import).
+jax import.
 """
 from __future__ import annotations
 
@@ -42,11 +43,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.comm.ddp import make_ddp_train_step, shard_map_compat
+from repro.comm.ddp import make_ddp_train_step
 from repro.comm.sync import DEFAULT_BUCKET_BYTES
 from repro.launch import hlo as hlo_mod
 from repro.launch.mesh import make_dp_mesh
-from repro.measure.calibrate import HOST_CLUSTER_NAME, grad_payload_bytes
+from repro.measure.calibrate import device_cluster_name, grad_payload_bytes
 from repro.models import transformer as T
 from repro.models.common import ModelConfig
 from repro.optim.sgd import sgd
@@ -133,8 +134,10 @@ def _default_depths(cfg: ModelConfig) -> tuple[int, int]:
             f"(num_layers {cfg.num_layers} < pattern "
             f"{cfg.layer_pattern!r})")
     # a 2x depth spread keeps the fitted slope well above wall-clock
-    # noise even for tiny smoke models (one extra trip would not)
-    return (u, 2 * u)
+    # noise even for tiny smoke models (one extra trip would not); the
+    # deeper point is the measured model itself, so segmentation never
+    # builds a model larger than the one the step trains
+    return (u // 2, u) if u >= 2 else (1, 2)
 
 
 # ----------------------------------------------------------------------
@@ -154,6 +157,7 @@ class MeasuredRun:
     trace: Trace                          # per-layer fwd/bwd/comm + bytes
     segments: SegmentTiming
     policy_times: dict[str, float]        # measured wall s/iter per policy
+    policy_losses: dict[str, float]       # loss after the timed steps
     collective_stats: dict[str, dict]     # per policy: HLO-harvested bytes
     t_update_s: float
     allreduce_samples: list[tuple[float, float]]   # (payload bytes, seconds)
@@ -176,6 +180,7 @@ class MeasuredRun:
             "num_units": self.num_units,
             "depths": list(self.depths),
             "policy_times_s": self.policy_times,
+            "policy_losses": self.policy_losses,
             "collective_stats": self.collective_stats,
             "t_update_s": self.t_update_s,
             "allreduce_samples": [[b, t] for b, t in self.allreduce_samples],
@@ -226,28 +231,32 @@ def _time_allreduce(mesh, nbytes: float, repeats: int) -> float:
 
     n = max(int(nbytes) // 4, 1)
     arr = jnp.ones((n_dev, n), jnp.float32)
-    fn = jax.jit(shard_map_compat(
-        lambda x: jax.lax.pmean(x, "data"), mesh,
-        in_specs=P("data"), out_specs=P("data")))
+    fn = jax.jit(jax.shard_map(
+        lambda x: jax.lax.pmean(x, "data"), mesh=mesh,
+        in_specs=P("data"), out_specs=P("data"), check_vma=False))
     return _timeit(lambda: jax.block_until_ready(fn(arr)), repeats)
 
 
 def _time_policy_step(cfg: ModelConfig, mesh, policy: str,
                       batch: dict, step_iters: int,
-                      bucket_bytes: float) -> tuple[float, dict]:
-    """(measured seconds/iteration, HLO collective stats) for one
-    executable sync policy: AOT-compile the ddp step once, read its
-    optimized HLO for the bytes harvest, then run it ``step_iters``
-    times back-to-back (outputs re-fed, one trailing block) — the
-    steady-pipeline timing of the paper's measurements."""
+                      bucket_bytes: float) -> tuple[float, dict, float]:
+    """(measured seconds/iteration, HLO collective stats, last loss)
+    for one executable sync policy: AOT-compile the ddp step once, read
+    its optimized HLO for the bytes harvest (and the count of Pallas
+    kernel calls, ``stats["pallas_calls"]``), then run it
+    ``step_iters`` times back-to-back (outputs re-fed, one trailing
+    block) — the steady-pipeline timing of the paper's
+    measurements."""
     opt = sgd(lr=1e-2, momentum=0.9)
     step = make_ddp_train_step(cfg, opt, mesh, sync_policy=policy,
                                bucket_bytes=bucket_bytes)
     params = T.init_lm(cfg, jax.random.PRNGKey(3))
     opt_state = opt.init(params)
     compiled = step.lower(params, opt_state, batch).compile()
+    hlo = compiled.as_text()
     stats = hlo_mod.collective_stats(
-        compiled.as_text(), loop_trip_count=max(cfg.num_units, 1))
+        hlo, loop_trip_count=max(cfg.num_units, 1)).to_dict()
+    stats["pallas_calls"] = hlo.count('custom_call_target="tpu_custom_call"')
 
     p, st, m = compiled(params, opt_state, batch)      # warmup
     jax.block_until_ready(m["loss"])
@@ -255,7 +264,8 @@ def _time_policy_step(cfg: ModelConfig, mesh, policy: str,
     for _ in range(step_iters):
         p, st, m = compiled(p, st, batch)
     jax.block_until_ready(m["loss"])
-    return (time.perf_counter() - t0) / step_iters, stats.to_dict()
+    return ((time.perf_counter() - t0) / step_iters, stats,
+            float(m["loss"]))
 
 
 def measure_model(cfg: ModelConfig, *, arch: str = "",
@@ -265,8 +275,9 @@ def measure_model(cfg: ModelConfig, *, arch: str = "",
                   depths: tuple[int, int] | None = None,
                   repeats: int = 3, step_iters: int = 5,
                   bucket_bytes: float = DEFAULT_BUCKET_BYTES) -> MeasuredRun:
-    """Instrument ``cfg``'s train step end to end on ``n_devices``
-    forced host devices and return the full :class:`MeasuredRun`.
+    """Instrument ``cfg``'s train step end to end on the first
+    ``n_devices`` visible devices and return the full
+    :class:`MeasuredRun`.
 
     ``batch_per_gpu`` is the per-device batch (the global batch is
     ``batch_per_gpu * n_devices``); segmentation and collective timing
@@ -277,7 +288,8 @@ def measure_model(cfg: ModelConfig, *, arch: str = "",
     avail = len(jax.devices())
     if avail < n_devices:
         raise RuntimeError(
-            f"need {n_devices} devices but jax sees {avail}; spawn via "
+            f"need {n_devices} devices but jax sees {avail} "
+            f"{jax.default_backend()} device(s); on a CPU host run "
             f"`python -m repro.measure` (or call "
             f"repro.launch.hostdev.force_host_device_count before the "
             f"first jax import)")
@@ -294,10 +306,12 @@ def measure_model(cfg: ModelConfig, *, arch: str = "",
 
     # 1) whole-step wall time + HLO collective bytes, per policy
     policy_times: dict[str, float] = {}
+    policy_losses: dict[str, float] = {}
     collective: dict[str, dict] = {}
     for pol in policies:
-        policy_times[pol], collective[pol] = _time_policy_step(
-            cfg, mesh, pol, batch, step_iters, bucket_bytes)
+        policy_times[pol], collective[pol], policy_losses[pol] = \
+            _time_policy_step(cfg, mesh, pol, batch, step_iters,
+                              bucket_bytes)
 
     # 2) per-layer segmentation via the scan structure (one device)
     segments = _time_segments(cfg, depths, batch_per_gpu, seq_len, repeats)
@@ -313,14 +327,21 @@ def measure_model(cfg: ModelConfig, *, arch: str = "",
         if nbytes > 0 and t > 0:
             samples.append((nbytes, t))
 
-    # 4) optimizer update (t_u)
+    # 4) optimizer update (t_u); params and state are donated and
+    # re-fed, so a full-width model holds one copy of each on the chip
     opt = sgd(lr=1e-2, momentum=0.9)
     params = T.init_lm(cfg, jax.random.PRNGKey(2))
-    st = opt.init(params)
+    cur = {"p": params, "s": opt.init(params)}
     g = jax.tree_util.tree_map(jnp.ones_like, params)
-    upd = jax.jit(lambda g, s, p: opt.update(g, s, p))
-    t_update = _timeit(lambda: jax.block_until_ready(upd(g, st, params)),
-                       repeats)
+    del params
+    upd = jax.jit(opt.update, donate_argnums=(1, 2))
+
+    def update_once():
+        cur["p"], cur["s"] = upd(g, cur["s"], cur["p"])
+        jax.block_until_ready(cur["p"])
+
+    t_update = _timeit(update_once, repeats)
+    del cur, g
 
     # 5) paper-format trace: the non-scanned remainder (embedding +
     # head + loss) as layer 0, one record per scanned unit.  Layer 0's
@@ -338,7 +359,7 @@ def measure_model(cfg: ModelConfig, *, arch: str = "",
                                 unit_bytes))
     trace = Trace(
         network=cfg.name,
-        cluster=f"{HOST_CLUSTER_NAME}-x{n_devices}",
+        cluster=device_cluster_name(mesh.devices.flat),
         iterations=(tuple(recs),),
         batch_per_gpu=batch_per_gpu,
         # int32 tokens + labels per sample position
@@ -356,6 +377,7 @@ def measure_model(cfg: ModelConfig, *, arch: str = "",
         trace=trace,
         segments=segments,
         policy_times=policy_times,
+        policy_losses=policy_losses,
         collective_stats=collective,
         t_update_s=t_update,
         allreduce_samples=samples,

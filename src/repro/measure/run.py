@@ -1,22 +1,29 @@
 """Measurement runner / CLI: ``python -m repro.measure --arch <id>``.
 
-jax locks the host device count at first backend init, so the
-measurement always executes in a **child process** whose environment
-carries ``--xla_force_host_platform_device_count`` (via the shared
-:mod:`repro.launch.hostdev` helper, which appends to — never clobbers
-— user ``XLA_FLAGS``).  Invoked without the child marker, :func:`main`
-re-spawns itself with the right environment; with it, it measures
-in-process and writes two artifacts into the measurement directory:
+Measures in process, on the devices jax finds:
+
+* on a TPU host, the chips there, at the architecture's published
+  width and its own dtype with only the depth cut
+  (:data:`CHIP_GEOMETRY`);
+* on a CPU host, forced host CPU devices at a reduced f32 width
+  (:class:`Geometry` defaults; ``--smoke`` for the tiny CI preset).
+  jax fixes the host device count when its backend starts, so
+  :func:`main` puts ``--xla_force_host_platform_device_count`` into
+  ``XLA_FLAGS`` (:mod:`repro.launch.hostdev`) before any device is
+  queried; a chip host ignores the flag.
+
+Two artifacts land in the measurement directory:
 
 * ``<arch>.trace`` — the paper-format per-layer trace the ``jax:``
   workload provider serves (sweepable like any other workload);
-* ``<arch>.json`` — the full harvest: per-policy measured step times,
-  HLO collective bytes + cross-checks, the alpha-beta collective fit,
-  segmentation and geometry metadata.
+* ``<arch>.json`` — the full harvest: the device it ran on, the
+  geometry, per-policy measured step times and losses, HLO collective
+  bytes + cross-checks, the alpha-beta collective fit, segmentation
+  metadata.
 
-The measured model is a host-CPU-feasible ``reduced()`` variant of the
-named architecture (geometry on the CLI); the trace records the real
-geometry in its headers.  ``--smoke`` picks the tiny CI-sized preset.
+:func:`measure_in_subprocess` is the CPU rehearsal path for tests and
+benchmarks whose own process already holds jax: a child pinned to the
+CPU platform with forced host devices.
 """
 from __future__ import annotations
 
@@ -28,9 +35,7 @@ import subprocess
 import sys
 from pathlib import Path
 
-from repro.launch.hostdev import child_env
-
-_CHILD_MARKER = "REPRO_MEASURE_CHILD"
+from repro.launch.hostdev import child_env, force_host_device_count
 
 #: Decoder-only archs the explicit-DP step can train as-is (the
 #: encoder-decoder and vision archs need extra batch inputs the ddp
@@ -40,19 +45,26 @@ MEASURABLE_ARCHS = (
     "qwen1.5-4b", "qwen2-moe-a2.7b", "recurrentgemma-2b", "rwkv6-1.6b",
 )
 
+#: Width fields of :class:`Geometry`; all ``None`` means the
+#: architecture's published width.
+WIDTH_FIELDS = ("d_model", "num_heads", "d_ff", "vocab_size")
+
 
 @dataclasses.dataclass(frozen=True)
 class Geometry:
-    """Host-feasible model/measurement geometry."""
+    """Model/measurement geometry.  Width fields set: the f32
+    ``reduced()`` variant at those widths (host-CPU feasible); all
+    ``None``: the published width in the architecture's own dtype.
+    ``n_devices=None`` takes every visible device."""
 
     num_layers: int = 8
-    d_model: int = 256
-    num_heads: int = 4
-    d_ff: int = 512
-    vocab_size: int = 1024
+    d_model: int | None = 256
+    num_heads: int | None = 4
+    d_ff: int | None = 512
+    vocab_size: int | None = 1024
     seq_len: int = 64
     batch_per_gpu: int = 4
-    n_devices: int = 2
+    n_devices: int | None = 2
     repeats: int = 5
     step_iters: int = 8
 
@@ -61,6 +73,13 @@ SMOKE_GEOMETRY = Geometry(num_layers=4, d_model=128, d_ff=256,
                           vocab_size=512, seq_len=32, batch_per_gpu=2,
                           repeats=3, step_iters=4)
 
+#: The TPU preset: published width, depth cut to 4 layers, 1k-token
+#: sequences, every chip of the host.
+CHIP_GEOMETRY = Geometry(num_layers=4, d_model=None, num_heads=None,
+                         d_ff=None, vocab_size=None, seq_len=1024,
+                         batch_per_gpu=2, n_devices=None, repeats=3,
+                         step_iters=3)
+
 
 def default_out_dir() -> str:
     from repro.core.workloads import JaxProvider
@@ -68,21 +87,42 @@ def default_out_dir() -> str:
     return JaxProvider.measure_dir()
 
 
+def measured_config(arch: str, geometry: Geometry):
+    """The :class:`~repro.models.common.ModelConfig` a geometry
+    measures: the published config with only ``num_layers`` cut when
+    every width field is ``None``, else its f32 ``reduced()`` variant."""
+    from repro.configs import get_config
+
+    cfg = get_config(arch)
+    widths = {f: getattr(geometry, f) for f in WIDTH_FIELDS}
+    if all(v is None for v in widths.values()):
+        return dataclasses.replace(
+            cfg, name=f"{cfg.name}-{geometry.num_layers}l",
+            num_layers=geometry.num_layers).validate()
+    unset = [f for f, v in widths.items() if v is None]
+    if unset:
+        raise ValueError(f"geometry sets some widths but not {unset}: "
+                         f"set all of {WIDTH_FIELDS} or none")
+    return cfg.reduced(num_layers=geometry.num_layers, **widths)
+
+
 def run_measurement(arch: str, out_dir: str | Path,
                     geometry: Geometry,
                     policies: tuple[str, ...] | None = None) -> dict:
-    """Measure ``arch`` in-process (device count must already be
-    forced), write ``<arch>.trace`` + ``<arch>.json`` into ``out_dir``,
-    and return the JSON document."""
-    from repro.configs import get_config
+    """Measure ``arch`` in process on the visible devices (on a CPU
+    host the device count must already be forced), write
+    ``<arch>.trace`` + ``<arch>.json`` into ``out_dir``, and return the
+    JSON document."""
+    import jax
+
     from repro.measure import calibrate
     from repro.measure.harness import MEASURED_SYNC_POLICIES, measure_model
     from repro.traces.format import write_trace
 
     g = geometry
-    cfg = get_config(arch).reduced(
-        num_layers=g.num_layers, d_model=g.d_model, num_heads=g.num_heads,
-        d_ff=g.d_ff, vocab_size=g.vocab_size)
+    if g.n_devices is None:
+        g = dataclasses.replace(g, n_devices=len(jax.devices()))
+    cfg = measured_config(arch, g)
     run = measure_model(
         cfg, arch=arch, n_devices=g.n_devices,
         batch_per_gpu=g.batch_per_gpu, seq_len=g.seq_len,
@@ -95,9 +135,18 @@ def run_measurement(arch: str, out_dir: str | Path,
     write_trace(run.trace, trace_path)
 
     latency, bandwidth = calibrate.fit_alpha_beta(run.allreduce_samples)
-    checks = calibrate.crosscheck_collective_bytes(cfg, run.collective_stats)
+    # one device needs no gradient traffic, and XLA's TPU pipeline drops
+    # single-participant all-reduces, so the bytes cross-check is a
+    # multi-device check
+    checks = calibrate.crosscheck_collective_bytes(
+        cfg, run.collective_stats) if g.n_devices > 1 else {}
+    dev = jax.devices()[0]
     doc = dict(run.summary())
     doc.update({
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": g.n_devices},
+        "geometry": dataclasses.asdict(g),
+        "dtype": jax.numpy.dtype(cfg.dtype).name,
         "workload": f"jax:{arch}",
         "trace_path": str(trace_path),
         "allreduce_fit": {"latency_s": latency,
@@ -123,41 +172,31 @@ def _geometry_flag(field_name: str) -> str:
                                "--" + field_name.replace("_", "-"))
 
 
-def _marked_child_env(n_devices: int) -> dict[str, str]:
-    """Environment for the measurement child: forced host devices, the
-    re-spawn marker, and this repo's ``src`` on PYTHONPATH so the child
-    resolves ``repro`` regardless of cwd."""
-    env = child_env(n_devices)
-    env[_CHILD_MARKER] = "1"
-    src = str(Path(__file__).resolve().parents[2])
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    return env
-
-
-def _spawn_child(child_argv: list[str], n_devices: int, *,
-                 capture: bool, timeout: float | None = None):
-    """The one spawn contract for measurement children — CLI re-spawn
-    and programmatic runs must never diverge."""
-    return subprocess.run(
-        [sys.executable, "-m", "repro.measure.run", *child_argv],
-        env=_marked_child_env(n_devices),
-        capture_output=capture, text=capture, timeout=timeout)
-
-
 def measure_in_subprocess(arch: str, *, out_dir: str | Path,
                           geometry: Geometry = SMOKE_GEOMETRY,
                           policies: tuple[str, ...] | None = None,
                           timeout: float = 1800) -> dict:
-    """Spawn the measurement child for ``arch`` and return its JSON
-    document — the entry point for benchmarks/tests whose own process
-    must keep the single-device view."""
+    """Measure ``arch`` in a CPU child and return its JSON document —
+    the CPU rehearsal for benchmarks/tests whose own process already
+    holds jax.  The child runs a reduced width only: the published
+    width is measured in process on a chip host."""
+    if None in (getattr(geometry, f) for f in WIDTH_FIELDS + ("n_devices",)):
+        raise ValueError("measure_in_subprocess runs reduced widths on "
+                         "forced CPU devices; set every width field and "
+                         "n_devices")
     argv = ["--arch", arch, "--out-dir", str(out_dir)]
     for f in dataclasses.fields(Geometry):
         argv += [_geometry_flag(f.name), str(getattr(geometry, f.name))]
     if policies:
         argv += ["--policies", ",".join(policies)]
-    r = _spawn_child(argv, geometry.n_devices, capture=True,
-                     timeout=timeout)
+    # CPU platform, forced host devices, and this repo's ``src`` on
+    # PYTHONPATH so the child resolves ``repro`` regardless of cwd
+    env = child_env(geometry.n_devices)
+    src = str(Path(__file__).resolve().parents[2])
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    r = subprocess.run(
+        [sys.executable, "-m", "repro.measure.run", *argv],
+        env=env, capture_output=True, text=True, timeout=timeout)
     if r.returncode != 0:
         raise RuntimeError(
             f"measurement subprocess for {arch!r} failed "
@@ -177,12 +216,13 @@ def build_parser() -> argparse.ArgumentParser:
                         "$REPRO_MEASURE_DIR or results/measure/)")
     # geometry flags default to None so "explicitly passed" is
     # distinguishable from "follow the preset" (--smoke or full)
-    full, smoke = Geometry(), SMOKE_GEOMETRY
+    full, smoke, chip = Geometry(), SMOKE_GEOMETRY, CHIP_GEOMETRY
     for f in dataclasses.fields(Geometry):
         p.add_argument(_geometry_flag(f.name), type=int, default=None,
                        dest=f.name,
-                       help=f"default {getattr(full, f.name)} "
-                            f"(--smoke: {getattr(smoke, f.name)})")
+                       help=f"default {getattr(full, f.name)} on CPU, "
+                            f"{getattr(chip, f.name) or 'published/all'} on "
+                            f"TPU (--smoke: {getattr(smoke, f.name)})")
     p.add_argument("--policies", default=None,
                    help="comma-separated sync policies "
                         "(default: at_end,wfbp,bucketed)")
@@ -192,8 +232,10 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
-def _geometry_from_args(args: argparse.Namespace) -> Geometry:
-    base = SMOKE_GEOMETRY if args.smoke else Geometry()
+def _geometry_from_args(args: argparse.Namespace,
+                        on_tpu: bool = False) -> Geometry:
+    base = SMOKE_GEOMETRY if args.smoke else (
+        CHIP_GEOMETRY if on_tpu else Geometry())
     return dataclasses.replace(base, **{
         f.name: getattr(args, f.name) for f in dataclasses.fields(Geometry)
         if getattr(args, f.name) is not None})
@@ -201,22 +243,24 @@ def _geometry_from_args(args: argparse.Namespace) -> Geometry:
 
 def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
-    geometry = _geometry_from_args(args)
+    # forced host devices must be in XLA_FLAGS before jax's backend
+    # starts; they are CPU devices, which a chip host leaves unused
+    force_host_device_count(args.n_devices or Geometry().n_devices)
+    import jax
+
+    from repro.launch.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
+    geometry = _geometry_from_args(
+        args, on_tpu=jax.default_backend() == "tpu")
     out_dir = args.out_dir or default_out_dir()
     policies = tuple(t.strip() for t in args.policies.split(",")
                      if t.strip()) if args.policies else None
-
-    if os.environ.get(_CHILD_MARKER) != "1":
-        # re-spawn with the forced-host-device environment
-        child_argv = sys.argv[1:] if argv is None else list(argv)
-        return _spawn_child(child_argv, geometry.n_devices,
-                            capture=False).returncode
-
     doc = run_measurement(args.arch, out_dir, geometry, policies)
     brief = {k: doc[k] for k in
-             ("workload", "trace_path", "n_devices", "policy_times_s",
-              "t_update_s", "allreduce_fit", "bytes_crosscheck",
-              "elapsed_s")}
+             ("workload", "device", "trace_path", "policy_times_s",
+              "policy_losses", "t_update_s", "allreduce_fit",
+              "bytes_crosscheck", "elapsed_s")}
     print(json.dumps(brief, indent=2))
     return 0
 

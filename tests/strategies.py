@@ -7,10 +7,7 @@ one vocabulary: random per-layer cost vectors, random gradient-payload
 rows, and random batched-eligible scenario grids (the NumPy ≡ JAX
 differential surface of ``test_batched_jax.py``).
 
-Works under both the real ``hypothesis`` package (CI installs it) and
-the deterministic mini-shim ``conftest.py`` substitutes locally — stick
-to the shim's API subset: ``integers`` / ``floats`` / ``booleans`` /
-``lists`` / ``sampled_from`` / ``composite`` with positional bounds.
+Built on the ``hypothesis`` package (a test dependency).
 """
 from __future__ import annotations
 

@@ -141,7 +141,6 @@ class TestKthOrderStatistic:
     def test_jitted_jax_top_k_agrees_with_numpy(self):
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         rng = np.random.default_rng(0)
         vals = rng.uniform(0.1, 2.0, size=(32, 7))
@@ -149,7 +148,7 @@ class TestKthOrderStatistic:
         vals *= np.arange(7) < n[:, None]          # zero-pad dead slots
         k = np.minimum(rng.integers(1, 8, size=32), n)
         want = analytical.kth_order_statistic(vals, n, k)
-        with enable_x64():
+        with jax.enable_x64(True):
             got = jax.jit(analytical.kth_order_statistic)(
                 jnp.asarray(vals), jnp.asarray(n), jnp.asarray(k))
         np.testing.assert_allclose(np.asarray(got), want, rtol=1e-12)

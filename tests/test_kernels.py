@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import ref
+from repro.kernels import ops, ref
 from repro.kernels.chunked_attention import chunked_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rglru import rglru
@@ -48,6 +48,28 @@ class TestFlashAttention:
                               block_q=128, block_k=128, interpret=True)
         want = ref.attention(q, k, v, causal=True, window=window)
         assert float(jnp.max(jnp.abs(out - want))) < 2e-4
+
+    @pytest.mark.parametrize("K,window", [(2, None), (4, 100)])
+    def test_grad_matches_reference(self, K, window):
+        """The custom VJP (Pallas forward + the chunked recomputing
+        backward) against autodiff of the jnp oracle."""
+        ks = jax.random.split(KEY, 3)
+        q = jax.random.normal(ks[0], (1, 256, 4, 64))
+        k = jax.random.normal(ks[1], (1, 256, K, 64))
+        v = jax.random.normal(ks[2], (1, 256, K, 64))
+
+        def f(*a):
+            return jnp.sum(jnp.sin(flash_attention(
+                *a, window=window, interpret=True)))
+
+        def g(*a):
+            return jnp.sum(jnp.sin(ref.attention(*a, causal=True,
+                                                 window=window)))
+
+        gf = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(g, argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(gf, gr):
+            assert float(jnp.max(jnp.abs(a - b))) < 1e-4
 
     def test_rejects_misaligned(self):
         q = jnp.zeros((1, 100, 2, 64))
@@ -172,3 +194,30 @@ class TestDecodePartials:
         l = l1 * jnp.exp(m1 - m) + l2 * jnp.exp(m2 - m)
         got = o / jnp.maximum(l, 1e-30)[..., None]
         assert float(jnp.max(jnp.abs(got - want))) < 1e-5
+
+
+class TestOpsDispatch:
+    """``impl="pallas"`` never substitutes another implementation: a
+    shape the kernel does not cover raises."""
+
+    @pytest.mark.parametrize("call", [
+        lambda: ops.attention(jnp.zeros((1, 100, 2, 64)),
+                              jnp.zeros((1, 100, 2, 64)),
+                              jnp.zeros((1, 100, 2, 64)), impl="pallas"),
+        lambda: ops.wkv6(*[jnp.zeros((1, 100, 2, 64))] * 4,
+                         jnp.zeros((2, 64)), impl="pallas"),
+        lambda: ops.rglru(*[jnp.zeros((1, 128, 100))] * 3,
+                          jnp.zeros((100,)), impl="pallas"),
+    ], ids=["attention", "wkv6", "rglru"])
+    def test_pallas_on_uncovered_shape_raises(self, call):
+        with pytest.raises(ValueError, match="does not cover"):
+            call()
+
+    def test_auto_off_tpu_is_the_reference(self):
+        ks = jax.random.split(KEY, 3)
+        q = jax.random.normal(ks[0], (1, 128, 2, 128))
+        k = jax.random.normal(ks[1], (1, 128, 2, 128))
+        v = jax.random.normal(ks[2], (1, 128, 2, 128))
+        assert jax.default_backend() != "tpu"
+        assert jnp.array_equal(ops.attention(q, k, v),
+                               ref.attention(q, k, v, causal=True))

@@ -58,10 +58,46 @@ class TestHostdev:
         env = child_env(4, {"PYTHONPATH": "x"})
         assert env["PYTHONPATH"] == "x"
         assert f"{HOST_DEVICE_FLAG}=4" in env["XLA_FLAGS"]
+        assert env["JAX_PLATFORMS"] == "cpu"
 
     def test_rejects_nonpositive(self):
         with pytest.raises(ValueError):
             host_device_flags(0)
+
+
+# ----------------------------------------------------------------------
+# The persistent compilation cache's location rule
+# ----------------------------------------------------------------------
+class TestCompileCacheLocation:
+    def test_env_var_leaves_the_location_to_jax(self):
+        from repro.launch.compile_cache import compilation_cache_dir
+
+        assert compilation_cache_dir(
+            {"JAX_COMPILATION_CACHE_DIR": "/elsewhere"}) is None
+
+    def test_default_is_the_checkouts_ignored_dir(self):
+        from repro.launch.compile_cache import (CHECKOUT_CACHE_DIR,
+                                                compilation_cache_dir)
+
+        root = Path(__file__).resolve().parents[1]
+        assert compilation_cache_dir({}) == CHECKOUT_CACHE_DIR \
+            == root / ".jax_cache"
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+
+    def test_cache_is_written_where_the_env_var_says(self, tmp_path):
+        import subprocess
+        import sys
+
+        code = ("from repro.launch.compile_cache import "
+                "enable_compilation_cache as e; print(e()); import jax; "
+                "jax.jit(lambda x: x * 3)(1.0).block_until_ready()")
+        env = child_env(1)
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+        env["PYTHONPATH"] = SRC
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, check=True)
+        assert out.stdout.strip() == str(tmp_path)
+        assert any(tmp_path.iterdir())
 
 
 # ----------------------------------------------------------------------

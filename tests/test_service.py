@@ -234,6 +234,27 @@ class TestServiceAccounting:
                 svc.submit({"workloads": []})
             assert ei.value.code == "bad-query"
 
+    def test_dispatcher_survives_a_failing_batch(self, monkeypatch):
+        """An exception outside a group's evaluation (here the jax
+        structure probe) fails that batch's tickets with a structured
+        error, and the dispatcher goes on to answer the next query."""
+        import repro.core.batched_jax as bj
+
+        def broken_probe(grid):
+            raise RuntimeError("structure probe exploded")
+
+        monkeypatch.setattr(bj, "jax_evaluator_cached", broken_probe)
+        spec = {"workloads": ["resnet50"], "workers": [4], "seed": 7}
+        with SweepService(window_s=0.0) as svc:
+            with pytest.raises(QueryError) as ei:
+                svc.query({**spec, "backend": "jax"}, timeout=60)
+            assert ei.value.code == "evaluation-failed"
+            assert "structure probe exploded" in str(ei.value)
+            res = svc.query(dict(spec), timeout=60)
+            snap = svc.stats_snapshot()
+        assert_tables_equal(res.table, reference(spec).columns)
+        assert snap["n_errors"] == 1 and snap["n_queries"] == 1
+
     def test_close_resolves_pending(self):
         svc = SweepService(window_s=0.0)
         svc.close()

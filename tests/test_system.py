@@ -126,8 +126,7 @@ def test_dryrun_machinery_on_cpu_mesh():
         shape = dataclasses.replace(TRAIN_4K, seq_len=32, global_batch=4)
         specs = S.input_specs(cfg, shape)
         step = S.make_train_step(cfg, opt, remat=True)
-        from repro.launch.mesh import activate_mesh
-        with activate_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             lowered = jax.jit(step, in_shardings=(pspecs, ospecs, None)) \
                 .lower(pshape, oshape, specs)
             compiled = lowered.compile()
