@@ -66,7 +66,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.core import analytical, batched, bucketsim
+from repro.core import analytical, batched, bucketsim, obs
 from repro.core.batched import grid_evaluator
 from repro.core.hardware import (hierarchical_allreduce_coeffs,
                                  ring_allreduce_coeffs,
@@ -312,6 +312,34 @@ def _columns_jax(tables: dict, pflags: dict, kcodes: dict, scodes: dict,
                        scodes["kidx"])
 
 
+def _host_columns(args: tuple, size: int) -> dict[str, np.ndarray]:
+    """One call of :func:`_columns_jax` on ``args``, as the first ``size``
+    rows of each numeric column in host float64 arrays.
+
+    The device path splits into three spans: ``sweep.columns.call``
+    (flattening the arguments, placing every host array on the device,
+    the launch), ``sweep.columns.wait`` (the device work the host cannot
+    hide, waited for only while the recorder is on) and
+    ``sweep.columns.fetch`` (the device-to-host copies; with the
+    recorder off they also do the waiting).  The counters
+    ``sweep.h2d_arrays``/``sweep.h2d_bytes`` count the NumPy leaves of
+    the arguments, each of which the call copies to the device."""
+    with obs.span("sweep.columns"), jax.enable_x64(True):
+        if obs.enabled():
+            host = [x for x in jax.tree_util.tree_leaves(args)
+                    if isinstance(x, np.ndarray)]
+            obs.count("sweep.h2d_arrays", len(host))
+            obs.count("sweep.h2d_bytes", sum(x.nbytes for x in host))
+        with obs.span("sweep.columns.call"):
+            out = _columns_jax(*args)
+        if obs.enabled():
+            with obs.span("sweep.columns.wait"):
+                jax.block_until_ready(out)
+        with obs.span("sweep.columns.fetch"):
+            return {k: np.asarray(v)[:size] for k, v in out.items()
+                    if k in _NUMERIC_COLS}
+
+
 # ----------------------------------------------------------------------
 # Sharding: pad the batch axes to a device-count multiple and place
 # the code vectors over the mesh's data axis.
@@ -405,15 +433,10 @@ class JaxGridEvaluator:
         S = len(self.ev)
         if S == 0:
             return {k: np.empty(0) for k in _NUMERIC_COLS}
-        with jax.enable_x64(True):
-            out = self._traced_columns(params)
-            return {k: np.asarray(v)[:S] for k, v in out.items()
-                    if k in _NUMERIC_COLS}
+        return _host_columns(self._args(params), S)
 
-    def _traced_columns(self, params: dict | None = None) -> dict:
-        """The jit call itself — kept separate so the differentiable
-        front end (:func:`iteration_time_fn`) can trace through it.
-        Callers are responsible for the ``jax.enable_x64(True)`` scope."""
+    def _args(self, params: dict | None = None) -> tuple:
+        """The kernel's arguments, ``params`` swapped into the tables."""
         tables = self._tables
         if params:
             unknown = set(params) - set(PARAM_KEYS)
@@ -421,9 +444,14 @@ class JaxGridEvaluator:
                 raise ValueError(f"unknown param keys {sorted(unknown)}; "
                                  f"differentiable params are {PARAM_KEYS}")
             tables = {**tables, **params}
-        return _columns_jax(tables, self._pflags, self._kcodes,
-                            self._scodes, self._ucodes, self._tl_overlaps,
-                            self._coll_codes)
+        return (tables, self._pflags, self._kcodes, self._scodes,
+                self._ucodes, self._tl_overlaps, self._coll_codes)
+
+    def _traced_columns(self, params: dict | None = None) -> dict:
+        """The jit call itself — kept separate so the differentiable
+        front end (:func:`iteration_time_fn`) can trace through it.
+        Callers are responsible for the ``jax.enable_x64(True)`` scope."""
+        return _columns_jax(*self._args(params))
 
     def run(self, params: dict | None = None, seed: int = 0) -> "JaxGridRun":
         """One evaluation: the jit kernel for the deterministic
@@ -505,24 +533,34 @@ _JAX_MEMO: dict = {}
 _MEMO_LIMIT = 64
 
 
+def _build(grid: ScenarioGrid, mesh=None) -> JaxGridEvaluator:
+    """A fresh :class:`JaxGridEvaluator`, under the ``sweep.build`` span
+    and counted in ``sweep.builds``."""
+    with obs.span("sweep.build"):
+        obs.count("sweep.builds")
+        return JaxGridEvaluator(grid, mesh=mesh)
+
+
 def jax_grid_evaluator(grid: ScenarioGrid, *, mesh=None) -> JaxGridEvaluator:
     """Memoized :class:`JaxGridEvaluator` (unsharded/auto mesh only —
     explicit meshes always build fresh)."""
     if mesh is not None:
-        return JaxGridEvaluator(grid, mesh=mesh)
+        return _build(grid, mesh)
     try:
         from repro.core.workloads import resolve_workload
         tables = tuple(resolve_workload(w) for w in grid.workloads)
         key = (grid, tuple(id(t) for t in tables))
         hash(key)
     except TypeError:
-        return JaxGridEvaluator(grid)
+        return _build(grid)
     hit = _JAX_MEMO.get(key)
     if hit is not None:
+        # a hit counts too, as no build: 0 builds differ from no counter
+        obs.count("sweep.builds", 0)
         return hit[0]
     if len(_JAX_MEMO) >= _MEMO_LIMIT:
         _JAX_MEMO.clear()
-    jev = JaxGridEvaluator(grid)
+    jev = _build(grid)
     _JAX_MEMO[key] = (jev, tables)
     return jev
 
@@ -580,11 +618,8 @@ def eval_scenarios_table_jax(
               "tmul": np.ones(len(uw)) if ut is None else ut}
     scodes = {"pi": polidx, "kidx": np.arange(S, dtype=np.int64)}
     coll_codes = tuple(int(x) for x in np.unique(coll)) or (0,)
-    with jax.enable_x64(True):
-        out = _columns_jax(tables, pflags, kcodes, scodes, ucodes,
-                           tl_overlaps, coll_codes)
-        cols = {k: np.asarray(v) for k, v in out.items()
-                if k in _NUMERIC_COLS}
+    cols = _host_columns((tables, pflags, kcodes, scodes, ucodes,
+                          tl_overlaps, coll_codes), S)
     batched._apply_mc_tails(wax, cax, pax, widx, cidx, coll, n, batch,
                             polidx, hks, wtab, bwmul, latmul, st_specs,
                             stidx, cols, seed, synck=synck,

@@ -66,7 +66,7 @@ from typing import Iterable, Iterator, Sequence
 
 import numpy as np
 
-from repro.core import analytical
+from repro.core import analytical, obs
 from repro.core import het as het_mod
 from repro.core.batched import grid_evaluator
 from repro.core.batched import eval_scenarios  # noqa: F401  (re-export)
@@ -705,31 +705,33 @@ def sweep(grid: ScenarioGrid | Iterable[Scenario], *,
     ``seed`` keys the straggler Monte Carlo draws; same grid + same
     seed reproduces the tail columns exactly on every backend.
     """
-    _check_backend(backend, batched=batched, force_simulator=force_simulator)
-    t0 = time.perf_counter()
-    grid_batched = isinstance(grid, ScenarioGrid) and batched \
-        and not force_simulator
-    if chunk is None:
-        if grid_batched and (jobs is None or jobs <= 1):
-            # one whole-grid chunk: a single table, no concat
-            chunk = max(len(grid), 1)
+    with obs.span("sweep"):
+        _check_backend(backend, batched=batched,
+                       force_simulator=force_simulator)
+        t0 = time.perf_counter()
+        grid_batched = isinstance(grid, ScenarioGrid) and batched \
+            and not force_simulator
+        if chunk is None:
+            if grid_batched and (jobs is None or jobs <= 1):
+                # one whole-grid chunk: a single table, no concat
+                chunk = max(len(grid), 1)
+            else:
+                chunk = DEFAULT_CHUNK
+        columns = concat_tables(list(iter_tables(
+            grid, force_simulator=force_simulator,
+            warm_iterations=warm_iterations, batched=batched,
+            backend=backend, chunk=chunk, jobs=jobs, seed=seed)))
+        elapsed = time.perf_counter() - t0
+        if grid_batched:
+            # static counts from the grid structure — no label scan
+            ev = grid_evaluator(grid)
+            n_fast, n_tl = ev.n_fast, ev.n_timeline
+            n_slow = 0 if backend == "jax" else len(ev) - n_fast - n_tl
         else:
-            chunk = DEFAULT_CHUNK
-    columns = concat_tables(list(iter_tables(
-        grid, force_simulator=force_simulator,
-        warm_iterations=warm_iterations, batched=batched,
-        backend=backend, chunk=chunk, jobs=jobs, seed=seed)))
-    elapsed = time.perf_counter() - t0
-    if grid_batched:
-        # static counts from the grid structure — no label scan
-        ev = grid_evaluator(grid)
-        n_fast, n_tl = ev.n_fast, ev.n_timeline
-        n_slow = 0 if backend == "jax" else len(ev) - n_fast - n_tl
-    else:
-        n_fast, n_tl, n_slow = method_counts(columns)
-    return SweepResult(columns=columns, elapsed_s=elapsed,
-                       n_analytical=n_fast, n_timeline=n_tl,
-                       n_simulated=n_slow, backend=backend)
+            n_fast, n_tl, n_slow = method_counts(columns)
+        return SweepResult(columns=columns, elapsed_s=elapsed,
+                           n_analytical=n_fast, n_timeline=n_tl,
+                           n_simulated=n_slow, backend=backend)
 
 
 def stream(grid: ScenarioGrid | Iterable[Scenario], *,
