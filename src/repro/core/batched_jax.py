@@ -15,9 +15,10 @@ whole code vectors (no ``vmap`` round trip, no per-point closures):
   ``where``/``maximum`` equation select over ``(S,)`` vectors;
 * the composition is one ``jit``-compiled function whose array inputs
   (axis tables, code vectors) are ordinary pytree arguments — same
-  shapes, same compilation, fresh numbers every call — and whose
-  output is exactly the numeric result columns, so ``backend="jax"``
-  end-to-end cost is the kernel plus host label gathers.
+  shapes, same compilation, fresh numbers every call — and whose one
+  output packs the numeric result columns into a single buffer, so
+  ``backend="jax"`` end-to-end cost is the kernel, one device-to-host
+  transfer and host label gathers.
 
 There is no parallel formula implementation to keep in lockstep: the
 affine coefficients come from the same dtype-polymorphic
@@ -298,46 +299,144 @@ def _select_jax(pflags: dict, tl_overlaps: tuple, kc: dict, pi, kidx):
     }
 
 
-@functools.partial(jax.jit, static_argnames=("tl_overlaps", "coll_codes"))
-def _columns_jax(tables: dict, pflags: dict, kcodes: dict, scodes: dict,
-                 ucodes: dict, tl_overlaps: tuple,
-                 coll_codes: tuple) -> dict:
-    """The whole two-tier evaluation — codes in, result columns out —
-    as one compiled function.  Compilation is keyed by array
-    shapes/dtypes and the static ``tl_overlaps``/``coll_codes``
-    tuples — re-running a grid (or any same-shaped grid) with fresh
-    numbers reuses the executable."""
+def _columns(tables: dict, pflags: dict, kcodes: dict, scodes: dict,
+             ucodes: dict, tl_overlaps: tuple, coll_codes: tuple) -> dict:
+    """The whole two-tier evaluation — codes in, the numeric result
+    columns out, as a dict of ``(S,)`` float64 arrays — traced inside
+    one of the two compiled functions below."""
     kc = _kernel_cols_jax(tables, kcodes, ucodes, tl_overlaps, coll_codes)
     return _select_jax(pflags, tl_overlaps, kc, scodes["pi"],
                        scodes["kidx"])
 
 
+#: :func:`_columns` compiled as it is, returning the dict: the function
+#: the differentiable front end (:func:`iteration_time_fn`) traces
+#: through.
+_columns_dict = jax.jit(_columns, static_argnames=("tl_overlaps",
+                                                   "coll_codes"))
+
+
+#: Elements in one tile of a float32 row on the TPU: each word row of
+#: the packed buffer starts on a tile, so no row is shifted to place it.
+_TILE = 1024
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("tl_overlaps", "coll_codes", "shards"))
+def _columns_jax(tables: dict, pflags: dict, kcodes: dict, scodes: dict,
+                 ucodes: dict, tl_overlaps: tuple, coll_codes: tuple,
+                 shards: int = 0):
+    """:func:`_columns` as one compiled function whose one output packs
+    the :data:`_NUMERIC_COLS` into a single buffer, so the host fetches
+    a sweep in one transfer (:func:`_host_columns`).  Compilation is
+    keyed by array shapes/dtypes and the static arguments — re-running a
+    grid (or any same-shaped grid) with fresh numbers reuses the
+    executable.
+
+    With ``shards == 0`` the buffer is the ``(6, S)`` float64 stack.
+    With ``shards = n`` (for a device that emulates float64, the TPU,
+    on ``n`` devices) it is the ``(n, 12 * m)`` float32 words of the
+    columns (:func:`_f32_words`): row ``d`` holds the ``d``-th of ``n``
+    equal blocks of the scenario axis, the block device ``d`` computes
+    on a mesh, as the six leading words and then the six remainders,
+    each padded to ``m``, a whole number of tiles.  So nothing moves
+    between devices, and no word row is laid out anew: stacking the
+    columns as rows of a 2-D float32 array cost 0.3 ms more of a
+    10 ms kernel on a TPU v5e."""
+    cols = _columns(tables, pflags, kcodes, scodes, ucodes, tl_overlaps,
+                    coll_codes)
+    if not shards:
+        return jnp.stack([cols[k] for k in _NUMERIC_COLS])
+    his, los = zip(*(_f32_words(cols[k]) for k in _NUMERIC_COLS))
+    rows = [w.reshape(shards, -1) for w in his + los]
+    pad = (-rows[0].shape[1]) % _TILE
+    return jnp.concatenate([jnp.pad(w, ((0, 0), (0, pad))) for w in rows],
+                           axis=1)
+
+
+def _f32_words(x) -> tuple:
+    """``x`` (float64) as the two float32 words of a float64 that the
+    device emulates as a pair of float32 (the TPU): the leading word and
+    the remainder, with a remainder of 0 beside a value that is not
+    finite.  :func:`_unpack` adds them back in float64.
+
+    On a TPU v5e the words added back give the very bits of the runtime's
+    own float64 copy to the host: for every column of the kernel checked
+    there, for +0 and the infinities.  The exceptions are tiny values,
+    where a word that counts is subnormal (below about 2**-72 in
+    magnitude; the device's float32 arithmetic flushes such words),
+    -0.0, which comes back as +0.0, and the payload of a NaN.  A
+    bit-cast to ``uint32`` would have no exceptions, but the TPU's
+    compiler has no emulation of a 64-bit bit-cast."""
+    hi = x.astype(jnp.float32)
+    lo = (x - hi.astype(jnp.float64)).astype(jnp.float32)
+    return hi, jnp.where(jnp.isfinite(hi), lo, 0.0)
+
+
+def _unpack(host: np.ndarray, length: int) -> np.ndarray:
+    """The ``(6, length)`` float64 columns of a fetched
+    :func:`_columns_jax` buffer over ``length`` scenarios: the float64
+    stack as it is, or the float32 words of its blocks added in float64
+    and the blocks put back in order."""
+    if host.dtype == np.float64:
+        return host
+    n, shards = len(_NUMERIC_COLS), host.shape[0]
+    m = length // shards
+    w = host.reshape(shards, 2 * n, -1)[:, :, :m]
+    cols = w[:, :n].astype(np.float64) + w[:, n:]
+    return cols.transpose(1, 0, 2).reshape(n, length)
+
+
+@functools.cache
+def _f64_words() -> bool:
+    """True where the default device emulates float64 as two float32
+    words (the TPU), so that :func:`_columns_jax` packs those words."""
+    return jax.devices()[0].platform == "tpu"
+
+
+def _shards(codes: np.ndarray | jax.Array) -> int:
+    """The devices the scenario axis is split over: one for host codes,
+    else those of the sharding :func:`_shard_codes` gave them."""
+    if isinstance(codes, jax.Array):
+        return len(codes.sharding.device_set)
+    return 1
+
+
 def _host_columns(args: tuple, size: int) -> dict[str, np.ndarray]:
     """One call of :func:`_columns_jax` on ``args``, as the first ``size``
-    rows of each numeric column in host float64 arrays.
+    rows of each numeric column in host float64 arrays: views of one
+    ``(6, S)`` host array, fetched in one transfer.
 
     The device path splits into three spans: ``sweep.columns.call``
     (flattening the arguments, placing every host array on the device,
-    the launch), ``sweep.columns.wait`` (the device work the host cannot
+    the launch, and the start of the copy of the packed output to the
+    host), ``sweep.columns.wait`` (the device work the host cannot
     hide, waited for only while the recorder is on) and
-    ``sweep.columns.fetch`` (the device-to-host copies; with the
-    recorder off they also do the waiting).  The counters
-    ``sweep.h2d_arrays``/``sweep.h2d_bytes`` count the NumPy leaves of
-    the arguments, each of which the call copies to the device."""
+    ``sweep.columns.fetch`` (the end of the copy and the columns taken
+    from it; with the recorder off it also does the waiting).  The
+    counters ``sweep.h2d_arrays``/``sweep.h2d_bytes`` count the NumPy
+    leaves of the arguments, each of which the call copies to the
+    device; ``sweep.d2h_arrays``/``sweep.d2h_bytes`` the device arrays
+    and bytes the fetch brings to the host."""
     with obs.span("sweep.columns"), jax.enable_x64(True):
         if obs.enabled():
             host = [x for x in jax.tree_util.tree_leaves(args)
                     if isinstance(x, np.ndarray)]
             obs.count("sweep.h2d_arrays", len(host))
             obs.count("sweep.h2d_bytes", sum(x.nbytes for x in host))
+        pi = args[3]["pi"]
+        shards = _shards(pi) if _f64_words() else 0
         with obs.span("sweep.columns.call"):
-            out = _columns_jax(*args)
+            out = _columns_jax(*args, shards=shards)
+            out.copy_to_host_async()
         if obs.enabled():
             with obs.span("sweep.columns.wait"):
-                jax.block_until_ready(out)
+                out.block_until_ready()
         with obs.span("sweep.columns.fetch"):
-            return {k: np.asarray(v)[:size] for k, v in out.items()
-                    if k in _NUMERIC_COLS}
+            cols = _unpack(np.asarray(out), len(pi))
+            obs.count("sweep.d2h_arrays")
+            obs.count("sweep.d2h_bytes", out.nbytes)
+            return {k: cols[i, :size] for i, k in enumerate(_NUMERIC_COLS)}
 
 
 # ----------------------------------------------------------------------
@@ -448,10 +547,11 @@ class JaxGridEvaluator:
                 self._ucodes, self._tl_overlaps, self._coll_codes)
 
     def _traced_columns(self, params: dict | None = None) -> dict:
-        """The jit call itself — kept separate so the differentiable
+        """The numeric columns as a dict of device arrays, from the
+        compiled :func:`_columns` — kept separate so the differentiable
         front end (:func:`iteration_time_fn`) can trace through it.
         Callers are responsible for the ``jax.enable_x64(True)`` scope."""
-        return _columns_jax(*self._args(params))
+        return _columns_dict(*self._args(params))
 
     def run(self, params: dict | None = None, seed: int = 0) -> "JaxGridRun":
         """One evaluation: the jit kernel for the deterministic

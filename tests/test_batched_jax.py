@@ -5,6 +5,11 @@ finite differences, sharded-mesh equivalence, and the explicit backend
 routing errors."""
 import dataclasses
 import json
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -223,6 +228,169 @@ class TestShardedMesh:
         assert set(a) == set(b)
         for k in a:
             np.testing.assert_array_equal(a[k], b[k], err_msg=k)
+
+
+MC_GRID = ScenarioGrid(workloads=("resnet50",), clusters=("v100-nvlink-ib",),
+                       worker_counts=(4, 16),
+                       policies=("tensorflow", "bucketed-4mb", "priority"),
+                       het_profiles=("het:1x0.5+3x1.0", "het:2x1.0@bw0.5"),
+                       stragglers=("none", "lognormal:0.2x100"),
+                       sync_ks=(0, 2))
+
+
+def _per_column(args: tuple, size: int) -> dict:
+    """The fetch as it was before the packed buffer: the dict-valued
+    kernel, one ``np.asarray`` copy per column."""
+    import jax
+
+    with jax.enable_x64(True):
+        out = BJ._columns_dict(*args)
+        return {k: np.asarray(out[k])[:size] for k in BJ._NUMERIC_COLS}
+
+
+def _assert_bits_equal(got: dict, want: dict):
+    """Every column equal; every float64 column bit for bit."""
+    assert got.keys() == want.keys()
+    assert {"iteration_time_s", "t_comm_s", "t_comp_s"} <= set(want)
+    for k in want:
+        a, b = np.asarray(got[k]), np.asarray(want[k])
+        assert a.shape == b.shape and a.dtype == b.dtype, k
+        if a.dtype == np.float64:
+            a, b = a.view(np.uint64), b.view(np.uint64)
+        assert np.array_equal(a, b), k
+
+
+def _words_of(cols: dict) -> dict:
+    """The columns as the float32 words of the TPU's packing give them
+    back, computed in NumPy: ``f64(hi) + f32(x - f64(hi))``."""
+    out = {}
+    for k, x in cols.items():
+        hi = x.astype(np.float32)
+        lo = (x - hi.astype(np.float64)).astype(np.float32)
+        out[k] = hi.astype(np.float64) + np.where(np.isfinite(hi), lo, 0)
+    return out
+
+
+SHARDED_SCRIPT = textwrap.dedent("""
+    import dataclasses, json
+    import jax
+    import numpy as np
+    from repro.core import batched_jax as BJ
+    from repro.core.scenarios import default_grid
+
+    def words_of(x):
+        hi = x.astype(np.float32)
+        lo = (x - hi.astype(np.float64)).astype(np.float32)
+        return hi.astype(np.float64) + np.where(np.isfinite(hi), lo, 0)
+
+    def mismatched(got, want):
+        return {k: int(np.sum(got[k].view(np.uint64)
+                              != want[k].view(np.uint64)))
+                for k in BJ._NUMERIC_COLS}
+
+    grid = dataclasses.replace(default_grid(), worker_counts=(2, 7, 16))
+    jev = BJ.JaxGridEvaluator(grid)
+    S = len(jev)
+    got = jev.columns()
+    with jax.enable_x64(True):
+        packed = BJ._columns_jax(*jev._args())
+        words = BJ._columns_jax(*jev._args(), shards=4)
+        ref = BJ._columns_dict(*jev._args())
+        want = {k: np.asarray(ref[k])[:S] for k in BJ._NUMERIC_COLS}
+    BJ._f64_words = lambda: True        # the TPU's packing, on the CPU
+    got_words = jev.columns()
+    print("RESULT " + json.dumps({
+        "devices": len(jax.devices()), "S": S,
+        "mesh": None if jev.mesh is None else int(jev.mesh.devices.size),
+        "packed_shape": list(packed.shape),
+        "packed_devices": len(packed.sharding.device_set),
+        "words_shape": list(words.shape),
+        "words_devices": len(words.sharding.device_set),
+        "contiguous": all(v.flags.c_contiguous for v in got.values()),
+        "mismatched": mismatched(got, want),
+        "words_mismatched": mismatched(
+            got_words, {k: words_of(v) for k, v in want.items()})}))
+""")
+
+
+class TestPackedFetch:
+    """The six numeric columns come back from the device in one packed
+    buffer, bit for bit what one ``np.asarray`` per column gave."""
+
+    @pytest.mark.parametrize("path", ["frontier", "monte_carlo",
+                                      "scenario_list"])
+    def test_packed_fetch_is_bit_identical_to_per_column_copies(
+            self, path, monkeypatch):
+        if path == "frontier":
+            jev = BJ.jax_grid_evaluator(frontier_grid())
+            got = jev.columns()
+            assert all(v.flags.c_contiguous for v in got.values())
+            _assert_bits_equal(got, _per_column(jev._args(), len(jev)))
+            return
+        if path == "monte_carlo":
+            def evaluate():
+                table, _ = BJ.jax_grid_evaluator(MC_GRID).run(seed=5) \
+                    .table_slice(0, len(MC_GRID))
+                return table
+        else:
+            scenarios = list(MC_GRID)[::3]
+
+            def evaluate():
+                return BJ.eval_scenarios_table_jax(scenarios, seed=5)
+        got = evaluate()
+        monkeypatch.setattr(BJ, "_host_columns", _per_column)
+        want = evaluate()
+        assert not np.array_equal(want["t_p99_s"], want["iteration_time_s"])
+        _assert_bits_equal(got, want)
+
+    def test_packed_fetch_is_bit_identical_on_four_sharded_devices(self):
+        src = str(Path(__file__).resolve().parents[1] / "src")
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src,
+                   XLA_FLAGS="--xla_force_host_platform_device_count=4")
+        r = subprocess.run([sys.executable, "-c", SHARDED_SCRIPT], env=env,
+                           capture_output=True, text=True, timeout=300)
+        assert r.returncode == 0, r.stderr[-3000:]
+        line = [x for x in r.stdout.splitlines() if x.startswith("RESULT ")]
+        got = json.loads(line[0][len("RESULT "):])
+        assert got["devices"] == got["mesh"] == got["packed_devices"] == 4
+        assert got["words_devices"] == 4
+        assert got["S"] % 4                                  # padded
+        padded = got["S"] + (-got["S"]) % 4
+        assert got["packed_shape"] == [len(BJ._NUMERIC_COLS), padded]
+        block = padded // 4 + (-(padded // 4)) % BJ._TILE
+        assert got["words_shape"] == [4, 2 * len(BJ._NUMERIC_COLS) * block]
+        assert got["contiguous"]
+        assert got["mismatched"] == {k: 0 for k in BJ._NUMERIC_COLS}
+        assert got["words_mismatched"] == {k: 0 for k in BJ._NUMERIC_COLS}
+
+    def test_the_tpu_word_packing_gives_back_the_columns_words(
+            self, monkeypatch):
+        """The TPU's packing, run on the CPU: its layout and unpacking
+        give back each column's two float32 words added in float64
+        (exact on the TPU, whose float64 is those words; on the CPU's
+        IEEE float64 the words drop the low bits)."""
+        jev = BJ.jax_grid_evaluator(frontier_grid())
+        want = _words_of(_per_column(jev._args(), len(jev)))
+        monkeypatch.setattr(BJ, "_f64_words", lambda: True)
+        got = jev.columns()
+        assert all(v.flags.c_contiguous for v in got.values())
+        _assert_bits_equal(got, want)
+
+    def test_float32_words_rebuild_every_value_a_pair_holds(self):
+        """The words form (the TPU's) gives back, exactly, any float64
+        whose significand fits two float32 words, and the infinities."""
+        import jax
+
+        r = np.random.default_rng(3).lognormal(0.0, 8.0, 4096)
+        m, e = np.frexp(r)
+        x = np.ldexp(np.round(np.ldexp(m, 48)), e - 48)      # 48 bits
+        x = np.concatenate([x, -x, [0.0, 1.0, np.inf, -np.inf]])
+        with jax.enable_x64(True):
+            hi, lo = (np.asarray(w) for w in jax.jit(BJ._f32_words)(x))
+        assert hi.dtype == lo.dtype == np.float32
+        assert np.all(lo[~np.isfinite(x)] == 0)
+        back = hi.astype(np.float64) + lo
+        assert np.array_equal(back.view(np.uint64), x.view(np.uint64))
 
 
 class TestBackendRouting:

@@ -66,8 +66,12 @@ def _compile(fn, *args):
 def test_frontier_sweep_kernel_f64(one_chip):
     """The fused two-tier kernel over the 51 840-scenario frontier grid,
     in float64 as the jax backend runs it (TPU f64 is emulated; this
-    pins that every op of the kernel has an emulation)."""
-    from repro.core.batched_jax import JaxGridEvaluator, _columns_jax
+    pins that every op of the kernel has an emulation).  Its one output
+    is the packed buffer the host fetches in one transfer: the two
+    float32 words of each of the six emulated float64 columns, each
+    padded to whole tiles of 1 024."""
+    from repro.core.batched_jax import (_NUMERIC_COLS, JaxGridEvaluator,
+                                        _columns_jax)
     from repro.core.scenarios import frontier_grid
 
     jev = JaxGridEvaluator(frontier_grid())
@@ -77,10 +81,44 @@ def test_frontier_sweep_kernel_f64(one_chip):
                           jev._scodes, jev._ucodes)]
         compiled = _columns_jax.lower(
             *args, tl_overlaps=jev._tl_overlaps,
-            coll_codes=jev._coll_codes).compile()
+            coll_codes=jev._coll_codes, shards=1).compile()
         out = compiled.out_info
-    assert out["iteration_time_s"].shape == (len(jev),)
-    assert out["iteration_time_s"].dtype == np.float64
+    assert isinstance(out, jax.ShapeDtypeStruct)
+    assert out.shape == (1, 2 * len(_NUMERIC_COLS) * 52_224)   # 51 tiles
+    assert out.dtype == np.float32
+    assert "f64[" in compiled.as_text()
+
+
+def test_frontier_sweep_kernel_packs_on_four_chips_in_place(topo):
+    """On a 2x2 mesh, the scenario axis sharded as ``JaxGridEvaluator``
+    shards it, each chip packs the words of its own block: no all-gather,
+    all-to-all or collective-permute moves the packed buffer."""
+    import re
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.core.batched_jax import (_NUMERIC_COLS, JaxGridEvaluator,
+                                        _columns_jax)
+    from repro.core.scenarios import frontier_grid
+
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
+    rep = NamedSharding(mesh, PartitionSpec())
+    split = NamedSharding(mesh, PartitionSpec("data"))
+    jev = JaxGridEvaluator(frontier_grid())
+    with jax.enable_x64(True):
+        args = [jax.tree_util.tree_map(lambda a: _spec(a, sh), t)
+                for t, sh in ((jev._tables, rep), (jev._pflags, rep),
+                              (jev._kcodes, split), (jev._scodes, split),
+                              (jev._ucodes, rep))]
+        compiled = _columns_jax.lower(
+            *args, tl_overlaps=jev._tl_overlaps,
+            coll_codes=jev._coll_codes, shards=4).compile()
+    out = compiled.out_info
+    assert out.shape == (4, 2 * len(_NUMERIC_COLS) * 13_312)  # 13 tiles
+    assert out.sharding.spec == PartitionSpec("data")
+    text = compiled.as_text()
+    for op in ("all-gather", "all-to-all", "collective-permute"):
+        assert not re.search(op + r"(-start)?\(", text), op
 
 
 def test_flash_attention_fwd_bwd_qwen15_4b(one_chip):

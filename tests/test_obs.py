@@ -165,6 +165,45 @@ def test_h2d_counters_are_the_numpy_leaves_of_the_call(recorder):
     assert counters["sweep.h2d_bytes"] == sum(x.nbytes for x in host)
 
 
+def test_d2h_counters_are_one_packed_buffer(recorder):
+    grid = _grid((4, 16))
+    sweep(grid, backend="jax")
+    counters = obs.snapshot()["counters"]
+    S = len(BJ.jax_grid_evaluator(grid))
+    assert counters["sweep.d2h_arrays"] == 1
+    assert counters["sweep.d2h_bytes"] == len(BJ._NUMERIC_COLS) * S * 8
+
+
+def test_traced_columns_stay_a_dict_of_the_six_columns():
+    jev = BJ.jax_grid_evaluator(_grid((2, 4)))
+    with jax.enable_x64(True):
+        traced = jev._traced_columns()
+        assert tuple(traced) == BJ._NUMERIC_COLS
+        host = {k: np.asarray(v) for k, v in traced.items()}
+    fetched = jev.columns()
+    for k, v in host.items():
+        assert v.shape == (len(jev),) and v.dtype == np.float64
+        assert np.array_equal(v.view(np.uint64),
+                              fetched[k].view(np.uint64)), k
+
+
+def test_grad_iteration_time_equals_the_gradient_through_the_packed_kernel():
+    grid = _grid((4, 8))
+    jev = BJ.jax_grid_evaluator(grid)
+    S = len(jev)
+    got = BJ.grad_iteration_time(grid)
+    row = BJ._NUMERIC_COLS.index("iteration_time_s")
+    with jax.enable_x64(True):
+        p = {k: jax.numpy.asarray(v)
+             for k, v in BJ.default_params(grid).items()}
+        want = jax.grad(
+            lambda q: BJ._columns_jax(*jev._args(q))[row, :S].sum())(p)
+    assert got.keys() == want.keys()
+    assert any(np.abs(got[k]).max() > 0 for k in ("intra_bw", "inter_bw"))
+    for k in got:
+        assert np.array_equal(got[k], np.asarray(want[k])), k
+
+
 def test_columns_are_bit_identical_with_the_recorder_on_and_off():
     grid = _grid((2, 64))
     obs.disable()
