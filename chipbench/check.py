@@ -1,31 +1,33 @@
 """The comparison that decides ``correct``.
 
 After the window has closed, every sampled row that the timed path
-returned is recomputed by the plain reference (:mod:`chipbench.reference`)
-from the request that produced it, and compared:
+returned is recomputed by the configuration's own plain reference and
+compared.  That reference is ``chipbench/references/<config>.py``, found
+by the configuration's name (``chipbench/run.py:resolve``), and exports
+``Reference(model, dtype)`` with ``.row(scenario)``,
+``scenario_at(axes, i)`` (the scenario at row ``i`` of a request),
+``LABEL_COLUMNS`` and ``NUMERIC_COLUMNS``.  This module imports no
+reference and names no configuration.  The numbers compared:
 
 * ``max_rel_err`` — the largest relative gap, over every sampled row and
-  every numeric column (the kernel's columns and the tail columns, which
-  without stragglers repeat the iteration time), between what the
-  program returned and the reference;
-* ``label_mismatches`` — sampled rows whose labels (workload, cluster,
-  workers, policy, collective, interconnect, het, straggler, sync_k,
-  faults, batch, method) differ from the scenario the reference puts at
-  that row: a wrong row order or a wrong demultiplexing shows here;
+  every numeric column (the reference's ``NUMERIC_COLUMNS``), between
+  what the program returned and the reference;
+* ``label_mismatches`` — sampled rows whose labels (the reference's
+  ``LABEL_COLUMNS``) differ from those of the scenario the reference
+  puts at that row: a wrong row order or a wrong demultiplexing shows
+  here;
 * ``failed_requests`` — requests that raised or never returned an
   answer;
 * ``rows_checked`` — at least one row has to be compared.
 
 The limit of ``max_rel_err`` is the configuration's ``check`` entry.
-With ``control=True`` the reference computed in float32 is put in the
-program's place, which a sound limit must reject.
+With ``control=True`` the same reference computed in float32,
+``Reference(model, np.float32)``, is put in the program's place, which a
+sound limit must reject.
 """
 from __future__ import annotations
 
 import numpy as np
-
-from chipbench.reference import (LABEL_COLUMNS, NUMERIC_COLUMNS, Reference,
-                                 scenario_at)
 
 
 def _rel(got: float, want: float) -> float:
@@ -36,23 +38,26 @@ def _rel(got: float, want: float) -> float:
     return abs(got - want) / abs(want)
 
 
-def compare(config: dict, records: list, control: bool = False) -> dict:
-    """``{name: {"value": v, "limit": l}}`` for every number compared."""
-    ref = Reference(config["model"], np.float64)
-    low = Reference(config["model"], np.float32) if control else None
+def compare(reference, config: dict, records: list,
+            control: bool = False) -> dict:
+    """``{name: {"value": v, "limit": l}}`` for every number compared;
+    ``reference`` is the configuration's reference module."""
+    ref = reference.Reference(config["model"], np.float64)
+    low = reference.Reference(config["model"], np.float32) \
+        if control else None
     worst, mismatches, rows = 0.0, 0, 0
     for rec in records:
         if rec.error is not None:
             continue
         for i, got in sorted(rec.rows.items()):
-            s = scenario_at(rec.request.axes, i)
+            s = reference.scenario_at(rec.request.axes, i)
             want = ref.row(s)
             if low is not None:
                 got = low.row(s)
             rows += 1
-            if any(got.get(c) != want[c] for c in LABEL_COLUMNS):
+            if any(got.get(c) != want[c] for c in reference.LABEL_COLUMNS):
                 mismatches += 1
-            for c in NUMERIC_COLUMNS:
+            for c in reference.NUMERIC_COLUMNS:
                 worst = max(worst, _rel(float(got[c]), want[c]))
     failed = sum(rec.error is not None for rec in records)
     return {

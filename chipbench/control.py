@@ -3,8 +3,9 @@
     python3 chipbench/control.py --workload frontier.sweep --seeds 1,2,3 --seconds 5
 
 For each seed it runs a short window of the cell at its own load and
-compares the sampled answers three ways: the program (the lower reading
-of the limit), the float32 reference put in the program's place (the
+compares the sampled answers with the configuration's own reference
+three ways: the program (the lower reading of the limit), that
+reference computed in float32 and put in the program's place (the
 control, whose smallest reading is the upper one), and, with
 ``--faults``, the program with each fault of :mod:`chipbench.faults`
 planted.  The benchmark's own runs never run this; its readings are
@@ -22,7 +23,7 @@ if sys.path and Path(sys.path[0]).resolve() == ROOT / "chipbench":
     sys.path.pop(0)
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from chipbench import check, faults, load, run  # noqa: E402
+from chipbench import check, faults, run  # noqa: E402
 
 
 def readings(workload: str, seeds: list[int], seconds: float,
@@ -40,7 +41,7 @@ def readings(workload: str, seeds: list[int], seconds: float,
         row = {"seed": seed, "device": device}
         for label in ("program", *planted):
             undo = faults.plant(label) if label != "program" else None
-            driver = load.DRIVERS[traffic["mode"]](config, traffic, seed)
+            driver = spec["driver"].Driver(config, traffic, seed)
             try:
                 driver.setup()
                 records, _ = driver.window(seconds)
@@ -48,10 +49,11 @@ def readings(workload: str, seeds: list[int], seconds: float,
                 driver.close()
                 if undo is not None:
                     undo()
-            got = check.compare(config, records)
+            got = check.compare(spec["reference"], config, records)
             row[label] = {k: v["value"] for k, v in got.items()}
             if label == "program":
-                low = check.compare(config, records, control=True)
+                low = check.compare(spec["reference"], config, records,
+                                    control=True)
                 row["control"] = {k: v["value"] for k, v in low.items()}
         print(json.dumps(row), flush=True)
         out.append(row)

@@ -1,27 +1,28 @@
-"""The one traffic generator and its driver.
+"""What every traffic driver shares.
 
-A traffic mix is a JSON file of parameters; ``mode`` picks the driver
-(``DRIVERS``):
+A traffic mix is a JSON file of parameters, ``traffic/<traffic>.json``;
+its ``mode`` names its driver, ``chipbench/drivers/<mode>.py``, found by
+that name (``chipbench/run.py:resolve``).  A driver module exports
 
-* ``"sweep"`` — one planner runs whole-grid ``sweep(grid, backend="jax")``
-  calls back to back.  With ``link_factors`` every sweep is a new
-  frontier: each factor axis is drawn log-uniform in ``[low, high]`` at
-  ``digits`` significant digits, distinct within the axis, so the grid
-  keeps its shape and only its numbers change.
+* ``Driver(config, traffic, seed)`` with ``setup()`` (warm every shape
+  the window uses), ``one(index, record)`` (one request, its sampled
+  answers kept in ``record.rows``), ``window(seconds, annotate)``
+  (``(records, window_s)``) and ``close()``;
+* ``request(config, traffic, seed, stream, index)``: request ``index``
+  of ``stream`` of the mix under ``seed``, a :class:`Request`.
 
-Every draw comes from ``numpy.random.default_rng([seed, stream, index])``,
-so one seed always gives the same requests and the same sampled rows.
+This module names no configuration and no mode.  Every draw comes from
+``numpy.random.default_rng([seed, stream, index])`` (:func:`_rng`), so
+one seed always gives the same requests and the same sampled rows.
 """
 from __future__ import annotations
 
 import math
 import time
 from contextlib import nullcontext
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, fields
 
 import numpy as np
-
-from chipbench.reference import grid_size, interconnect_label
 
 #: rng streams: warm-up requests, window requests, sampled rows.
 WARM, WINDOW, ROWS = 1, 0, 2
@@ -30,8 +31,7 @@ WARM, WINDOW, ROWS = 1, 0, 2
 @dataclass
 class Request:
     """One request of the mix: grid axes in the reference's form (the
-    configuration's ``axis_order`` plus a concrete ``interconnects``
-    list)."""
+    configuration's ``axis_order`` and one list per axis named there)."""
 
     axes: dict
 
@@ -53,6 +53,16 @@ class Record:
     @property
     def latency_s(self) -> float:
         return self.t1 - self.t0
+
+
+def grid_size(axes: dict) -> int:
+    return math.prod(len(axes[name]) for name in axes["axis_order"])
+
+
+def interconnect_label(base: str, bw: float, lat: float) -> str:
+    """The scaled-link spelling the program accepts and echoes:
+    ``<base>@bw<F>@lat<F>``."""
+    return f"{base}@bw{bw:g}@lat{lat:g}"
 
 
 def _rng(seed: int, stream: int, index: int) -> np.random.Generator:
@@ -87,92 +97,26 @@ def concrete_axes(grid: dict, factors: dict | None = None) -> dict:
     return axes
 
 
-def _fresh_factors(rng, traffic: dict) -> dict:
-    spec = traffic["link_factors"]
-    return {name: draw_factors(rng, spec[name], spec["digits"])
-            for name in ("bw_factors", "lat_factors")}
-
-
-def request(config: dict, traffic: dict, seed: int, stream: int,
-            index: int) -> Request:
-    """Request ``index`` of ``stream`` of the mix under ``seed``."""
-    rng = _rng(seed, stream, index)
-    factors = _fresh_factors(rng, traffic) \
-        if "link_factors" in traffic else None
-    return Request(concrete_axes(config["grid"], factors))
-
-
 def sample_rows(n: int, k: int, seed: int, index: int) -> np.ndarray:
     """The rows of request ``index`` whose answers are checked."""
     return np.sort(_rng(seed, ROWS, index).choice(n, min(k, n),
                                                   replace=False))
 
 
-# ----------------------------------------------------------------------
-# Program adapters: a request as the program takes it.
-# ----------------------------------------------------------------------
 def scenario_grid(axes: dict):
-    """The program's ``ScenarioGrid`` for a request."""
+    """The program's ``ScenarioGrid`` for a request: every axis named in
+    ``axes["axis_order"]`` is passed under that name.  A name that is not
+    an axis of ``ScenarioGrid`` raises, so none is dropped unseen."""
     from repro.core.scenarios import ScenarioGrid
 
-    return ScenarioGrid(workloads=tuple(axes["workloads"]),
-                        clusters=tuple(axes["clusters"]),
-                        worker_counts=tuple(axes["worker_counts"]),
-                        policies=tuple(axes["policies"]),
-                        collectives=tuple(axes["collectives"]),
-                        interconnects=tuple(axes["interconnects"]),
-                        het_profiles=tuple(axes["het_profiles"]),
-                        stragglers=tuple(axes["stragglers"]))
-
-
-# ----------------------------------------------------------------------
-# Drivers.
-# ----------------------------------------------------------------------
-class SweepDriver:
-    """Closed-loop whole-grid sweeps through ``sweep(backend="jax")``."""
-
-    def __init__(self, config: dict, traffic: dict, seed: int):
-        self.config, self.traffic, self.seed = config, traffic, seed
-
-    def _call(self, req: Request) -> dict:
-        from repro.core.sweep import sweep
-
-        return sweep(scenario_grid(req.axes), backend="jax").columns
-
-    def setup(self) -> None:
-        for k in range(self.traffic["warmup_requests"]):
-            self._call(request(self.config, self.traffic, self.seed, WARM, k))
-
-    def one(self, index: int, rec: Record) -> None:
-        cols = self._call(rec.request)
-        rec.t1 = time.perf_counter()
-        for i in sample_rows(rec.request.size,
-                             self.traffic["rows_checked_per_request"],
-                             self.seed, index):
-            rec.rows[int(i)] = {c: v[i].item() if hasattr(v[i], "item")
-                                else v[i] for c, v in cols.items()}
-
-    def window(self, seconds: float, annotate=None):
-        """Closed loop for ``seconds``: each sweep starts when the previous
-        one has returned; the window ends with the sweep that crosses
-        ``seconds``."""
-        records = []
-        t_start = time.perf_counter()
-        index = 0
-        while True:
-            req = request(self.config, self.traffic, self.seed, WINDOW, index)
-            rec = Record(req, t0=time.perf_counter())
-            _attempt(self, index, rec, annotate)
-            records.append(rec)
-            index += 1
-            if rec.t1 - t_start >= seconds:
-                return records, rec.t1 - t_start
-
-    def close(self) -> None:
-        pass
-
-
-DRIVERS = {"sweep": SweepDriver}
+    known = {f.name for f in fields(ScenarioGrid)
+             if isinstance(f.default, tuple)}
+    unknown = [name for name in axes["axis_order"] if name not in known]
+    if unknown:
+        raise ValueError(f"ScenarioGrid has no axis {unknown}; "
+                         f"its axes: {sorted(known)}")
+    return ScenarioGrid(**{name: tuple(axes[name])
+                           for name in axes["axis_order"]})
 
 
 def _attempt(driver, index: int, rec: Record, annotate) -> None:

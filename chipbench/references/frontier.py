@@ -1,4 +1,11 @@
-"""Plain reference of the DAG model of S-SGD, one scenario at a time.
+"""Plain reference of the DAG model of S-SGD, one scenario at a time:
+the reference of the configuration ``frontier``.
+
+A configuration's reference is ``chipbench/references/<config>.py``,
+found by the configuration's name (``chipbench/run.py:resolve``), and
+exports the four names :func:`chipbench.check.compare` uses:
+``Reference(model, dtype)`` with ``.row(scenario)``,
+``scenario_at(axes, i)``, ``LABEL_COLUMNS`` and ``NUMERIC_COLUMNS``.
 
 Written from the paper (arXiv:1805.03812, Eqs. 1-6 and the Fig. 1 DAG)
 and the configuration files under ``chipbench/configs``; it imports
@@ -99,12 +106,6 @@ def layer_table(spec: dict) -> tuple[list[float], list[int]]:
 # ----------------------------------------------------------------------
 # Scenario grid: the row order of a sweep's result.
 # ----------------------------------------------------------------------
-def interconnect_label(base: str, bw: float, lat: float) -> str:
-    """The scaled-link spelling the program accepts and echoes:
-    ``<base>@bw<F>@lat<F>``."""
-    return f"{base}@bw{bw:g}@lat{lat:g}"
-
-
 def scenario_at(axes: dict, i: int) -> dict:
     """The scenario at row ``i`` of a grid whose axes are given in
     ``axes["axis_order"]`` order, the last axis varying fastest."""
@@ -121,10 +122,6 @@ def scenario_at(axes: dict, i: int) -> dict:
             "policy": picks["policies"], "collective": picks["collectives"],
             "interconnect": picks["interconnects"],
             "het": picks["het_profiles"], "straggler": picks["stragglers"]}
-
-
-def grid_size(axes: dict) -> int:
-    return math.prod(len(axes[name]) for name in axes["axis_order"])
 
 
 # ----------------------------------------------------------------------

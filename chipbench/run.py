@@ -3,8 +3,21 @@
     python3 chipbench/run.py --workload frontier.sweep --seed 7 --seconds 20 --trace 0
 
 The cell, its configuration, its traffic mix and its metrics are found
-by name from ``BENCHMARK.json`` at the checkout's root.  One process
-holds the chip(s) and starts no child that touches JAX.  The run
+by name from ``BENCHMARK.json`` at the checkout's root, and so is the
+code that belongs to one configuration or one mix (:func:`resolve`):
+
+* ``chipbench/references/<config>.py``, the configuration's plain
+  reference, exporting ``Reference(model, dtype)`` with ``.row``,
+  ``scenario_at``, ``LABEL_COLUMNS`` and ``NUMERIC_COLUMNS``
+  (:mod:`chipbench.check`);
+* ``chipbench/drivers/<mode>.py``, the driver of the mix's ``mode``,
+  exporting ``Driver(config, traffic, seed)`` with ``setup``, ``one``,
+  ``window`` and ``close``, and ``request`` (:mod:`chipbench.load`);
+* ``chipbench/metrics/<metric>.py``, each metric's reader, exporting
+  ``read(run_view)``.
+
+One process holds the chip(s) and starts no child that touches JAX.
+The run
 
 1. refuses to run without a TPU or with fewer chips than the cell asks
    for, and names platform, ``device_kind`` and count;
@@ -13,7 +26,8 @@ holds the chip(s) and starts no child that touches JAX.  The run
 3. builds the cell's traffic and warms every shape the window uses;
 4. measures a closed loop for ``--seconds``, counting compilations inside
    the window (there should be none);
-5. compares sampled answers of the window with the plain reference, and
+5. compares sampled answers of the window with the configuration's
+   plain reference, and
    prints one JSON line last: ``correct``, ``attempted``, ``failed``,
    ``metrics``, ``device`` (and ``breakdown`` with ``--trace 1``), then
    ``checks``, each compared number beside its limit.
@@ -48,7 +62,7 @@ if sys.path and Path(sys.path[0]).resolve() == ROOT / BENCH:
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from chipbench import check, load, trace  # noqa: E402
+from chipbench import check, trace  # noqa: E402
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
@@ -70,9 +84,22 @@ def load_json(path: Path) -> dict:
         return json.load(f)
 
 
+def code(root: Path, kind: str, name: str):
+    """The module ``<root>/chipbench/<kind>/<name>.py``: a metric's
+    reader, a configuration's reference or a traffic mode's driver."""
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_{kind}_{name.replace('.', '_')}",
+        root / BENCH / kind / f"{name}.py")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 def resolve(root: Path, workload: str) -> dict:
     """The cell ``workload`` of ``<root>/BENCHMARK.json`` with its
-    configuration, traffic mix and metric entries."""
+    configuration, traffic mix and metric entries, its configuration's
+    reference module (``<root>/chipbench/references/<config>.py``) and
+    its mix's driver module (``<root>/chipbench/drivers/<mode>.py``)."""
     bench = load_json(root / "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
@@ -83,10 +110,14 @@ def resolve(root: Path, workload: str) -> dict:
     def applies(metric):
         return workload in metric.get("workloads", [workload])
 
+    config = load_json(root / BENCH / "configs" / f"{cell['config']}.json")
+    traffic = load_json(root / BENCH / "traffic" / f"{cell['traffic']}.json")
     return {
         "cell": cell,
-        "config": load_json(root / BENCH / "configs" / f"{cell['config']}.json"),
-        "traffic": load_json(root / BENCH / "traffic" / f"{cell['traffic']}.json"),
+        "config": config,
+        "traffic": traffic,
+        "reference": code(root, "references", cell["config"]),
+        "driver": code(root, "drivers", traffic["mode"]),
         "end_to_end": [m for m in bench["end_to_end"] if applies(m)],
         "per_layer": [m for m in bench["per_layer"] if applies(m)],
     }
@@ -94,12 +125,7 @@ def resolve(root: Path, workload: str) -> dict:
 
 def reader(root: Path, name: str):
     """The module ``<root>/chipbench/metrics/<name>.py``."""
-    path = root / BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_')}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+    return code(root, "metrics", name)
 
 
 # ----------------------------------------------------------------------
@@ -218,7 +244,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
 
     info(f"compile cache: {enable_compilation_cache()}")
     counter = CompileCounter()
-    driver = load.DRIVERS[traffic["mode"]](config, traffic, seed)
+    driver = spec["driver"].Driver(config, traffic, seed)
     timers = trace.HostTimers(jax.profiler.TraceAnnotation
                               if traced else None)
     try:
@@ -292,7 +318,8 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
     t_check = time.perf_counter()
-    checks = check.compare(config, records, control=control)
+    checks = check.compare(spec["reference"], config, records,
+                           control=control)
     checks["compiles_in_window"] = {"value": in_window, "limit": 0}
     correct = check.passed(checks)
     info(f"check: {checks['rows_checked']['value']} rows against the "

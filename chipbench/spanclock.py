@@ -147,8 +147,7 @@ def record(workload: str, seed: int, seconds: float | None = None,
 
     device = run.device_info(jax, spec["cell"]["chips"], require_tpu)
     enable_compilation_cache()
-    driver = load.DRIVERS[spec["traffic"]["mode"]](
-        spec["config"], spec["traffic"], seed)
+    driver = spec["driver"].Driver(spec["config"], spec["traffic"], seed)
     trace_dir = tempfile.mkdtemp(prefix="spanclock-")
     try:
         driver.setup()
@@ -164,8 +163,8 @@ def record(workload: str, seed: int, seconds: float | None = None,
             else:
                 records = []
                 for i in range(sweeps):
-                    req = load.request(spec["config"], spec["traffic"],
-                                       seed, load.WINDOW, i)
+                    req = spec["driver"].request(
+                        spec["config"], spec["traffic"], seed, load.WINDOW, i)
                     rec = load.Record(req, t0=time.perf_counter())
                     with jax.profiler.TraceAnnotation(recorder.REQUEST):
                         driver.one(i, rec)

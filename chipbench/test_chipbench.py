@@ -8,6 +8,7 @@ holds, and with the persistent compile cache left off.
 from __future__ import annotations
 
 import json
+import re
 import shutil
 import sys
 from pathlib import Path
@@ -54,10 +55,12 @@ def read(run):
 @pytest.fixture
 def tiny_root(tmp_path, monkeypatch):
     """A checkout-shaped directory holding the real benchmark plus a new
-    cell, a new configuration, a new traffic mix and a new per-layer
-    metric."""
-    for sub in ("configs", "traffic", "metrics"):
+    cell, a new configuration with its own reference (a copy of
+    frontier's), a new traffic mix and a new per-layer metric."""
+    for sub in ("configs", "references", "traffic", "drivers", "metrics"):
         shutil.copytree(ROOT / "chipbench" / sub, tmp_path / "chipbench" / sub)
+    shutil.copy(ROOT / "chipbench/references/frontier.py",
+                tmp_path / "chipbench/references/tiny.py")
     frontier = json.loads(
         (ROOT / "chipbench/configs/frontier.json").read_text())
     axis_order = frontier["grid"]["axis_order"]
@@ -97,6 +100,52 @@ def _run(root, cell, seed=2_147_483_659, seconds=0.5, traced=False,
                         require_tpu=False, control=control)
 
 
+def _add_cell(root, cell: dict) -> None:
+    """Append ``cell`` to ``<root>/BENCHMARK.json``, reporting every
+    end-to-end metric that names its cells."""
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["workloads"].append(cell)
+    for m in bench["end_to_end"]:
+        if "workloads" in m:
+            m["workloads"].append(cell["name"])
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+
+#: A reference that puts every iteration time one part in 10**6 high.
+PERTURBED = '''
+
+_row = Reference.row
+
+
+def _perturbed(self, s):
+    row = _row(self, s)
+    return {**row, "iteration_time_s": row["iteration_time_s"] * (1 + 1e-6)}
+
+
+Reference.row = _perturbed
+'''
+
+#: The driver of a new traffic mode: the sweeps of mode ``sweep``, a
+#: fixed number of them however long they take.
+COUNTED_DRIVER = '''"""Traffic mode ``counted``: ``requests`` closed-loop sweeps."""
+import time
+
+from chipbench.drivers.sweep import Driver as Sweeps, request
+from chipbench.load import WINDOW, Record, _attempt
+
+
+class Driver(Sweeps):
+    def window(self, seconds, annotate=None):
+        records, t_start = [], time.perf_counter()
+        for index in range(self.traffic["requests"]):
+            req = request(self.config, self.traffic, self.seed, WINDOW, index)
+            rec = Record(req, t0=time.perf_counter())
+            _attempt(self, index, rec, annotate)
+            records.append(rec)
+        return records, records[-1].t1 - t_start
+'''
+
+
 # ----------------------------------------------------------------------
 # Discovery by name.
 # ----------------------------------------------------------------------
@@ -104,7 +153,15 @@ def _run(root, cell, seed=2_147_483_659, seconds=0.5, traced=False,
 def test_every_cell_resolves_by_name(cell):
     spec = run.resolve(ROOT, cell)
     assert spec["config"]["name"] == spec["cell"]["config"]
-    assert spec["traffic"]["mode"] in load.DRIVERS
+    assert Path(spec["reference"].__file__) == (
+        ROOT / "chipbench/references" / f"{spec['cell']['config']}.py")
+    for name in ("Reference", "scenario_at", "LABEL_COLUMNS",
+                 "NUMERIC_COLUMNS"):
+        assert hasattr(spec["reference"], name)
+    assert Path(spec["driver"].__file__) == (
+        ROOT / "chipbench/drivers" / f"{spec['traffic']['mode']}.py")
+    assert callable(spec["driver"].Driver)
+    assert callable(spec["driver"].request)
     names = [m["name"] for m in spec["end_to_end"] + spec["per_layer"]]
     assert "setup_s" in names
     for name in names:
@@ -131,6 +188,75 @@ def test_small_cells_are_correct_end_to_end(tiny_root, seed):
     assert res["metrics"][metric]["value"] > 0
     assert res["metrics"]["setup_s"]["value"] > 0
     assert res["checks"]["max_rel_err"]["value"] <= 1e-13
+
+
+# ----------------------------------------------------------------------
+# A configuration's own reference, a mode's own driver, the grid's axes.
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("perturbed", [False, True])
+def test_configuration_brings_its_own_reference(tiny_root, perturbed):
+    path = tiny_root / "chipbench/references/tiny.py"
+    if perturbed:
+        path.write_text(path.read_text() + PERTURBED)
+    assert Path(run.resolve(tiny_root, "tiny.sweep")["reference"].__file__) \
+        == path
+    res = _run(tiny_root, "tiny.sweep")
+    err = res["checks"]["max_rel_err"]
+    assert res["checks"]["label_mismatches"]["value"] == 0
+    if perturbed:
+        assert not res["correct"]
+        assert 0.5e-6 < err["value"] < 2e-6 and err["value"] > err["limit"]
+    else:
+        assert res["correct"], res["checks"]
+        assert err["value"] <= 1e-13
+
+
+def test_missing_reference_fails_in_resolve_and_names_the_file(tiny_root):
+    path = tiny_root / "chipbench/references/tiny.py"
+    path.unlink()
+    with pytest.raises(FileNotFoundError, match=re.escape(str(path))):
+        run.resolve(tiny_root, "tiny.sweep")
+
+
+def test_driver_of_a_new_mode_is_found_and_run(tiny_root):
+    (tiny_root / "chipbench/drivers/counted.py").write_text(COUNTED_DRIVER)
+    traffic = json.loads(
+        (tiny_root / "chipbench/traffic/tiny_sweeps.json").read_text())
+    traffic.update(mode="counted", requests=3)
+    (tiny_root / "chipbench/traffic/tiny_counted.json").write_text(
+        json.dumps(traffic))
+    _add_cell(tiny_root, {"name": "tiny.counted", "config": "tiny",
+                          "traffic": "tiny_counted", "chips": 1, "why": "t"})
+    driver = run.resolve(tiny_root, "tiny.counted")["driver"]
+    assert Path(driver.__file__) == tiny_root / "chipbench/drivers/counted.py"
+    res = _run(tiny_root, "tiny.counted", seconds=60.0)
+    assert res["correct"], res["checks"]
+    assert res["attempted"] == 3 and res["failed"] == 0
+    assert res["checks"]["rows_checked"]["value"] == 3 * 4
+    assert res["metrics"]["scenarios_per_s"]["value"] > 0
+
+
+def test_scenario_grid_passes_every_named_axis():
+    from repro.core.scenarios import ScenarioGrid
+
+    axes = {"axis_order": ["workloads", "worker_counts", "sync_ks",
+                           "faults"],
+            "workloads": ["resnet50"], "worker_counts": [4, 8],
+            "sync_ks": [None, 2], "faults": [None, "fail:0.01@restart2.5"]}
+    grid = load.scenario_grid(axes)
+    assert grid == ScenarioGrid(workloads=("resnet50",), worker_counts=(4, 8),
+                                sync_ks=(None, 2),
+                                faults=(None, "fail:0.01@restart2.5"))
+    assert len(grid) == 2 * 2 * 2 * len(ScenarioGrid().clusters) \
+        * len(ScenarioGrid().policies)
+
+
+@pytest.mark.parametrize("axis", ["ep_degrees", "batch_per_gpu"])
+def test_scenario_grid_refuses_a_name_that_is_no_axis(axis):
+    axes = {"axis_order": ["workloads", axis], "workloads": ["resnet50"],
+            axis: [2]}
+    with pytest.raises(ValueError, match=axis):
+        load.scenario_grid(axes)
 
 
 # ----------------------------------------------------------------------
@@ -169,9 +295,10 @@ def test_refuses_to_run_without_a_tpu(capsys):
 def test_traffic_is_fixed_by_the_seed(cell):
     spec = run.resolve(ROOT, cell)
     config, traffic = spec["config"], spec["traffic"]
+    request = spec["driver"].request
 
     def mix(seed):
-        reqs = [load.request(config, traffic, seed, load.WINDOW, i)
+        reqs = [request(config, traffic, seed, load.WINDOW, i)
                 for i in range(40)]
         rows = [load.sample_rows(r.size, 4, seed, i).tolist()
                 for i, r in enumerate(reqs)]
@@ -180,7 +307,7 @@ def test_traffic_is_fixed_by_the_seed(cell):
     big = 3_000_000_019
     assert mix(big) == mix(big)
     assert mix(big) != mix(big + 1)
-    sizes = {load.request(config, traffic, s, load.WINDOW, i).size
+    sizes = {request(config, traffic, s, load.WINDOW, i).size
              for s in (1, big) for i in range(40)}
     assert len(sizes) == 1            # every seed: the same sizes
 
@@ -209,46 +336,48 @@ def test_frontier_config_is_frontier_grid_at_published_factors():
 # ----------------------------------------------------------------------
 # The reference.
 # ----------------------------------------------------------------------
+def _frontier_reference():
+    return run.code(ROOT, "references", "frontier")
+
+
 def test_reference_matches_the_numpy_engine_on_the_frontier():
-    from chipbench.reference import (LABEL_COLUMNS, NUMERIC_COLUMNS,
-                                     Reference, scenario_at)
     from repro.core.sweep import sweep
 
+    reference = _frontier_reference()
     config = run.load_json(ROOT / "chipbench/configs/frontier.json")
     axes = load.concrete_axes(config["grid"])
     res = sweep(load.scenario_grid(axes), backend="numpy")
-    ref = Reference(config["model"])
+    ref = reference.Reference(config["model"])
     for i in np.random.default_rng(0).choice(len(res), 120, replace=False):
-        want = ref.row(scenario_at(axes, int(i)))
-        for c in LABEL_COLUMNS:
+        want = ref.row(reference.scenario_at(axes, int(i)))
+        for c in reference.LABEL_COLUMNS:
             assert res.columns[c][i] == want[c]
-        for c in NUMERIC_COLUMNS:
+        for c in reference.NUMERIC_COLUMNS:
             assert res.columns[c][i] == pytest.approx(want[c], rel=1e-13)
 
 
 @pytest.mark.parametrize("het,straggler", [("het:1x0.5+3x1.0", None),
                                            (None, "lognormal:0.2")])
 def test_reference_refuses_what_it_does_not_model(het, straggler):
-    from chipbench.reference import Reference, scenario_at
-
+    reference = _frontier_reference()
     config = run.load_json(ROOT / "chipbench/configs/frontier.json")
     axes = {**load.concrete_axes(config["grid"]), "het_profiles": [het],
             "stragglers": [straggler]}
     with pytest.raises(ValueError, match="not modelled"):
-        Reference(config["model"]).row(scenario_at(axes, 0))
+        reference.Reference(config["model"]).row(
+            reference.scenario_at(axes, 0))
 
 
 def test_check_counts_label_mismatches_and_failures():
-    config = run.load_json(ROOT / "chipbench/configs/frontier.json")
-    from chipbench.reference import Reference, scenario_at
-
-    req = load.request(config, {"mode": "sweep"}, 1, load.WINDOW, 0)
-    ref = Reference(config["model"])
-    good = ref.row(scenario_at(req.axes, 5))
+    spec = run.resolve(ROOT, "frontier.sweep")
+    config, reference = spec["config"], spec["reference"]
+    req = spec["driver"].request(config, {"mode": "sweep"}, 1, load.WINDOW, 0)
+    ref = reference.Reference(config["model"])
+    good = ref.row(reference.scenario_at(req.axes, 5))
     rec = load.Record(req, t0=0.0, t1=1.0, rows={5: dict(good),
                                                  6: dict(good)})
     failed = load.Record(req, t0=0.0, t1=1.0, error="boom")
-    got = check.compare(config, [rec, failed])
+    got = check.compare(reference, config, [rec, failed])
     assert got["label_mismatches"]["value"] == 1      # row 6 is not row 5
     assert got["failed_requests"]["value"] == 1
     assert got["rows_checked"]["value"] == 2
