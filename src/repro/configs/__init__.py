@@ -17,6 +17,7 @@ _MODULES = {
     "recurrentgemma-2b": "repro.configs.recurrentgemma_2b",
     "llama-3.2-vision-90b": "repro.configs.llama32_vision_90b",
     "qwen1.5-32b": "repro.configs.qwen15_32b",
+    "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
 }
 
 ARCH_IDS = tuple(_MODULES)

@@ -5,7 +5,12 @@ matmuls; elementwise ignored).
 Conventions: FLOPs are multiply-accumulate*2.  Backward = 2x forward.
 Attention terms use 4*S*ctx*H*hd per layer forward (QK^T + PV);
 sliding-window layers replace ctx with min(S, window); MoE counts only
-routed-active + shared expert parameters (6*N_active*D).
+routed-active + shared expert parameters (6*N_active*D).  Latent
+attention (MLA) has score width ``qk_nope + qk_rope`` and value width
+``v_head``, so its QK^T + PV term is ``2*S*ctx*H*(qk_nope+qk_rope+v)``;
+its projections are counted as matrices like every other block
+(norm scales are left out throughout).  The leading ``first_k_dense``
+blocks of a MoE model carry the dense ``d_ff`` MLP.
 """
 from __future__ import annotations
 
@@ -25,37 +30,59 @@ class StepCost:
     n_active_params: float
 
 
-def _block_params(cfg: ModelConfig, kind: str) -> tuple[float, float]:
-    """(total, active) parameter count of one block of ``kind``."""
-    d, hd = cfg.d_model, cfg.head_size
-    H, K = cfg.num_heads, cfg.kv_heads
-    attn = d * H * hd + 2 * d * K * hd + H * hd * d
-    if cfg.num_experts:
-        e = cfg.num_experts * 3 * d * cfg.moe_d_ff
+def _attn_params(cfg: ModelConfig) -> int:
+    """Projection parameters of one self-attention mixer."""
+    d, H = cfg.d_model, cfg.num_heads
+    if cfg.is_mla:
+        r, v = cfg.kv_lora_rank, cfg.v_head_dim
+        nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        return (d * H * (nope + rope) + d * (r + rope)
+                + r * H * (nope + v) + H * v * d)
+    hd, K = cfg.head_size, cfg.kv_heads
+    return d * H * hd + 2 * d * K * hd + H * hd * d
+
+
+def _block_params(cfg: ModelConfig, kind: str,
+                  moe: bool = True) -> tuple[float, float, float]:
+    """(total, active, routed-expert) parameter count of one block of
+    ``kind``; ``moe=False`` is a leading dense block of a MoE model."""
+    d = cfg.d_model
+    attn = _attn_params(cfg)
+    routed = 0
+    if cfg.num_experts and moe:
+        routed = cfg.num_experts * 3 * d * cfg.moe_d_ff
         e_active = cfg.experts_per_token * 3 * d * cfg.moe_d_ff
         shared = 3 * d * cfg.shared_expert_d_ff if cfg.shared_expert_d_ff else 0
         router = d * cfg.num_experts
-        ffn, ffn_active = e + shared + router, e_active + shared + router
+        ffn, ffn_active = routed + shared + router, e_active + shared + router
     else:
         n_mats = 3 if cfg.mlp_gated else 2
         ffn = ffn_active = n_mats * d * cfg.d_ff
     if kind in ("G", "L"):
-        return attn + ffn, attn + ffn_active
+        return attn + ffn, attn + ffn_active, routed
     if kind == "C":
-        return 2 * attn + ffn, 2 * attn + ffn_active
+        return 2 * attn + ffn, 2 * attn + ffn_active, routed
     if kind == "R":
         W = cfg.rnn_size
         rec = 2 * d * W + 2 * W * W + W * d + cfg.conv1d_width * W
-        return rec + ffn, rec + ffn_active
+        return rec + ffn, rec + ffn_active, routed
     if kind == "W":
         tm = 6 * d * d                  # r,k,v,w,g,o projections
         cm = d * cfg.d_ff * 2 + d * d
-        return tm + cm, tm + cm
+        return tm + cm, tm + cm, 0
     raise ValueError(kind)
 
 
 def _pattern_of(cfg: ModelConfig) -> str:
-    return (cfg.layer_pattern * cfg.num_units) + cfg.remainder_pattern
+    return (cfg.layer_pattern[0] * cfg.first_k_dense
+            + cfg.layer_pattern * cfg.num_units + cfg.remainder_pattern)
+
+
+def _blocks(cfg: ModelConfig):
+    """``(kind, moe)`` of every block in order: the leading dense
+    blocks have ``moe=False``."""
+    return [(kind, i >= cfg.first_k_dense)
+            for i, kind in enumerate(_pattern_of(cfg))]
 
 
 def param_counts(cfg: ModelConfig) -> tuple[float, float]:
@@ -63,8 +90,8 @@ def param_counts(cfg: ModelConfig) -> tuple[float, float]:
     if not cfg.tie_embeddings:
         total += cfg.d_model * cfg.vocab_size
         active += cfg.d_model * cfg.vocab_size
-    for kind in _pattern_of(cfg):
-        t, a = _block_params(cfg, kind)
+    for kind, moe in _blocks(cfg):
+        t, a, _ = _block_params(cfg, kind, moe)
         total, active = total + t, active + a
     if cfg.arch_type == "audio":
         d = cfg.d_model
@@ -87,7 +114,9 @@ def _attention_flops_fwd(cfg: ModelConfig, S: int, B: int) -> float:
     H, hd = cfg.num_heads, cfg.head_size
     total = 0.0
     for kind in _pattern_of(cfg):
-        if kind == "G":
+        if kind == "G" and cfg.is_mla:
+            total += B * S * S * H * _mla_width(cfg)
+        elif kind == "G":
             # causal: average context S/2
             total += 2.0 * B * S * S * H * hd
         elif kind == "L":
@@ -112,12 +141,21 @@ class BlockCost:
     flops_fwd: float          # forward flops for ONE sequence of seq_len tokens
     params: float             # total learnable params (gradient payload)
     active_params: float      # per-token-active params (compute source)
+    routed_params: float = 0.0   # of ``params``, the routed experts'
+
+
+def _mla_width(cfg: ModelConfig) -> int:
+    """``qk_nope + qk_rope + v_head``: the per-head widths of QK^T and
+    PV together, so causal MLA costs ``S*S*H*_mla_width`` a sequence."""
+    return cfg.qk_nope_head_dim + cfg.qk_rope_head_dim + cfg.v_head_dim
 
 
 def _block_attn_flops_fwd(cfg: ModelConfig, kind: str, S: int) -> float:
     """Score+value matmul forward flops of one block for one sequence —
     the per-block slice of :func:`_attention_flops_fwd` (B=1)."""
     H, hd = cfg.num_heads, cfg.head_size
+    if kind == "G" and cfg.is_mla:
+        return float(S * S * H * _mla_width(cfg))
     if kind == "G":
         return 2.0 * S * S * H * hd
     if kind == "L":
@@ -142,18 +180,20 @@ def block_cost_table(cfg: ModelConfig, seq_len: int) -> list[BlockCost]:
 
     * ``sum(params)`` == ``param_counts(cfg)[0]``,
     * ``sum(active_params)`` == ``param_counts(cfg)[1]``,
+    * ``routed_params`` is the routed experts' part of a MoE block's
+      ``params`` (what expert parallelism shards), 0 elsewhere,
     * ``3 * B * sum(flops_fwd)`` == ``step_cost(cfg, train).flops``
       when the shapes' ``seq_len`` match (train = 3x forward).
     """
     S = seq_len
     emb = float(cfg.vocab_size * cfg.d_model)
     table = [BlockCost("embed", 2.0 * emb * S, emb, emb)]
-    for i, kind in enumerate(_pattern_of(cfg)):
-        total, active = _block_params(cfg, kind)
+    for i, (kind, moe) in enumerate(_blocks(cfg)):
+        total, active, routed = _block_params(cfg, kind, moe)
         table.append(BlockCost(
             f"block{i}_{kind}",
             2.0 * active * S + _block_attn_flops_fwd(cfg, kind, S),
-            float(total), float(active)))
+            float(total), float(active), float(routed)))
     if cfg.arch_type == "audio":
         d = cfg.d_model
         enc = float(4 * d * d + 2 * d * cfg.d_ff)
@@ -194,7 +234,11 @@ def step_cost(cfg: ModelConfig, shape: InputShape) -> StepCost:
                 ctx = min(S, cfg.sliding_window or S)
             else:
                 ctx = 0
-            if ctx:
+            if ctx and cfg.is_mla:      # the latent and the rope key
+                flops += 2.0 * B * ctx * cfg.num_heads * _mla_width(cfg)
+                cache_bytes += 2.0 * B * ctx * (cfg.kv_lora_rank
+                                                + cfg.qk_rope_head_dim)
+            elif ctx:
                 flops += 4.0 * B * ctx * cfg.num_heads * cfg.head_size
                 cache_bytes += 2.0 * B * ctx * cfg.kv_heads * cfg.head_size * 2
             if kind == "W":

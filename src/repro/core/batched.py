@@ -54,9 +54,10 @@ from typing import Sequence
 
 import numpy as np
 
-from repro.core import analytical, bucketsim
+from repro.core import analytical, bucketsim, obs
 from repro.core import het as het_mod
-from repro.core.hardware import (CLUSTERS, apply_interconnect_preset,
+from repro.core.hardware import (CLUSTERS, alltoall_coeffs,
+                                 apply_interconnect_preset,
                                  hierarchical_allreduce_coeffs,
                                  ring_allreduce_coeffs,
                                  tree_allreduce_coeffs)
@@ -92,6 +93,8 @@ class _WorkloadAxis:
     tf_meas: np.ndarray               # (W, Lmax) measured fwd s @ batch_default
     tb_meas: np.ndarray               # (W, Lmax) measured bwd s @ batch_default
     grad_bytes: np.ndarray            # (W, Lmax) all-reduce payload
+    routed: np.ndarray                # (W, Lmax) routed experts' part of it
+    a2a: np.ndarray                   # (W, Lmax) one all-to-all, bytes/sample
     bwd_ratio: np.ndarray             # (W,)
     batch_default: np.ndarray         # (W,) float64
     bytes_per_sample: np.ndarray      # (W,)
@@ -113,9 +116,13 @@ def _workload_axis(names: Sequence[str]) -> _WorkloadAxis:
     tf_meas = np.zeros((W, lmax))
     tb_meas = np.zeros((W, lmax))
     grad = np.zeros((W, lmax))
+    routed = np.zeros((W, lmax))
+    a2a = np.zeros((W, lmax))
     for i, t in enumerate(tables):
         L = t.num_layers
         grad[i, :L] = t.grad_bytes
+        routed[i, :L] = t.routed_bytes
+        a2a[i, :L] = t.a2a_bytes_per_sample
         if t.is_measured:
             tf_meas[i, :L] = t.t_f
             tb_meas[i, :L] = t.t_b
@@ -124,6 +131,7 @@ def _workload_axis(names: Sequence[str]) -> _WorkloadAxis:
     return _WorkloadAxis(
         names=list(names),
         flops=flops, tf_meas=tf_meas, tb_meas=tb_meas, grad_bytes=grad,
+        routed=routed, a2a=a2a,
         bwd_ratio=np.array([t.bwd_fwd_ratio for t in tables]),
         batch_default=np.array([t.batch_default for t in tables],
                                dtype=np.float64),
@@ -198,6 +206,80 @@ def _cluster_axis(pairs: Sequence[tuple[str, str | None]]) -> _ClusterAxis:
 
 
 @dataclass
+class _EPAxis:
+    """Expert-parallel tables, built only for a batch with some ``ep >
+    1``: one row per ``(workload, ep)`` *pair* (the kernel point's
+    ``we`` code), plus the per-workload all-to-all suffix tables.
+
+    A pair splits each layer's gradient into a dense payload, all-reduced
+    over ``n`` ranks, and an expert payload ``routed / ep``, all-reduced
+    over the ``n / ep`` ranks of an expert group.  An ``ep = 1`` pair is
+    the whole gradient as dense and no expert payload, so its columns
+    are the tables the kernel reads without expert parallelism and its
+    points come out bit for bit as they do there.  ``d*``/``e*`` are
+    inclusive prefix sums over forward layer order of the payloads and
+    of the live-collective counts; ``a2a_suf``/``a2a_sufc`` inclusive
+    suffix sums of all-to-all bytes per sample and of all-to-all layers
+    (column 0 holds the totals); ``buckets`` one entry per timeline
+    spec: release layer, mask and the suffix sums over issue order of
+    each bucket's dense and expert bytes and live collectives, buckets
+    closed on the per-device payload ``dense + expert``."""
+
+    dcum: np.ndarray                  # (P, L)
+    dcnt: np.ndarray
+    ecum: np.ndarray
+    ecnt: np.ndarray
+    param_bytes: np.ndarray           # (P,) per-device parameter bytes
+    a2a_suf: np.ndarray               # (W, L)
+    a2a_sufc: np.ndarray
+    buckets: list                     # per spec: (release, mask, sd, sdc, se, sec)
+
+
+def _suffix(a: np.ndarray) -> np.ndarray:
+    """Inclusive suffix sums along the last axis."""
+    return np.flip(np.cumsum(np.flip(a, -1), -1), -1)
+
+
+def _ep_axis(wax: _WorkloadAxis, pair_w: np.ndarray, pair_ep: np.ndarray,
+             tl_specs: Sequence[tuple[float, bool]]) -> _EPAxis:
+    """The :class:`_EPAxis` of the ``(workload index, ep)`` pairs."""
+    with obs.span("sweep.build.ep"):
+        one = (pair_ep == 1)[:, None]
+        grad, routed = wax.grad_bytes[pair_w], wax.routed[pair_w]
+        dense = np.where(one, grad, grad - routed)
+        expert = np.where(one, 0.0, routed / pair_ep[:, None])
+        rsum = wax.routed.sum(axis=1)[pair_w]
+        param = np.where(pair_ep == 1, wax.param_bytes[pair_w],
+                         wax.param_bytes[pair_w] - rsum + rsum / pair_ep)
+        buckets = []
+        for bb, _ in tl_specs:
+            rows = [bucketsim.bucket_partition(g > 0, d + e, bb)
+                    for g, d, e in zip(grad, dense, expert)]
+            B = max((len(r) for r in rows), default=0) or 1
+            release = np.zeros((len(rows), B), dtype=np.int64)
+            sums = np.zeros((4, len(rows), B))
+            for i, r in enumerate(rows):
+                for j, members in enumerate(r):
+                    release[i, j] = members[-1]
+                    db = sum(dense[i, m] for m in members)
+                    eb = sum(expert[i, m] for m in members)
+                    sums[:, i, j] = (db, db > 0, eb, eb > 0)
+            mask = np.zeros((len(rows), B))
+            for i, r in enumerate(rows):
+                mask[i, :len(r)] = 1.0
+            buckets.append((release, mask, *(_suffix(x) for x in sums)))
+        return _EPAxis(
+            dcum=np.cumsum(dense, axis=1),
+            dcnt=np.cumsum((dense > 0).astype(np.float64), axis=1),
+            ecum=np.cumsum(expert, axis=1),
+            ecnt=np.cumsum((expert > 0).astype(np.float64), axis=1),
+            param_bytes=param,
+            a2a_suf=_suffix(wax.a2a),
+            a2a_sufc=_suffix((wax.a2a > 0).astype(np.float64)),
+            buckets=buckets)
+
+
+@dataclass
 class _PolicyAxis:
     names: list[str]
     overlap_io: np.ndarray            # (P,) bool
@@ -241,10 +323,40 @@ def _policy_axis(names: Sequence[str]) -> _PolicyAxis:
 # ----------------------------------------------------------------------
 # Tier 1: the affine kernel — policy-independent cost terms.
 # ----------------------------------------------------------------------
+def _derated_links(cax: _ClusterAxis, cidx: np.ndarray,
+                   bwmul: np.ndarray | None, latmul: np.ndarray | None):
+    """``(intra_bw, intra_lat, inter_bw, inter_lat)`` per point, with
+    the slowest-worker multipliers applied where given."""
+    intra_bw, intra_lat = cax.intra_bw[cidx], cax.intra_lat[cidx]
+    inter_bw, inter_lat = cax.inter_bw[cidx], cax.inter_lat[cidx]
+    if bwmul is not None:
+        intra_bw = intra_bw * bwmul
+        inter_bw = inter_bw * bwmul
+    if latmul is not None:
+        intra_lat = intra_lat * latmul
+        inter_lat = inter_lat * latmul
+    return intra_bw, intra_lat, inter_bw, inter_lat
+
+
+def _alltoall_point_coeffs(cax: _ClusterAxis, cidx: np.ndarray,
+                           ep: np.ndarray, bwmul: np.ndarray | None,
+                           latmul: np.ndarray | None):
+    """Per-point ``(per_byte, per_message)`` of one all-to-all over an
+    EP group of ``ep`` contiguous ranks: on the intra-node link while
+    the group fits in a node, else the inter-node one, derated like the
+    all-reduce links."""
+    intra_bw, intra_lat, inter_bw, inter_lat = _derated_links(
+        cax, cidx, bwmul, latmul)
+    use_intra = ep <= cax.gpn[cidx]
+    return alltoall_coeffs(ep, np.where(use_intra, intra_bw, inter_bw),
+                           np.where(use_intra, intra_lat, inter_lat))
+
+
 def _collective_coeffs(cax: _ClusterAxis, cidx: np.ndarray,
                        coll: np.ndarray, n: np.ndarray,
                        bwmul: np.ndarray | None = None,
                        latmul: np.ndarray | None = None,
+                       gpn: np.ndarray | None = None,
                        ) -> tuple[np.ndarray, np.ndarray]:
     """Per-point affine collective coefficients ``(per_byte,
     per_message)``: every collective model is affine in the payload for
@@ -259,17 +371,17 @@ def _collective_coeffs(cax: _ClusterAxis, cidx: np.ndarray,
     inter-node parameters are derated before the algorithm dispatch
     (hierarchical scales both levels).  ``None`` (or all-ones — FP
     multiply by 1.0 is exact) leaves the homogeneous path bit-identical.
+
+    ``gpn`` overrides the devices a node holds per point: an expert
+    group of ``n / ep`` strided ranks holds ``max(1, gpn // ep)`` a
+    node (:meth:`repro.core.hardware.ClusterSpec.expert_group`).
     """
     n_f = n.astype(np.float64)
-    intra_bw, intra_lat = cax.intra_bw[cidx], cax.intra_lat[cidx]
-    inter_bw, inter_lat = cax.inter_bw[cidx], cax.inter_lat[cidx]
-    if bwmul is not None:
-        intra_bw = intra_bw * bwmul
-        inter_bw = inter_bw * bwmul
-    if latmul is not None:
-        intra_lat = intra_lat * latmul
-        inter_lat = inter_lat * latmul
-    use_intra = n <= cax.gpn[cidx]
+    intra_bw, intra_lat, inter_bw, inter_lat = _derated_links(
+        cax, cidx, bwmul, latmul)
+    if gpn is None:
+        gpn = cax.gpn[cidx]
+    use_intra = n <= gpn
     link_bw = np.where(use_intra, intra_bw, inter_bw)
     link_lat = np.where(use_intra, intra_lat, inter_lat)
     codes_present = np.unique(coll)
@@ -288,7 +400,7 @@ def _collective_coeffs(cax: _ClusterAxis, cidx: np.ndarray,
                                          link_lat[sel])
         else:
             a, b = hierarchical_allreduce_coeffs(
-                n[sel], cax.gpn[cidx[sel]], intra_bw[sel], intra_lat[sel],
+                n[sel], gpn[sel], intra_bw[sel], intra_lat[sel],
                 inter_bw[sel], inter_lat[sel])
         per_byte[sel], per_message[sel] = a, b
     return per_byte, per_message
@@ -330,7 +442,10 @@ def _kernel_cols(wax: _WorkloadAxis, cax: _ClusterAxis,
                  chunk: int = KERNEL_CHUNK,
                  tmul: np.ndarray | None = None,
                  bwmul: np.ndarray | None = None,
-                 latmul: np.ndarray | None = None) -> dict[str, np.ndarray]:
+                 latmul: np.ndarray | None = None,
+                 epx: _EPAxis | None = None,
+                 we: np.ndarray | None = None,
+                 ep: np.ndarray | None = None) -> dict[str, np.ndarray]:
     """Policy-independent terms for every kernel point, reduced over
     the layer axis: ``(K,)`` vectors of ``io_h2d``, ``t_h2d``, ``comp``
     (= sum t_f + sum t_b), ``sum_c``, ``tc_no``, ``t_u``, plus the
@@ -373,6 +488,19 @@ def _kernel_cols(wax: _WorkloadAxis, cax: _ClusterAxis,
     (their channels are per-worker and identical) and ``t_u`` is
     HBM-bandwidth-bound, not compute-rate-bound, so neither is scaled.
     All-ones multipliers are bit-identity (IEEE ``x * 1.0 == x``).
+
+    Expert parallelism (``epx`` with the per-point pair codes ``we``
+    and group sizes ``ep``, all ``None`` for a batch without it) adds
+    two more affine pairs per point.  The all-to-alls: four a layer, at
+    ``alltoall_coeffs`` over the EP group times ``batch`` times the
+    layer's bytes per sample, two of them in the backward pass — so
+    ``a_byte * a2a_suf + a_msg * a2a_sufc`` joins the backward suffix
+    (hence every release time and the WFBP candidates), the backward
+    total and, twice, ``comp``; they are not compute and ``tmul`` does
+    not scale them.  The expert all-reduce: the expert group's
+    coefficients times the pair's ``ecum``/``ecnt`` beside the dense
+    ``dcum``/``dcnt``, in ``sum_c``, the WFBP candidates and the bucket
+    durations; ``t_u`` reads the pair's per-device parameter bytes.
     """
     K = len(widx)
     # Per-workload layer tables: inclusive payload/count prefix sums
@@ -424,10 +552,23 @@ def _kernel_cols(wax: _WorkloadAxis, cax: _ClusterAxis,
         total_b = total_b_u[uk]
 
         # per-point affine collective coefficients
-        per_byte, per_message = _collective_coeffs(
-            cax, c, cl, nn,
-            None if bwmul is None else bwmul[sl],
-            None if latmul is None else latmul[sl])
+        bm = None if bwmul is None else bwmul[sl]
+        lm = None if latmul is None else latmul[sl]
+        per_byte, per_message = _collective_coeffs(cax, c, cl, nn, bm, lm)
+        if epx is not None:
+            pe, ep_k = we[sl], ep[sl]
+            e_byte, e_msg = _collective_coeffs(
+                cax, c, cl, nn // ep_k, bm, lm,
+                gpn=np.maximum(cax.gpn[c] // ep_k, 1))
+            a_byte, a_msg = _alltoall_point_coeffs(cax, c, ep_k, bm, lm)
+            a_byte = 2.0 * a_byte * batch_f     # two a layer, each pass
+            a_msg = 2.0 * a_msg
+            suffix_b = suffix_b_u[uk] \
+                + a_byte[:, None] * epx.a2a_suf[w] \
+                + a_msg[:, None] * epx.a2a_sufc[w]
+            a2a_pass = a_byte * epx.a2a_suf[w, 0] \
+                + a_msg * epx.a2a_sufc[w, 0]
+            total_b = total_b + a2a_pass
 
         # pipeline terms: (k,)
         nbytes_in = batch_f * wax.bytes_per_sample[w]
@@ -441,21 +582,33 @@ def _kernel_cols(wax: _WorkloadAxis, cax: _ClusterAxis,
 
         out["io_h2d"][sl] = t_io + t_h2d
         out["t_h2d"][sl] = t_h2d
-        out["comp"][sl] = comp_u[uk]
-        out["sum_c"][sl] = per_byte * gradsum[w] + per_message * ncomm[w]
         # WFBP residual (non_overlapped_comm_batch, affine form): the
         # comm prefix sum at layer l is per_byte*cumgrad[l] +
         # per_message*cumcount[l]; candidates masked to comm layers
         # (t_c > 0 <=> grad > 0 when n > 1; when n <= 1 both
         # coefficients are 0, every candidate is <= total_b and the
         # clamp yields the same exact 0.0)
-        cand = suffix_b_u[uk]
-        cand += per_byte[:, None] * cumgrad[w]
-        cand += per_message[:, None] * cumcount[w]
+        if epx is None:
+            out["comp"][sl] = comp_u[uk]
+            out["sum_c"][sl] = per_byte * gradsum[w] + per_message * ncomm[w]
+            cand = suffix_b_u[uk]
+            cand += per_byte[:, None] * cumgrad[w]
+            cand += per_message[:, None] * cumcount[w]
+            out["t_u"][sl] = 3.0 * wax.param_bytes[w] / cax.hbm_bw[c]
+        else:
+            out["comp"][sl] = comp_u[uk] + 2.0 * a2a_pass
+            out["sum_c"][sl] = per_byte * epx.dcum[pe, -1] \
+                + per_message * epx.dcnt[pe, -1] \
+                + e_byte * epx.ecum[pe, -1] + e_msg * epx.ecnt[pe, -1]
+            cand = suffix_b.copy()
+            cand += per_byte[:, None] * epx.dcum[pe]
+            cand += per_message[:, None] * epx.dcnt[pe]
+            cand += e_byte[:, None] * epx.ecum[pe]
+            cand += e_msg[:, None] * epx.ecnt[pe]
+            out["t_u"][sl] = 3.0 * epx.param_bytes[pe] / cax.hbm_bw[c]
         cand *= comm_mask[w]
         out["tc_no"][sl] = np.maximum(
             cand.max(axis=1, initial=0.0) - total_b, 0.0)
-        out["t_u"][sl] = 3.0 * wax.param_bytes[w] / cax.hbm_bw[c]
         out["n_f"][sl] = n_f
         out["batch_f"][sl] = batch_f
 
@@ -465,6 +618,20 @@ def _kernel_cols(wax: _WorkloadAxis, cax: _ClusterAxis,
         # masked max over the (k, B) bucket axis per spec
         for i, ((bt, sufnb, sufcnt), (_, ov_comm)) in \
                 enumerate(zip(btables, tl_specs)):
+            if epx is not None:
+                rel, bmask, sd, sdc, se, sec = epx.buckets[i]
+                if ov_comm:
+                    cand = np.take_along_axis(suffix_b, rel[pe], axis=1)
+                else:
+                    cand = np.repeat(total_b[:, None], rel.shape[1], axis=1)
+                cand += per_byte[:, None] * sd[pe]
+                cand += per_message[:, None] * sdc[pe]
+                cand += e_byte[:, None] * se[pe]
+                cand += e_msg[:, None] * sec[pe]
+                cand *= bmask[pe]
+                out[f"tl{i}"][sl] = np.maximum(
+                    cand.max(axis=1, initial=0.0) - total_b, 0.0)
+                continue
             if ov_comm:
                 release_u = np.take_along_axis(
                     suffix_b_u, bt.release_layer[uw], axis=1)
@@ -576,6 +743,7 @@ def select_to_columns(cols: dict[str, np.ndarray],
         "straggler": labels["straggler"],
         "sync_k": labels["sync_k"],
         "faults": labels["faults"],
+        "ep_size": labels["ep_size"],
         "batch_per_gpu": np.asarray(cols["batch"]).astype(np.int64),
         "iteration_time_s": t_iter,
         "samples_per_sec": np.asarray(cols["samples_per_sec"]),
@@ -603,7 +771,10 @@ def _apply_mc_tails(wax: _WorkloadAxis, cax: _ClusterAxis, pax: _PolicyAxis,
                     active: np.ndarray | None = None,
                     synck: np.ndarray | None = None,
                     ft_specs: Sequence = (None,),
-                    fidx: np.ndarray | None = None) -> None:
+                    fidx: np.ndarray | None = None,
+                    epx: _EPAxis | None = None,
+                    we: np.ndarray | None = None,
+                    ep: np.ndarray | None = None) -> None:
     """Attach ``t_mean_s``/``t_p95_s``/``t_p99_s`` to a
     :func:`_policy_select` output in place.
 
@@ -615,7 +786,9 @@ def _apply_mc_tails(wax: _WorkloadAxis, cax: _ClusterAxis, pax: _PolicyAxis,
     ``None``), ``bwmul``/``latmul`` its deterministic slowest-link
     multipliers, ``synck`` its normalized sync threshold (``0`` = full
     sync) and ``fidx`` its spec in ``ft_specs`` (parsed
-    :class:`repro.core.het.FaultSpec` or ``None``).  Deterministic rows
+    :class:`repro.core.het.FaultSpec` or ``None``); ``epx``/``we``/
+    ``ep`` its expert-parallel pair and group size, as
+    :func:`_kernel_cols` takes them.  Deterministic rows
     (no stochastic spec) keep the point-mass default — tails equal to
     ``iteration_time_s``, bit-exact.
 
@@ -676,7 +849,8 @@ def _apply_mc_tails(wax: _WorkloadAxis, cax: _ClusterAxis, pax: _PolicyAxis,
             # identical draws
             key = np.stack([widx[rows], cidx[rows], coll[rows], n[rows],
                             batch[rows], polidx[rows], hks[rows],
-                            synck[rows]], axis=1)
+                            synck[rows]]
+                           + ([] if epx is None else [we[rows]]), axis=1)
             _, rep, uinv = np.unique(key, axis=0, return_index=True,
                                      return_inverse=True)
             urows = rows[rep]
@@ -715,7 +889,9 @@ def _apply_mc_tails(wax: _WorkloadAxis, cax: _ClusterAxis, pax: _PolicyAxis,
                     batch[rp], tl_specs=pax.tl_specs,
                     tmul=tmuls[lo:lo + m].ravel(),
                     bwmul=None if bwmul is None else bwmul[rp],
-                    latmul=None if latmul is None else latmul[rp])
+                    latmul=None if latmul is None else latmul[rp],
+                    epx=epx, we=None if epx is None else we[rp],
+                    ep=None if epx is None else ep[rp])
                 ti = _policy_select(
                     pax, polidx[rp], kc, kidx=None,
                     chain_extra=None if pens is None
@@ -767,8 +943,9 @@ class GridEvaluator:
         nA, nI = len(grid.collectives), len(grid.interconnects)
         nH, nT = len(grid.het_profiles), len(grid.stragglers)
         nQ, nF = len(grid.sync_ks), len(grid.faults)
-        self._sizes = (nW, nC, nK, nP, nA, nI, nH, nT, nQ, nF)
-        self.n_scenarios = (nW * nC * nK * nP * nA * nI * nH * nT
+        nE = len(grid.ep_sizes)
+        self._sizes = (nW, nC, nK, nE, nP, nA, nI, nH, nT, nQ, nF)
+        self.n_scenarios = (nW * nC * nK * nE * nP * nA * nI * nH * nT
                             * nQ * nF)
 
         self._wax = _workload_axis(grid.workloads)
@@ -778,8 +955,8 @@ class GridEvaluator:
 
         # Kernel grid: the scenario product with the policy, straggler
         # and fault axes dropped — order (workloads, clusters, workers,
-        # collectives, interconnects, het_profiles, sync_ks), rightmost
-        # fastest.  The straggler and fault axes never change a
+        # ep_sizes, collectives, interconnects, het_profiles, sync_ks),
+        # rightmost fastest.  The straggler and fault axes never change a
         # deterministic kernel point (jitter and crash penalties only
         # enter the Monte Carlo pass); the het axis does, through the
         # bottleneck multipliers, and the sync_k axis does too — it
@@ -788,8 +965,8 @@ class GridEvaluator:
         # quantity is derived per chunk instead (see _scenario_codes),
         # so preparation stays O(axes + K) however large the scenario
         # product is.
-        kw, kc, kk, ka, ki, kh, kq = _axis_codes(
-            (nW, nC, nK, nA, nI, nH, nQ))
+        kw, kc, kk, ke, ka, ki, kh, kq = _axis_codes(
+            (nW, nC, nK, nE, nA, nI, nH, nQ))
         self._kwidx = kw
         self._kcidx = kc * nI + ki              # (cluster, interconnect) pair
         self._kcoll = np.array(
@@ -804,6 +981,18 @@ class GridEvaluator:
             [normalize_sync_k(k) for k in grid.sync_ks], dtype=np.int64)
         self._ksynck = sk_values[kq]            # 0 = full sync
         _check_batch_locked(self._wax, kw, self._kbatch)
+
+        # Expert parallelism: one table row per (workload, ep) pair,
+        # built only when some point has ep > 1 (else the kernel runs
+        # exactly as without the axis).
+        self._ep_values = np.array([int(e) for e in grid.ep_sizes],
+                                   dtype=np.int64)
+        self._kep = self._ep_values[ke]
+        self._kwe = kw * nE + ke                # (workload, ep) pair row
+        self._epx = _ep_axis(self._wax, np.repeat(np.arange(nW), nE),
+                             np.tile(self._ep_values, nW),
+                             self._pax.tl_specs) \
+            if bool((self._ep_values > 1).any()) else None
 
         # Heterogeneity: one padded per-worker table row per (profile,
         # n_workers) pair, reduced once to the bottleneck multipliers
@@ -874,12 +1063,19 @@ class GridEvaluator:
     def __len__(self) -> int:
         return self.n_scenarios
 
+    def _ep_kwargs(self, k: np.ndarray | slice = slice(None)) -> dict:
+        """The kernel's expert-parallel arguments for kernel points
+        ``k`` (none on a grid without ``ep > 1``)."""
+        if self._epx is None:
+            return {}
+        return {"epx": self._epx, "we": self._kwe[k], "ep": self._kep[k]}
+
     def _scenario_codes(self, lo: int, hi: int) -> dict[str, np.ndarray]:
         """Axis codes, the kernel-point map and the fast mask for flat
         scenario indices ``[lo, hi)``, derived arithmetically from the
         expand() order (rightmost axis fastest) — O(chunk) work and
         memory, nothing per-scenario is ever stored."""
-        nW, nC, nK, nP, nA, nI, nH, nT, nQ, nF = self._sizes
+        nW, nC, nK, nE, nP, nA, nI, nH, nT, nQ, nF = self._sizes
         r = np.arange(lo, hi, dtype=np.int64)
         fli = r % nF
         r //= nF
@@ -895,14 +1091,17 @@ class GridEvaluator:
         r //= nA
         pi = r % nP
         r //= nP
+        ei = r % nE
+        r //= nE
         ki = r % nK
         r //= nK
         ci = r % nC
         wi = r // nC
-        kidx = ((((((wi * nC + ci) * nK + ki) * nA + ai) * nI + ii) * nH
-                 + hp) * nQ + ski)
-        return {"wi": wi, "ci": ci, "ki": ki, "pi": pi, "ai": ai, "ii": ii,
-                "hi": hp, "sti": sti, "ski": ski, "fli": fli, "kidx": kidx,
+        kidx = (((((((wi * nC + ci) * nK + ki) * nE + ei) * nA + ai) * nI
+                  + ii) * nH + hp) * nQ + ski)
+        return {"wi": wi, "ci": ci, "ki": ki, "ei": ei, "pi": pi, "ai": ai,
+                "ii": ii, "hi": hp, "sti": sti, "ski": ski, "fli": fli,
+                "kidx": kidx,
                 "batched": self._pax.has_fast[pi] | self._pax.has_tl[pi]}
 
     def _label_columns(self, codes: dict[str, np.ndarray]) -> dict:
@@ -917,6 +1116,7 @@ class GridEvaluator:
             "straggler": self._st_values[codes["sti"]],
             "sync_k": self._sk_values[codes["ski"]],
             "faults": self._fl_values[codes["fli"]],
+            "ep_size": self._ep_values[codes["ei"]],
         }
 
     def _apply_tails(self, codes: dict[str, np.ndarray],
@@ -941,7 +1141,8 @@ class GridEvaluator:
             None if self._klatmul is None else self._klatmul[k],
             self._st_specs, codes["sti"], cols, seed,
             active=codes["batched"], synck=self._ksynck[k],
-            ft_specs=self._ft_specs, fidx=codes["fli"])
+            ft_specs=self._ft_specs, fidx=codes["fli"],
+            **self._ep_kwargs(k))
 
     def run(self, seed: int = 0) -> "GridRun":
         """Evaluate the kernel grid (fresh numbers every call) and
@@ -951,7 +1152,8 @@ class GridEvaluator:
             self._wax, self._cax, self._kwidx, self._kcidx,
             self._kcoll, self._kn, self._kbatch,
             tl_specs=self._pax.tl_specs,
-            tmul=self._ktmul, bwmul=self._kbwmul, latmul=self._klatmul),
+            tmul=self._ktmul, bwmul=self._kbwmul, latmul=self._klatmul,
+            **self._ep_kwargs()),
             seed=seed)
 
     def run_span(self, lo: int, hi: int, seed: int = 0):
@@ -970,7 +1172,8 @@ class GridEvaluator:
             tl_specs=self._pax.tl_specs,
             tmul=None if self._ktmul is None else self._ktmul[uk],
             bwmul=None if self._kbwmul is None else self._kbwmul[uk],
-            latmul=None if self._klatmul is None else self._klatmul[uk])
+            latmul=None if self._klatmul is None else self._klatmul[uk],
+            **self._ep_kwargs(uk))
         cols = _policy_select(self._pax, codes["pi"], kc, inv)
         self._apply_tails(codes, cols, seed)
         return (select_to_columns(cols, self._label_columns(codes)),
@@ -1133,6 +1336,22 @@ def scenario_axes(scenarios: Sequence[Scenario]):
     return wax, cax, pax, widx, cidx, polidx, coll, n, batch
 
 
+def scenario_ep_axes(scenarios: Sequence[Scenario], wax: _WorkloadAxis,
+                     widx: np.ndarray, pax: _PolicyAxis) -> dict:
+    """The kernel's expert-parallel arguments for a scenario list (the
+    :meth:`GridEvaluator._ep_kwargs` of the list front end): the
+    :class:`_EPAxis` over the list's unique ``(workload, ep)`` pairs and
+    each scenario's pair row and group size — none when every scenario
+    has ``ep_size == 1``.  Shared with the jax list front end."""
+    ep = np.array([s.ep_size for s in scenarios], dtype=np.int64)
+    if not bool((ep > 1).any()):
+        return {}
+    pairs, we = np.unique(np.stack([widx, ep], axis=1), axis=0,
+                          return_inverse=True)
+    return {"epx": _ep_axis(wax, pairs[:, 0], pairs[:, 1], pax.tl_specs),
+            "we": we.reshape(-1), "ep": ep}
+
+
 def scenario_het_axes(scenarios: Sequence[Scenario]):
     """One Python pass over a scenario list: the heterogeneity /
     failure-model structure the kernel and the Monte Carlo pass need.
@@ -1222,6 +1441,7 @@ def scenario_labels(scenarios: Sequence[Scenario]) -> dict[str, np.ndarray]:
         "faults": np.array(
             [het_mod.normalize_fault(s.faults) for s in scenarios],
             dtype=object),
+        "ep_size": np.array([s.ep_size for s in scenarios], dtype=np.int64),
     }
 
 
@@ -1241,13 +1461,15 @@ def eval_scenarios_table(scenarios: Sequence[Scenario],
         scenario_axes(scenarios)
     (hks, wtab, tmul, bwmul, latmul, st_specs, stidx,
      synck, ft_specs, fidx) = scenario_het_axes(scenarios)
+    epkw = scenario_ep_axes(scenarios, wax, widx, pax)
     kc = _kernel_cols(wax, cax, widx, cidx, coll, n, batch,
                       tl_specs=pax.tl_specs,
-                      tmul=tmul, bwmul=bwmul, latmul=latmul)
+                      tmul=tmul, bwmul=bwmul, latmul=latmul, **epkw)
     cols = _policy_select(pax, polidx, kc, kidx=None)
     _apply_mc_tails(wax, cax, pax, widx, cidx, coll, n, batch, polidx,
                     hks, wtab, bwmul, latmul, st_specs, stidx,
-                    cols, seed, synck=synck, ft_specs=ft_specs, fidx=fidx)
+                    cols, seed, synck=synck, ft_specs=ft_specs, fidx=fidx,
+                    **epkw)
     return select_to_columns(cols, scenario_labels(scenarios))
 
 

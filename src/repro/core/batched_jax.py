@@ -69,7 +69,8 @@ import jax.numpy as jnp
 
 from repro.core import analytical, batched, bucketsim, obs
 from repro.core.batched import grid_evaluator
-from repro.core.hardware import (hierarchical_allreduce_coeffs,
+from repro.core.hardware import (alltoall_coeffs,
+                                 hierarchical_allreduce_coeffs,
                                  ring_allreduce_coeffs,
                                  tree_allreduce_coeffs)
 from repro.core.resulttable import METHOD_LABELS, rows_from_table
@@ -145,6 +146,20 @@ def _axes_tables(wax, cax, pax, wtab) -> tuple[dict, dict]:
     return tables, pflags
 
 
+def _ep_tables(epx) -> dict:
+    """The expert-parallel tables of a :class:`repro.core.batched._EPAxis`
+    as ``ep_*`` entries of the kernel's ``tables`` argument."""
+    tables = {"ep_dcum": epx.dcum, "ep_dcnt": epx.dcnt,
+              "ep_ecum": epx.ecum, "ep_ecnt": epx.ecnt,
+              "ep_param_bytes": epx.param_bytes,
+              "ep_a2a_suf": epx.a2a_suf, "ep_a2a_sufc": epx.a2a_sufc}
+    for i, b in enumerate(epx.buckets):
+        for name, x in zip(("release", "mask", "sd", "sdc", "se", "sec"), b):
+            tables[f"ep_bt{i}_{name}"] = x
+    return tables
+
+
+
 # ----------------------------------------------------------------------
 # Tier 1: the affine kernel over whole code vectors.
 # ----------------------------------------------------------------------
@@ -162,7 +177,14 @@ def _kernel_cols_jax(tbl: dict, kcodes: dict, ucodes: dict,
     per-point link multipliers — reduced in-jit from the padded worker
     table gathered at ``kcodes["hk"]`` — derate both link levels
     before the collective dispatch.  All-ones multipliers are
-    bit-identity (IEEE ``x * 1.0 == x``)."""
+    bit-identity (IEEE ``x * 1.0 == x``).
+
+    Expert parallelism is traced only when ``kcodes`` holds its codes
+    (``we``, the ``(workload, ep)`` pair row, and ``ep``, the group
+    size), which the host puts there only
+    for a batch with some ``ep > 1`` — the pytree's structure is static,
+    so a batch without it compiles to the same program as before the
+    axis existed.  Its terms are those of the NumPy kernel."""
     w, c = kcodes["w"], kcodes["c"]
     coll, n, batch, uk = kcodes["coll"], kcodes["n"], kcodes["batch"], \
         kcodes["uk"]
@@ -217,6 +239,43 @@ def _kernel_cols_jax(tbl: dict, kcodes: dict, ucodes: dict,
         sel = coll == code
         per_byte = jnp.where(sel, a, per_byte)
         per_message = jnp.where(sel, b, per_message)
+    ep_on = "we" in kcodes
+    if ep_on:
+        pe, ep = kcodes["we"], kcodes["ep"]
+        gpn = tbl["gpn"][c]
+        group = n // ep
+        group_gpn = jnp.maximum(gpn // ep, 1)
+        e_intra = group <= group_gpn
+        e_bw = jnp.where(e_intra, intra_bw, inter_bw)
+        e_lat = jnp.where(e_intra, intra_lat, inter_lat)
+
+        def _expert_model(code: int):
+            if code == 0:
+                return ring_allreduce_coeffs(group.astype(jnp.float64),
+                                             e_bw, e_lat)
+            if code == 1:
+                return tree_allreduce_coeffs(group, e_bw, e_lat)
+            return hierarchical_allreduce_coeffs(
+                group, group_gpn, intra_bw, intra_lat, inter_bw, inter_lat)
+
+        e_byte, e_msg = _expert_model(coll_codes[0])
+        for code in coll_codes[1:]:
+            a, b = _expert_model(code)
+            sel = coll == code
+            e_byte = jnp.where(sel, a, e_byte)
+            e_msg = jnp.where(sel, b, e_msg)
+        a_intra = ep <= gpn
+        a_byte, a_msg = alltoall_coeffs(
+            ep, jnp.where(a_intra, intra_bw, inter_bw),
+            jnp.where(a_intra, intra_lat, inter_lat))
+        a_byte = 2.0 * a_byte * batch_f     # two a layer, each pass
+        a_msg = 2.0 * a_msg
+        suffix_b = suffix_b_u[uk] \
+            + a_byte[:, None] * tbl["ep_a2a_suf"][w] \
+            + a_msg[:, None] * tbl["ep_a2a_sufc"][w]
+        a2a_pass = a_byte * tbl["ep_a2a_suf"][w, 0] \
+            + a_msg * tbl["ep_a2a_sufc"][w, 0]
+        total_b = total_b + a2a_pass
 
     # pipeline terms: (K,)
     nbytes_in = batch_f * tbl["bytes_per_sample"][w]
@@ -227,6 +286,43 @@ def _kernel_cols_jax(tbl: dict, kcodes: dict, ucodes: dict,
     t_h2d = tbl["h2d_lat"][c] + nbytes_in / tbl["h2d_bw"][c]
 
     # WFBP residual (affine form — see the NumPy kernel's derivation)
+    if ep_on:
+        cand = suffix_b \
+            + per_byte[:, None] * tbl["ep_dcum"][pe] \
+            + per_message[:, None] * tbl["ep_dcnt"][pe] \
+            + e_byte[:, None] * tbl["ep_ecum"][pe] \
+            + e_msg[:, None] * tbl["ep_ecnt"][pe]
+        cand = cand * tbl["comm_mask"][w]
+        out = {
+            "io_h2d": t_io + t_h2d,
+            "t_h2d": t_h2d,
+            "comp": comp_u[uk] + 2.0 * a2a_pass,
+            "sum_c": per_byte * tbl["ep_dcum"][pe, -1]
+            + per_message * tbl["ep_dcnt"][pe, -1]
+            + e_byte * tbl["ep_ecum"][pe, -1]
+            + e_msg * tbl["ep_ecnt"][pe, -1],
+            "tc_no": jnp.maximum(cand.max(axis=1, initial=0.0) - total_b,
+                                 0.0),
+            "t_u": 3.0 * tbl["ep_param_bytes"][pe] / tbl["hbm_bw"][c],
+            "n_f": n_f,
+            "batch_f": batch_f,
+        }
+        for i, ov_comm in enumerate(tl_overlaps):
+            rel = tbl[f"ep_bt{i}_release"]
+            if ov_comm:
+                cand = jnp.take_along_axis(suffix_b, rel[pe], axis=1)
+            else:
+                cand = jnp.broadcast_to(total_b[:, None],
+                                        (len(pe), rel.shape[1]))
+            cand = cand \
+                + per_byte[:, None] * tbl[f"ep_bt{i}_sd"][pe] \
+                + per_message[:, None] * tbl[f"ep_bt{i}_sdc"][pe] \
+                + e_byte[:, None] * tbl[f"ep_bt{i}_se"][pe] \
+                + e_msg[:, None] * tbl[f"ep_bt{i}_sec"][pe]
+            cand = cand * tbl[f"ep_bt{i}_mask"][pe]
+            out[f"tl{i}"] = jnp.maximum(
+                cand.max(axis=1, initial=0.0) - total_b, 0.0)
+        return out
     cand = suffix_b_u[uk] \
         + per_byte[:, None] * tbl["cumgrad"][w] \
         + per_message[:, None] * tbl["cumcount"][w]
@@ -416,14 +512,21 @@ def _host_columns(args: tuple, size: int) -> dict[str, np.ndarray]:
     from it; with the recorder off it also does the waiting).  The
     counters ``sweep.h2d_arrays``/``sweep.h2d_bytes`` count the NumPy
     leaves of the arguments, each of which the call copies to the
-    device; ``sweep.d2h_arrays``/``sweep.d2h_bytes`` the device arrays
-    and bytes the fetch brings to the host."""
+    device, and ``sweep.ep_h2d_bytes`` the bytes of those that are
+    expert-parallel tables and codes (only where there are any);
+    ``sweep.d2h_arrays``/``sweep.d2h_bytes`` the device arrays and
+    bytes the fetch brings to the host."""
     with obs.span("sweep.columns"), jax.enable_x64(True):
         if obs.enabled():
             host = [x for x in jax.tree_util.tree_leaves(args)
                     if isinstance(x, np.ndarray)]
             obs.count("sweep.h2d_arrays", len(host))
             obs.count("sweep.h2d_bytes", sum(x.nbytes for x in host))
+            if "we" in args[2]:
+                ep = [v for k, v in args[0].items() if k.startswith("ep_")] \
+                    + [args[2]["we"], args[2]["ep"]]
+                obs.count("sweep.ep_h2d_bytes", sum(
+                    x.nbytes for x in ep if isinstance(x, np.ndarray)))
         pi = args[3]["pi"]
         shards = _shards(pi) if _f64_words() else 0
         with obs.span("sweep.columns.call"):
@@ -439,13 +542,20 @@ def _host_columns(args: tuple, size: int) -> dict[str, np.ndarray]:
             return {k: cols[i, :size] for i, k in enumerate(_NUMERIC_COLS)}
 
 
+def _count_points(kernel_points: int, ep_points: int) -> None:
+    """Counters ``sweep.kernel_points`` and ``sweep.ep_points``: the
+    kernel points of one evaluation, and those with ``ep > 1``."""
+    obs.count("sweep.kernel_points", kernel_points)
+    obs.count("sweep.ep_points", ep_points)
+
+
 # ----------------------------------------------------------------------
 # Sharding: pad the batch axes to a device-count multiple and place
 # the code vectors over the mesh's data axis.
 # ----------------------------------------------------------------------
 #: Benign fill for padding rows (index 0 is always valid; n=1 is the
 #: zero-comm degenerate; batch=0 means "table default").
-_PAD_FILL = {"n": 1}
+_PAD_FILL = {"n": 1, "ep": 1}
 
 
 def _shard_codes(codes: dict, mesh) -> dict:
@@ -503,6 +613,11 @@ class JaxGridEvaluator:
         kcodes = {"w": ev._kwidx, "c": ev._kcidx, "coll": ev._kcoll,
                   "n": ev._kn, "batch": ev._kbatch, "uk": uk,
                   "hk": ev._khk}
+        ep = ev._ep_kwargs()
+        if ep:
+            self._tables.update(_ep_tables(ep["epx"]))
+            kcodes.update(we=ep["we"], ep=ep["ep"])
+        self._points = (len(ev._kn), int((ev._kep > 1).sum()))
         self._ucodes = {"w": uw, "c": uc, "batch": ub,
                         "tmul": np.ones(len(uw)) if ut is None else ut}
         S = len(ev)
@@ -532,6 +647,7 @@ class JaxGridEvaluator:
         S = len(self.ev)
         if S == 0:
             return {k: np.empty(0) for k in _NUMERIC_COLS}
+        _count_points(*self._points)
         return _host_columns(self._args(params), S)
 
     def _args(self, params: dict | None = None) -> tuple:
@@ -575,7 +691,7 @@ class JaxGridEvaluator:
                 None if ev._klatmul is None else ev._klatmul[k],
                 ev._st_specs, codes["sti"], cols, seed,
                 synck=ev._ksynck[k], ft_specs=ev._ft_specs,
-                fidx=codes["fli"])
+                fidx=codes["fli"], **ev._ep_kwargs(k))
         else:
             t_iter = cols["iteration_time_s"]
             cols["t_mean_s"] = t_iter
@@ -714,16 +830,21 @@ def eval_scenarios_table_jax(
                                                   batch, tmul)
     kcodes = {"w": widx, "c": cidx, "coll": coll, "n": n, "batch": batch,
               "uk": uk, "hk": hks}
+    epkw = batched.scenario_ep_axes(scenarios, wax, widx, pax)
+    if epkw:
+        tables.update(_ep_tables(epkw["epx"]))
+        kcodes.update(we=epkw["we"], ep=epkw["ep"])
     ucodes = {"w": uw, "c": uc, "batch": ub,
               "tmul": np.ones(len(uw)) if ut is None else ut}
     scodes = {"pi": polidx, "kidx": np.arange(S, dtype=np.int64)}
     coll_codes = tuple(int(x) for x in np.unique(coll)) or (0,)
+    _count_points(S, int((epkw["ep"] > 1).sum()) if epkw else 0)
     cols = _host_columns((tables, pflags, kcodes, scodes, ucodes,
                           tl_overlaps, coll_codes), S)
     batched._apply_mc_tails(wax, cax, pax, widx, cidx, coll, n, batch,
                             polidx, hks, wtab, bwmul, latmul, st_specs,
                             stidx, cols, seed, synck=synck,
-                            ft_specs=ft_specs, fidx=fidx)
+                            ft_specs=ft_specs, fidx=fidx, **epkw)
     cols["method_code"] = pax.tier[polidx]
     return batched.select_to_columns(cols,
                                      batched.scenario_labels(scenarios))
@@ -808,7 +929,8 @@ def numpy_iteration_times(grid: ScenarioGrid,
     kc = batched._kernel_cols(ev._wax, cax, ev._kwidx, ev._kcidx,
                               ev._kcoll, ev._kn, ev._kbatch,
                               tl_specs=tl_specs, tmul=ev._ktmul,
-                              bwmul=ev._kbwmul, latmul=ev._klatmul)
+                              bwmul=ev._kbwmul, latmul=ev._klatmul,
+                              **ev._ep_kwargs())
     codes = ev._scenario_codes(0, len(ev))
     return batched._policy_select(ev._pax, codes["pi"], kc,
                                   codes["kidx"])["iteration_time_s"]

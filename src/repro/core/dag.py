@@ -18,6 +18,8 @@ import enum
 from dataclasses import dataclass, field
 from typing import Callable, Iterable, Sequence
 
+import numpy as np
+
 from repro.core.policies import Policy
 
 
@@ -42,6 +44,10 @@ def pcie_channel(worker: int) -> str:
 
 
 NET_CHANNEL = "net"
+
+#: Expert-parallel all-to-all channel: MoE dispatch and combine, kept
+#: apart from the gradient ``net`` channel (see :class:`IterationCosts`).
+EP_CHANNEL = "ep"
 
 #: Shared checkpoint-store channel: crash restores read the same npz
 #: store (:mod:`repro.checkpoint.ckpt`), so they serialize — which is
@@ -154,6 +160,18 @@ class IterationCosts:
     layer-wise ``t_f^(l)``, ``t_b^(l)``, ``t_c^(l)`` and ``t_u``.
     Comm durations are for the *collective* across all participating
     workers (layer-wise all-reduce), as measured in the paper's traces.
+
+    Expert parallelism (``t_a2a`` set) adds, per layer: ``t_a2a``, one
+    all-to-all of the layer's MoE dispatch or combine (0 on a layer
+    without routed experts), of which the layer runs four an iteration
+    — dispatch and combine after its forward, their gradients before
+    its backward — each one task on :data:`EP_CHANNEL` that waits for
+    every worker and that every worker waits for; and ``t_ce``, the
+    all-reduce of the routed experts' gradient (per-device payload
+    ``expert_bytes``) over the ranks that hold the same experts, issued
+    right after the layer's dense all-reduce ``t_c`` on the ``net``
+    channel.  The EP channel is a departure: real all-to-alls share
+    links with the gradient traffic.
     """
 
     t_f: Sequence[float]              # forward, layer 1..L
@@ -163,10 +181,32 @@ class IterationCosts:
     t_h2d: float = 0.0
     t_u: float = 0.0
     grad_bytes: Sequence[float] | None = None   # per layer, for bucketing
+    t_a2a: Sequence[float] | None = None        # one all-to-all, per layer
+    t_ce: Sequence[float] | None = None         # expert-gradient all-reduce
+    expert_bytes: Sequence[float] | None = None  # its per-device payload
 
     @property
     def num_layers(self) -> int:
         return len(self.t_f)
+
+    def folded(self) -> "IterationCosts":
+        """The costs as the closed forms read them: each layer's four
+        all-to-alls inside its compute (``t_f + 2 t_a2a``, ``t_b + 2
+        t_a2a``) and its two all-reduces as one comm term (``t_c +
+        t_ce``).  Exact for the steady state: the all-to-alls sit on the
+        compute chain, and the two all-reduces share a release time and
+        run back to back on the one ``net`` channel.  Costs without
+        expert parallelism come back as they are."""
+        if self.t_a2a is None:
+            return self
+        a2a = 2.0 * np.asarray(self.t_a2a)
+        return dataclasses.replace(
+            self, t_f=np.asarray(self.t_f) + a2a,
+            t_b=np.asarray(self.t_b) + a2a,
+            t_c=np.asarray(self.t_c) + np.asarray(self.t_ce),
+            grad_bytes=None if self.grad_bytes is None
+            else np.asarray(self.grad_bytes) + np.asarray(self.expert_bytes),
+            t_a2a=None, t_ce=None, expert_bytes=None)
 
     def with_comm(self, t_c: Sequence[float],
                   grad_bytes: Sequence[float] | None = None) -> "IterationCosts":
@@ -183,6 +223,12 @@ class IterationCosts:
             raise ValueError("t_f, t_b, t_c must have equal length")
         if self.grad_bytes is not None and len(self.grad_bytes) != len(self.t_f):
             raise ValueError("grad_bytes length mismatch")
+        ep = [x is None for x in (self.t_a2a, self.t_ce, self.expert_bytes)]
+        if any(ep) and not all(ep):
+            raise ValueError("t_a2a, t_ce and expert_bytes come together")
+        if not any(ep) and not (len(self.t_a2a) == len(self.t_ce)
+                                == len(self.expert_bytes) == len(self.t_f)):
+            raise ValueError("t_a2a, t_ce, expert_bytes length mismatch")
 
 
 def _bucketize(costs: IterationCosts, policy: Policy,
@@ -195,6 +241,12 @@ def _bucketize(costs: IterationCosts, policy: Policy,
     durations are re-derived via ``comm_scale(total_bytes, total_time)``
     when byte sizes are known, else summed.
 
+    Under expert parallelism (``costs.t_ce`` set) a layer has
+    communication if it has gradient bytes at all, buckets close on the
+    per-device payload ``grad_bytes + expert_bytes``, and ``duration``
+    is the bucket's dense all-reduce; :func:`_expert_duration` gives its
+    expert one.
+
     Boundaries come from the shared
     :func:`repro.core.bucketsim.bucket_partition` — the one boundary
     rule this builder and the batched timeline kernel both consume, so
@@ -202,21 +254,42 @@ def _bucketize(costs: IterationCosts, policy: Policy,
     """
     from repro.core.bucketsim import bucket_partition  # circular-safe
 
+    mask, payload = [c > 0 for c in costs.t_c], costs.grad_bytes
+    if costs.t_ce is not None:
+        mask = [c + e > 0 for c, e in zip(costs.t_c, costs.t_ce)]
+        if payload is not None:
+            payload = [g + e for g, e in zip(payload, costs.expert_bytes)]
+            mask = [p > 0 for p in payload]
     if not policy.bucket_bytes:
         return [(f"comm_l{m + 1}", [m], costs.t_c[m])
-                for [m] in bucket_partition(
-                    [c > 0 for c in costs.t_c], None, None)]
+                for [m] in bucket_partition(mask, None, None)]
 
     buckets: list[tuple[str, list[int], float]] = []
-    for members in bucket_partition([c > 0 for c in costs.t_c],
-                                    costs.grad_bytes, policy.bucket_bytes):
-        cur_time = sum(costs.t_c[m] for m in members)
-        cur_bytes = sum(costs.grad_bytes[m] for m in members) \
-            if costs.grad_bytes is not None else 0.0
-        dur = comm_scale(cur_bytes, cur_time) \
-            if (comm_scale and cur_bytes) else cur_time
-        buckets.append((f"comm_bucket{len(buckets)}", members, dur))
+    for members in bucket_partition(mask, payload, policy.bucket_bytes):
+        buckets.append((f"comm_bucket{len(buckets)}", members,
+                        _fused(comm_scale, costs.grad_bytes, costs.t_c,
+                               members)))
     return buckets
+
+
+def _fused(scale, nbytes, times, members) -> float:
+    """One collective over the bucket ``members``: ``scale(total_bytes,
+    total_time)`` when byte sizes are known, else the summed times."""
+    cur_time = sum(times[m] for m in members)
+    cur_bytes = sum(nbytes[m] for m in members) if nbytes is not None \
+        else 0.0
+    return scale(cur_bytes, cur_time) if (scale and cur_bytes) else cur_time
+
+
+def _expert_duration(costs: IterationCosts, members: list[int],
+                     policy: Policy,
+                     scale: Callable[[float, float], float] | None) -> float:
+    """The expert all-reduce of a bucket from :func:`_bucketize`: its
+    layers' ``t_ce`` without fusion, else one collective over their
+    ``expert_bytes`` (``scale`` costs it over the expert group)."""
+    if not policy.bucket_bytes:
+        return costs.t_ce[members[0]]
+    return _fused(scale, costs.expert_bytes, costs.t_ce, members)
 
 
 class SSGDDagBuilder:
@@ -233,6 +306,7 @@ class SSGDDagBuilder:
 
     def __init__(self, costs: IterationCosts, n_workers: int, policy: Policy,
                  comm_scale: Callable[[float, float], float] | None = None,
+                 expert_comm_scale: Callable[[float, float], float] | None = None,
                  shared_compute: bool = False,
                  worker_scale: Sequence[float] | None = None,
                  sync_k: int | None = None,
@@ -269,6 +343,16 @@ class SSGDDagBuilder:
         # bucket boundaries depend only on (costs, policy, comm_scale)
         self._buckets = _bucketize(costs, policy, comm_scale) \
             if n_workers > 1 else []
+        self._expert_durs = None if costs.t_ce is None else [
+            _expert_duration(costs, members, policy, expert_comm_scale)
+            for _, members, _ in self._buckets]
+        # all-to-all layers (expert parallelism): each is a barrier
+        # across every worker, so K-of-N partial sync cannot skip one
+        self._a2a = [] if costs.t_a2a is None \
+            else [l for l, t in enumerate(costs.t_a2a) if t > 0]
+        if self._a2a and sync_k and int(sync_k) < n_workers:
+            raise ValueError("K-of-N partial sync with all-to-alls: every "
+                             "all-to-all waits for all workers")
         # K-of-N partial synchronization: the aggregation and the model
         # update gate on the K *fastest* workers only (smallest
         # compute multiplier, ties broken by worker index — exactly the
@@ -330,6 +414,9 @@ class SSGDDagBuilder:
             h2d_tasks.append(h2d)
 
         # --- forward, layer 1..L ---------------------------------------
+        # A worker's chain breaks after each all-to-all layer: the
+        # layer's dispatch and combine join the workers there.
+        a2a = set(self._a2a)
         scale = self._worker_scale
         fwd: list[list[int]] = [[] for _ in range(L)]
         for w in range(self.n_workers):
@@ -340,13 +427,22 @@ class SSGDDagBuilder:
                                costs.t_f[l] * ws, self._gpu_of(w),
                                iteration=it,
                                layer=l + 1, worker=w, priority=float(l))
-                g.add_edge(prev, t)
+                if prev is not None:
+                    g.add_edge(prev, t)
                 if l == 0 and prev_update is not None:
                     g.add_edge(prev_update, t)
                 fwd[l].append(t)
-                prev = t
+                prev = None if l in a2a else t
+        after_fwd = {l: self._all_to_all("fwd", l, fwd[l], it)
+                     for l in self._a2a}
+        for l, last in after_fwd.items():
+            if l + 1 < L:
+                for t in fwd[l + 1]:
+                    g.add_edge(last, t)
 
         # --- backward, layer L..1 --------------------------------------
+        # An all-to-all layer's backward starts with the gradients of
+        # its combine and dispatch, which wait for every worker.
         bwd: dict[int, list[int]] = {}
         for w in range(self.n_workers):
             ws = 1.0 if scale is None else scale[w]
@@ -357,9 +453,15 @@ class SSGDDagBuilder:
                                iteration=it,
                                layer=l + 1, worker=w,
                                priority=float(2 * L - l))
-                g.add_edge(prev, t)
+                if l not in a2a:
+                    g.add_edge(prev, t)
                 bwd.setdefault(l, []).append(t)
                 prev = t
+        for l in self._a2a:
+            preds = bwd[l + 1] if l + 1 < L else [after_fwd[l]]
+            last = self._all_to_all("bwd", l, preds, it)
+            for t in bwd[l]:
+                g.add_edge(last, t)
         last_bwd = [bwd[0][w] for w in range(self.n_workers)]  # layer 1 last
         # Partial sync: only the K participants' gradients gate the
         # aggregation and the update.  Non-participants keep training
@@ -372,30 +474,37 @@ class SSGDDagBuilder:
         # --- gradient aggregation (comm tasks T32-T34) -----------------
         comm_tasks: list[int] = []
         prev_comm: int | None = None
-        for bname, members, dur in self._buckets:
+        for j, (bname, members, dur) in enumerate(self._buckets):
             # ByteScheduler semantics (policies.py): priority is the
             # bucket's earliest layer — layer-1/earlier-needed
             # tensors overtake on a priority-scheduled net channel
             # (lower value = scheduled first).  ``members`` is in
             # backward order, so the earliest layer is members[-1].
-            c = g.add_task(bname, TaskKind.COMM, dur, NET_CHANNEL,
-                           iteration=it, layer=members[0] + 1,
-                           priority=float(members[-1]),
-                           nbytes=sum(costs.grad_bytes[m] for m in members)
-                           if costs.grad_bytes is not None else 0.0)
-            if policy.overlap_comm:
-                # WFBP: ready as soon as every participating worker
-                # finished the backward of every member layer.
-                for m in members:
-                    g.add_edges(bwd[m] if sync is None
-                                else [bwd[m][w] for w in sync], c)
-            else:
-                # CNTK: aggregation only after the entire backward pass.
-                g.add_edges(sync_last_bwd, c)
-            if prev_comm is not None and policy.serialize_comm:
-                g.add_edge(prev_comm, c)
-            prev_comm = c
-            comm_tasks.append(c)
+            # Under expert parallelism the bucket's expert all-reduce
+            # follows its dense one as a second task.
+            parts = [(bname, dur, costs.grad_bytes)]
+            if self._expert_durs is not None:
+                parts.append((bname + "_experts", self._expert_durs[j],
+                              costs.expert_bytes))
+            for name, d, nbytes in parts:
+                c = g.add_task(name, TaskKind.COMM, d, NET_CHANNEL,
+                               iteration=it, layer=members[0] + 1,
+                               priority=float(members[-1]),
+                               nbytes=sum(nbytes[m] for m in members)
+                               if nbytes is not None else 0.0)
+                if policy.overlap_comm:
+                    # WFBP: ready as soon as every participating worker
+                    # finished the backward of every member layer.
+                    for m in members:
+                        g.add_edges(bwd[m] if sync is None
+                                    else [bwd[m][w] for w in sync], c)
+                else:
+                    # CNTK: aggregation only after the entire backward.
+                    g.add_edges(sync_last_bwd, c)
+                if prev_comm is not None and policy.serialize_comm:
+                    g.add_edge(prev_comm, c)
+                prev_comm = c
+                comm_tasks.append(c)
 
         # --- checkpoint restores (crash/recover events) ----------------
         # A crashed worker re-reads the checkpoint before the update may
@@ -430,6 +539,25 @@ class SSGDDagBuilder:
         self.n_iterations += 1
         return upd
 
+    def _all_to_all(self, phase: str, layer: int, preds: list[int],
+                    it: int) -> int:
+        """The two all-to-alls of ``layer`` in ``phase`` (``"fwd"``:
+        dispatch then combine; ``"bwd"``: their gradients in reverse)
+        as a chain on :data:`EP_CHANNEL` after every task of ``preds``;
+        returns the second, which the workers' next compute waits for."""
+        g, L = self.dag, self.costs.num_layers
+        prio = float(layer) if phase == "fwd" else float(2 * L - layer)
+        names = ("dispatch", "combine") if phase == "fwd" \
+            else ("combine", "dispatch")
+        prev = None
+        for name in names:
+            t = g.add_task(f"a2a_{phase}_{name}_l{layer + 1}", TaskKind.COMM,
+                           self.costs.t_a2a[layer], EP_CHANNEL,
+                           iteration=it, layer=layer + 1, priority=prio)
+            g.add_edges(preds if prev is None else [prev], t)
+            prev = t
+        return prev
+
 
 def build_ssgd_dag(
     costs: IterationCosts,
@@ -437,6 +565,7 @@ def build_ssgd_dag(
     policy: Policy,
     n_iterations: int = 1,
     comm_scale: Callable[[float, float], float] | None = None,
+    expert_comm_scale: Callable[[float, float], float] | None = None,
     shared_compute: bool = False,
     worker_scale: Sequence[float] | None = None,
     sync_k: int | None = None,
@@ -450,7 +579,9 @@ def build_ssgd_dag(
 
     ``comm_scale(total_bytes, naive_total_time)`` maps a fused bucket to
     its collective duration (used by the bucketing policy to model the
-    latency amortization the paper calls for in §VII).
+    latency amortization the paper calls for in §VII);
+    ``expert_comm_scale`` does the same for a bucket's expert bytes
+    over the expert group, under expert parallelism.
     ``worker_scale`` gives per-worker compute-time multipliers
     (heterogeneous GPUs / straggler jitter draws) — the per-worker DAG
     is the agreement oracle for the heterogeneous batched engine.
@@ -459,6 +590,7 @@ def build_ssgd_dag(
     checkpoint restore before each iteration's update.
     """
     b = SSGDDagBuilder(costs, n_workers, policy, comm_scale=comm_scale,
+                       expert_comm_scale=expert_comm_scale,
                        shared_compute=shared_compute,
                        worker_scale=worker_scale, sync_k=sync_k,
                        crashed=crashed, restart_s=restart_s)

@@ -267,6 +267,23 @@ def hierarchical_allreduce_coeffs(n, gpus_per_node,
     return per_byte + ring_byte / gf, per_message + ring_message
 
 
+def alltoall_coeffs(n, bandwidth, latency):
+    """``(per_byte, per_message)`` of an all-to-all over ``n`` ranks of
+    the bytes each rank holds (MoE dispatch or combine):
+    ``(n-1)/n / B`` and ``(n-1) alpha`` — each rank keeps ``1/n`` of its
+    payload and sends the rest as ``n - 1`` messages — zeroed where
+    ``n <= 1``.  :meth:`ClusterSpec.alltoall_time` is this pair applied
+    to a payload, and the batched kernels apply it to the suffix tables
+    of all-to-all bytes."""
+    xp = array_namespace(n, bandwidth, latency)
+    n = xp.asarray(n, dtype=xp.float64)
+    live = n > 1
+    safe_n = xp.where(live, n, 2.0)
+    per_byte = (safe_n - 1) / safe_n / bandwidth * live
+    per_message = (safe_n - 1) * latency * live
+    return per_byte, per_message
+
+
 @dataclass(frozen=True)
 class DeviceSpec:
     name: str
@@ -381,15 +398,29 @@ class ClusterSpec:
         """Ring all-gather — same alpha-beta cost as reduce-scatter."""
         return self.reduce_scatter_time(nbytes, n_workers)
 
-    def alltoall_time(self, nbytes: float, n_workers: int | None = None) -> float:
-        """All-to-all of ``nbytes`` bytes held per device (MoE dispatch),
-        in seconds."""
+    def alltoall_time(self, nbytes, n_workers: int | None = None):
+        """All-to-all of ``nbytes`` bytes held per device (MoE dispatch
+        or combine) over ``n_workers`` contiguous devices, in seconds:
+        :func:`alltoall_coeffs` on the bottleneck link (intra-node while
+        the group fits in a node).  ``nbytes`` may be an array."""
         n = self.total_devices if n_workers is None else n_workers
         if n <= 1:
-            return 0.0
+            return nbytes * 0.0
         link = self._bottleneck(n)
-        return (n - 1) / n * nbytes / link.effective_bandwidth \
-            + (n - 1) * link.latency
+        per_byte, per_message = alltoall_coeffs(
+            n, link.effective_bandwidth, link.latency)
+        return per_byte * nbytes + per_message
+
+    def expert_group(self, ep: int) -> "ClusterSpec":
+        """The cluster as an expert-gradient group sees it under expert
+        parallelism of degree ``ep``: the ``n / ep`` ranks that hold the
+        same experts are strided ``ep`` apart, so a node holds
+        ``max(1, gpus_per_node // ep)`` of them.  Collectives over the
+        group run on this copy; ``ep = 1`` returns the cluster."""
+        if ep <= 1:
+            return self
+        return dataclasses.replace(
+            self, gpus_per_node=max(1, self.gpus_per_node // ep))
 
     # ------------------------------------------------------------------
     # Elementary task models (the paper's Table I vocabulary)

@@ -29,13 +29,14 @@ import numpy as np
 #: ``sync_k`` / ``faults`` the failure-model axes (``sync_k = 0`` means
 #: full synchronization, a positive K means the iteration waits for the
 #: first K of N gradients; ``faults`` is the ``fail:`` spec label,
-#: ``"none"`` when unused); ``t_mean_s``/``t_p95_s``/``t_p99_s`` are
+#: ``"none"`` when unused); ``ep_size`` the expert-parallel group size
+#: (1 = no expert parallelism); ``t_mean_s``/``t_p95_s``/``t_p99_s`` are
 #: the Monte Carlo tail statistics of the iteration time — equal to
 #: ``iteration_time_s`` on deterministic rows (a point mass has no
 #: tails).
 COLUMNS = ("workload", "cluster", "n_workers", "policy", "collective",
            "interconnect", "het", "straggler", "sync_k", "faults",
-           "batch_per_gpu",
+           "ep_size", "batch_per_gpu",
            "iteration_time_s", "samples_per_sec", "speedup",
            "t_comm_s", "t_comp_s", "t_mean_s", "t_p95_s", "t_p99_s",
            "method")
@@ -47,7 +48,7 @@ LABEL_COLUMNS = ("workload", "cluster", "policy", "collective",
                  "interconnect", "het", "straggler", "faults", "method")
 
 #: Integer-valued columns (int64).
-INT_COLUMNS = ("n_workers", "sync_k", "batch_per_gpu")
+INT_COLUMNS = ("n_workers", "sync_k", "ep_size", "batch_per_gpu")
 
 #: Float-valued columns (float64).
 FLOAT_COLUMNS = ("iteration_time_s", "samples_per_sec", "speedup",

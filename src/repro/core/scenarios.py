@@ -63,6 +63,41 @@ def validate_sync_k(sync_k: int | None) -> None:
         raise ValueError(f"sync_k must be >= 0 (0 = full sync), got {k}")
 
 
+def validate_ep(workload: str, cluster: str, n_workers: int, ep: int,
+                sync_k: int | None = None) -> None:
+    """Raise ``ValueError`` unless expert parallelism of degree ``ep``
+    fits the deployment.  An EP group is ``ep`` contiguous ranks, inside
+    a node while ``ep <= gpus_per_node``; so ``ep`` divides
+    ``n_workers`` and the workload's routed-expert count, and either
+    ``gpus_per_node % ep == 0`` or ``ep % gpus_per_node == 0``.  ``ep >
+    1`` needs routed experts, and full synchronization: every
+    all-to-all waits for every rank, so K-of-N partial sync cannot
+    leave a rank out."""
+    from repro.core.workloads import resolve_workload
+
+    if int(ep) != ep or ep < 1:
+        raise ValueError(f"ep_size must be a positive int, got {ep!r}")
+    if ep == 1:
+        return
+    experts = resolve_workload(workload).routed_experts
+    if not experts:
+        raise ValueError(f"ep_size {ep} needs routed experts; workload "
+                         f"{workload!r} has none")
+    gpn = CLUSTERS[cluster].gpus_per_node
+    if n_workers % ep or experts % ep:
+        raise ValueError(
+            f"ep_size {ep} must divide n_workers ({n_workers}) and the "
+            f"routed experts of {workload!r} ({experts})")
+    if gpn % ep and ep % gpn:
+        raise ValueError(
+            f"ep_size {ep} and {cluster!r}'s {gpn} devices a node must "
+            f"divide one another")
+    if normalize_sync_k(sync_k):
+        raise ValueError(
+            f"ep_size {ep} needs full synchronization (sync_k none): "
+            f"every all-to-all waits for every rank")
+
+
 def validate_interconnect(interconnect: str | None) -> None:
     """Raise ``ValueError`` unless ``interconnect`` is ``None``,
     ``"default"``, a preset name, or a scaled preset
@@ -90,6 +125,8 @@ class Scenario:
     :data:`repro.core.hardware.INTERCONNECT_PRESETS`; ``batch_per_gpu``
     ``None`` means the workload's default (Table IV for CNNs, the
     measured batch for traces, one sequence for LLM configs).
+    ``ep_size`` is the expert-parallel group size (1: none; see
+    :func:`validate_ep` and :class:`repro.core.dag.IterationCosts`).
     """
 
     workload: str
@@ -103,11 +140,14 @@ class Scenario:
     sync_k: int | None = None
     faults: str | None = None
     batch_per_gpu: int | None = None
+    ep_size: int = 1
 
     def label(self) -> str:
         ic = normalize_interconnect(self.interconnect)
         label = (f"{self.workload}/{self.cluster}/w{self.n_workers}"
                  f"/{self.policy}/{self.collective}/{ic}")
+        if self.ep_size != 1:
+            label += f"/ep{self.ep_size}"
         if self.het is not None and self.het != "none":
             label += f"/{self.het}"
         if self.straggler is not None and self.straggler != "none":
@@ -142,6 +182,8 @@ class Scenario:
         if self.batch_per_gpu is not None and self.batch_per_gpu < 1:
             raise ValueError(f"batch_per_gpu must be >= 1, "
                              f"got {self.batch_per_gpu}")
+        validate_ep(self.workload, self.cluster, self.n_workers,
+                    self.ep_size, self.sync_k)
 
 
 def apply_het_links(cluster: ClusterSpec, bw_mult: float,
@@ -202,10 +244,13 @@ class ScenarioGrid:
     sync_ks: Sequence[int | None] = (None,)
     faults: Sequence[str | None] = (None,)
     batch_per_gpu: int | None = None
+    #: Expert-parallel group sizes; expands right after ``worker_counts``.
+    ep_sizes: Sequence[int] = (1,)
 
     def __len__(self) -> int:
         return (len(self.workloads) * len(self.clusters)
-                * len(self.worker_counts) * len(self.policies)
+                * len(self.worker_counts) * len(self.ep_sizes)
+                * len(self.policies)
                 * len(self.collectives) * len(self.interconnects)
                 * len(self.het_profiles) * len(self.stragglers)
                 * len(self.sync_ks) * len(self.faults))
@@ -214,10 +259,13 @@ class ScenarioGrid:
         return iter(self.expand())
 
     def validate_axes(self) -> None:
-        """Validate every axis *value* once.  Scenario validity is
-        axis-separable (no cross-field constraints), so this is
-        equivalent to validating all ``len(self)`` scenarios — which is
-        exactly why ``expand()`` can skip per-scenario validation."""
+        """Validate every axis *value* once, and the one cross-field
+        rule, expert parallelism's (:func:`validate_ep`), once per
+        ``(workload, cluster, n_workers, ep_size, sync_k)``
+        combination; everything else about a scenario is
+        axis-separable.  This is equivalent to validating all
+        ``len(self)`` scenarios — which is exactly why ``expand()`` can
+        skip per-scenario validation."""
         if self.batch_per_gpu is not None and self.batch_per_gpu < 1:
             raise ValueError(f"batch_per_gpu must be >= 1, "
                              f"got {self.batch_per_gpu}")
@@ -248,19 +296,25 @@ class ScenarioGrid:
             validate_sync_k(k)
         for f in self.faults:
             het_mod.validate_fault(f)
+        if any(ep != 1 for ep in self.ep_sizes):
+            for wl, cl, n, ep, sk in itertools.product(
+                    self.workloads, self.clusters, self.worker_counts,
+                    self.ep_sizes, self.sync_ks):
+                validate_ep(wl, cl, int(n), ep, sk)
 
     def expand(self) -> list[Scenario]:
         self.validate_axes()
         return [Scenario(workload=wl, cluster=cl, n_workers=int(n),
                          policy=pol, collective=coll, interconnect=ic,
                          het=h, straggler=st, sync_k=sk, faults=fl,
-                         batch_per_gpu=self.batch_per_gpu)
-                for wl, cl, n, pol, coll, ic, h, st, sk, fl
+                         batch_per_gpu=self.batch_per_gpu,
+                         ep_size=int(ep))
+                for wl, cl, n, ep, pol, coll, ic, h, st, sk, fl
                 in itertools.product(
                     self.workloads, self.clusters, self.worker_counts,
-                    self.policies, self.collectives, self.interconnects,
-                    self.het_profiles, self.stragglers, self.sync_ks,
-                    self.faults)]
+                    self.ep_sizes, self.policies, self.collectives,
+                    self.interconnects, self.het_profiles, self.stragglers,
+                    self.sync_ks, self.faults)]
 
     def scenario_at(self, i: int) -> Scenario:
         """Materialize the scenario at flat ``expand()`` index ``i``
@@ -270,11 +324,11 @@ class ScenarioGrid:
         codes = []
         for axis in (self.faults, self.sync_ks, self.stragglers,
                      self.het_profiles, self.interconnects,
-                     self.collectives, self.policies, self.worker_counts,
-                     self.clusters, self.workloads):
+                     self.collectives, self.policies, self.ep_sizes,
+                     self.worker_counts, self.clusters, self.workloads):
             i, c = divmod(i, len(axis))
             codes.append(c)
-        fi, qi, sti, hi, ii, ai, pi, ki, ci, wi = codes
+        fi, qi, sti, hi, ii, ai, pi, ei, ki, ci, wi = codes
         return Scenario(workload=self.workloads[wi],
                         cluster=self.clusters[ci],
                         n_workers=int(self.worker_counts[ki]),
@@ -285,7 +339,8 @@ class ScenarioGrid:
                         straggler=self.stragglers[sti],
                         sync_k=self.sync_ks[qi],
                         faults=self.faults[fi],
-                        batch_per_gpu=self.batch_per_gpu)
+                        batch_per_gpu=self.batch_per_gpu,
+                        ep_size=int(self.ep_sizes[ei]))
 
 def default_grid() -> ScenarioGrid:
     """The out-of-the-box study: every paper workload and cluster, six

@@ -270,6 +270,7 @@ def simulate_policy(
     auto_steady: bool = False,
     rtol: float = STEADY_RTOL,
     worker_scale=None,
+    expert_comm_scale: Callable[[float, float], float] | None = None,
     sync_k: int | None = None,
     crashed: tuple = (),
     restart_s: float = 0.0,
@@ -294,6 +295,7 @@ def simulate_policy(
     """
     builder = SSGDDagBuilder(costs, n_workers, policy,
                              comm_scale=comm_scale,
+                             expert_comm_scale=expert_comm_scale,
                              worker_scale=worker_scale, sync_k=sync_k,
                              crashed=crashed, restart_s=restart_s)
     prio = frozenset([NET_CHANNEL]) if getattr(policy, "priority_comm", False) \
@@ -322,6 +324,7 @@ def simulate_steady(
     sync_k: int | None = None,
     crashed: tuple = (),
     restart_s: float = 0.0,
+    expert_comm_scale: Callable[[float, float], float] | None = None,
 ) -> float:
     """:func:`simulate_policy`, reduced to the warm per-iteration time
     in seconds.  Auto-detects the steady state: the warm-up stops as
@@ -329,6 +332,8 @@ def simulate_steady(
     as the cap (the historical fixed warm-up count)."""
     return simulate_policy(costs, n_workers, policy, n_iterations,
                            comm_scale, auto_steady=True,
-                           worker_scale=worker_scale, sync_k=sync_k,
+                           worker_scale=worker_scale,
+                           expert_comm_scale=expert_comm_scale,
+                           sync_k=sync_k,
                            crashed=crashed, restart_s=restart_s) \
         .steady_iteration_time()

@@ -106,7 +106,8 @@ def _scenario_costs(s: Scenario, tab: WorkloadTable):
     cluster = resolve_cluster(s)
     policy = resolve_policy(s)
     batch = s.batch_per_gpu or tab.batch_default
-    costs = tab.iteration_costs(cluster, batch, s.n_workers, s.collective)
+    costs = tab.iteration_costs(cluster, batch, s.n_workers, s.collective,
+                                ep=s.ep_size)
     return costs, cluster, policy, batch
 
 
@@ -115,7 +116,9 @@ def _scale_compute(costs, tmul: float):
     synchronous steady state with per-worker compute multipliers equals
     the homogeneous closed form with ``t_f``/``t_b`` scaled by the
     bottleneck multiplier (``t_io``/``t_h2d``/``t_c``/``t_u`` are not
-    compute-rate-bound and stay put)."""
+    compute-rate-bound and stay put, nor are the all-to-alls, which
+    every worker waits for: the slowest gates each stretch between
+    them)."""
     return replace(costs, t_f=np.asarray(costs.t_f) * tmul,
                    t_b=np.asarray(costs.t_b) * tmul)
 
@@ -192,8 +195,8 @@ def _fast_eval(s: Scenario, seed: int = 0) -> dict:
     costs0, _, policy, batch = _scenario_costs(s, resolve_workload(s.workload))
     inv, st = _het_state(s)
     sk = normalize_sync_k(s.sync_k)
-    costs = costs0 if inv is None else _scale_compute(
-        costs0, float(_kth_tmul(inv[None, :], sk)[0]))
+    costs = (costs0 if inv is None else _scale_compute(
+        costs0, float(_kth_tmul(inv[None, :], sk)[0]))).folded()
     t_iter = float(analytical.closed_form(costs, policy))
     t1 = float(analytical.closed_form(
         costs.with_comm(np.zeros_like(costs.t_f)), policy))
@@ -209,8 +212,8 @@ def _fast_eval(s: Scenario, seed: int = 0) -> dict:
         pens = np.zeros(D) if cm is None else ft.restart * cm.sum(axis=1)
         tails = _ref_tails([
             float(analytical.closed_form(
-                replace(_scale_compute(costs0, m), t_u=costs0.t_u + p),
-                policy))
+                replace(_scale_compute(costs0, m),
+                        t_u=costs0.t_u + p).folded(), policy))
             for m, p in zip(tmuls, pens)])
     return _row(s, batch, t_iter, t1, float(np.sum(costs.t_c)),
                 float(np.sum(costs.t_f) + np.sum(costs.t_b)), "analytical",
@@ -236,17 +239,22 @@ def _sim_eval(s: Scenario, warm_iterations: int = 6, seed: int = 0) -> dict:
     sk = normalize_sync_k(s.sync_k)
     comm_scale = comm_scale_fn(cluster, s.n_workers, s.collective) \
         if policy.bucket_bytes else None
+    expert_scale = comm_scale_fn(
+        cluster.expert_group(s.ep_size), s.n_workers // s.ep_size,
+        s.collective) if policy.bucket_bytes and s.ep_size > 1 else None
     t_iter = simulate_steady(costs, s.n_workers, policy,
                              n_iterations=warm_iterations,
                              comm_scale=comm_scale,
+                             expert_comm_scale=expert_scale,
                              worker_scale=inv,
                              sync_k=sk or None)
     # weak-scaling baseline: same pipeline, one worker, no comm — with
-    # the same bottleneck compute rate, matching the batched speedup
+    # the same bottleneck compute rate, matching the batched speedup;
+    # the all-to-alls stay in the compute, as in the batched engine
     base_policy = replace(policy, bucket_bytes=None, priority_comm=False)
-    c1 = costs.with_comm([0.0] * costs.num_layers)
-    if inv is not None:
-        c1 = _scale_compute(c1, float(_kth_tmul(inv[None, :], sk)[0]))
+    c1 = costs if inv is None else _scale_compute(
+        costs, float(_kth_tmul(inv[None, :], sk)[0]))
+    c1 = c1.folded().with_comm([0.0] * costs.num_layers)
     t1 = analytical.closed_form(c1, base_policy)
     if t1 is None:                                    # pragma: no cover
         t1 = simulate_steady(c1, 1, base_policy, n_iterations=warm_iterations)
@@ -264,13 +272,15 @@ def _sim_eval(s: Scenario, warm_iterations: int = 6, seed: int = 0) -> dict:
             simulate_steady(costs, s.n_workers, policy,
                             n_iterations=warm_iterations,
                             comm_scale=comm_scale,
+                            expert_comm_scale=expert_scale,
                             worker_scale=m,
                             sync_k=sk or None,
                             crashed=crashed,
                             restart_s=0.0 if ft is None else ft.restart)
             for m, crashed in zip(mul, crash_sets)])
-    return _row(s, batch, t_iter, t1, float(np.sum(costs.t_c)),
-                float(np.sum(costs.t_f) + np.sum(costs.t_b)), "simulated",
+    folded = costs.folded()
+    return _row(s, batch, t_iter, t1, float(np.sum(folded.t_c)),
+                float(np.sum(folded.t_f) + np.sum(folded.t_b)), "simulated",
                 tails=tails)
 
 
@@ -290,6 +300,7 @@ def _row(s: Scenario, batch: int, t_iter: float, t1: float, t_comm: float,
         "straggler": het_mod.normalize_straggler(s.straggler),
         "sync_k": normalize_sync_k(s.sync_k),
         "faults": het_mod.normalize_fault(s.faults),
+        "ep_size": s.ep_size,
         "batch_per_gpu": batch,
         "iteration_time_s": t_iter,
         "samples_per_sec": s.n_workers * batch / t_iter if t_iter else 0.0,

@@ -77,6 +77,13 @@ class WorkloadTable:
     (f32 for CNN tables, bf16 for LLM configs, verbatim for traces),
     which is what lets every source sweep across worker counts,
     collectives and interconnects.
+
+    Expert parallelism reads three more fields, all zero for a table
+    without routed experts: ``routed_bytes`` (the routed experts' part
+    of ``grad_bytes``, which an EP group of ``ep`` ranks shards),
+    ``a2a_bytes_per_sample`` (what one all-to-all of a layer moves per
+    device per sample: the layer's dispatch or combine payload) and
+    ``routed_experts`` (the count ``ep`` must divide).
     """
 
     name: str
@@ -90,6 +97,16 @@ class WorkloadTable:
     t_io_measured: float | None = None    # measured input-pipeline seconds
     bwd_fwd_ratio: float = 2.0
     batch_locked: bool = False        # True: measured batch unknown, no rescale
+    routed_bytes: np.ndarray | None = None          # (L,) EP-sharded part
+    a2a_bytes_per_sample: np.ndarray | None = None  # (L,) one all-to-all
+    routed_experts: int = 0
+
+    def __post_init__(self):
+        zeros = np.zeros(len(self.grad_bytes))
+        if self.routed_bytes is None:
+            object.__setattr__(self, "routed_bytes", zeros)
+        if self.a2a_bytes_per_sample is None:
+            object.__setattr__(self, "a2a_bytes_per_sample", zeros)
 
     @property
     def num_layers(self) -> int:
@@ -103,10 +120,22 @@ class WorkloadTable:
                         n_workers: int, collective: str = "ring",
                         bwd_fwd_ratio: float | None = None,
                         bytes_per_sample: float | None = None,
-                        decode_seconds_per_byte: float = 0.0) -> IterationCosts:
+                        decode_seconds_per_byte: float = 0.0,
+                        ep: int = 1) -> IterationCosts:
         """The paper's Table-I cost vocabulary (seconds) on a concrete
         cluster — the one construction path used by both the analytical
         fast path and the simulator fallback, so the two cannot drift.
+
+        ``ep > 1`` (expert parallelism, :mod:`repro.core.scenarios`)
+        splits each layer's gradient: ``t_c`` all-reduces the dense
+        part over all ``n_workers``, ``t_ce`` the routed part
+        (``routed_bytes / ep`` per device) over the ``n_workers / ep``
+        ranks that hold the same experts
+        (:meth:`~repro.core.hardware.ClusterSpec.expert_group`);
+        ``t_a2a`` is one all-to-all of ``batch *
+        a2a_bytes_per_sample`` over the ``ep`` ranks of an EP group, and
+        ``t_u`` updates the per-device parameters.  ``ep = 1`` returns
+        the costs without any of that, exactly as before.
 
         ``bytes_per_sample`` overrides the table's own;
         ``bwd_fwd_ratio`` and ``decode_seconds_per_byte`` work exactly
@@ -144,10 +173,25 @@ class WorkloadTable:
                 else bwd_fwd_ratio
             t_f = cluster.compute_time(self.flops_fwd * batch_per_gpu)
             t_b = ratio * t_f
+        grad, param_bytes, ep_costs = self.grad_bytes, self.param_bytes, {}
+        if ep > 1:
+            grad = self.grad_bytes - self.routed_bytes
+            expert = self.routed_bytes / ep
+            routed = float(self.routed_bytes.sum())
+            param_bytes = self.param_bytes - routed + routed / ep
+            group = n_workers // ep
+            t_ce = np.where(
+                expert > 0, cluster.expert_group(ep).allreduce_time(
+                    expert, group, collective), 0.0) \
+                if group > 1 else np.zeros_like(t_f)
+            a2a = batch_per_gpu * self.a2a_bytes_per_sample
+            ep_costs = dict(
+                t_ce=t_ce, expert_bytes=expert,
+                t_a2a=np.where(a2a > 0, cluster.alltoall_time(a2a, ep), 0.0))
         if n_workers > 1:
             t_c = np.where(
-                self.grad_bytes > 0,
-                cluster.allreduce_time(self.grad_bytes, n_workers, collective),
+                grad > 0,
+                cluster.allreduce_time(grad, n_workers, collective),
                 0.0)
         else:
             t_c = np.zeros_like(t_f)
@@ -163,8 +207,8 @@ class WorkloadTable:
             t_f=t_f, t_b=t_b, t_c=t_c,
             t_io=t_io,
             t_h2d=cluster.h2d_time(nbytes_in),
-            t_u=update_time(self.param_bytes, cluster),
-            grad_bytes=self.grad_bytes)
+            t_u=update_time(param_bytes, cluster),
+            grad_bytes=grad, **ep_costs)
 
 
 @runtime_checkable
@@ -363,24 +407,42 @@ class LLMProvider:
 
     def build(self, spec: str) -> WorkloadTable:
         from repro.configs import get_config
-        from repro.core.archcost import block_cost_table
 
         try:
             cfg = get_config(spec)
         except KeyError as e:
             raise ValueError(str(e)) from None
-        blocks = block_cost_table(cfg, LLM_SEQ_LEN)
-        # bf16 gradient payloads over *total* params (every expert's
-        # gradient is all-reduced, not just the routed-active ones);
-        # compute from *active* params, matching archcost.step_cost.
-        return WorkloadTable(
-            name=f"llm:{spec}",
-            flops_fwd=np.array([b.flops_fwd for b in blocks], dtype=np.float64),
-            grad_bytes=np.array([2.0 * b.params for b in blocks],
-                                dtype=np.float64),
-            batch_default=1,
-            bytes_per_sample=LLM_BYTES_PER_TOKEN * LLM_SEQ_LEN,
-            param_bytes=2.0 * sum(b.params for b in blocks))
+        return llm_table(cfg, f"llm:{spec}")
+
+
+def llm_table(cfg, name: str) -> WorkloadTable:
+    """The ``llm:`` table of a :class:`~repro.models.common.ModelConfig`:
+    one layer per :func:`repro.core.archcost.block_cost_table` block at
+    :data:`LLM_SEQ_LEN` tokens a sample.
+
+    bf16 gradient payloads over *total* params (every expert's gradient
+    is all-reduced, not just the routed-active ones); compute from
+    *active* params, matching ``archcost.step_cost``.  A block with
+    routed experts moves, in each of its all-to-alls, every token's
+    ``experts_per_token`` copies of a bf16 ``d_model`` activation."""
+    from repro.core.archcost import block_cost_table
+
+    blocks = block_cost_table(cfg, LLM_SEQ_LEN)
+    a2a = 2.0 * LLM_SEQ_LEN * cfg.experts_per_token * cfg.d_model
+    return WorkloadTable(
+        name=name,
+        flops_fwd=np.array([b.flops_fwd for b in blocks], dtype=np.float64),
+        grad_bytes=np.array([2.0 * b.params for b in blocks],
+                            dtype=np.float64),
+        batch_default=1,
+        bytes_per_sample=LLM_BYTES_PER_TOKEN * LLM_SEQ_LEN,
+        param_bytes=2.0 * sum(b.params for b in blocks),
+        routed_bytes=np.array([2.0 * b.routed_params for b in blocks],
+                              dtype=np.float64),
+        a2a_bytes_per_sample=np.array(
+            [a2a if b.routed_params else 0.0 for b in blocks],
+            dtype=np.float64),
+        routed_experts=cfg.num_experts)
 
 
 # ----------------------------------------------------------------------

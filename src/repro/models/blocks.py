@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import attention as attn
+from repro.models import mla
 from repro.models import moe as moe_mod
 from repro.models import recurrent as rec
 from repro.models.common import (ModelConfig, Params, apply_norm, dense_init,
@@ -46,8 +47,8 @@ def mlp_apply(cfg: ModelConfig, p: Params, x: jax.Array) -> jax.Array:
     return jnp.einsum("bsf,fd->bsd", h, p["wo"])
 
 
-def _ffn_init(cfg: ModelConfig, key) -> Params:
-    if cfg.num_experts:
+def _ffn_init(cfg: ModelConfig, key, moe: bool = True) -> Params:
+    if cfg.num_experts and moe:
         return {"moe": moe_mod.init_moe(cfg, key)}
     return {"mlp": init_mlp(cfg, key)}
 
@@ -61,11 +62,15 @@ def _ffn_apply(cfg: ModelConfig, p: Params, x: jax.Array) -> tuple[jax.Array, ja
 # ----------------------------------------------------------------------
 # Block init
 # ----------------------------------------------------------------------
-def init_block(cfg: ModelConfig, kind: str, key) -> Params:
+def init_block(cfg: ModelConfig, kind: str, key, moe: bool = True) -> Params:
+    """``moe=False`` gives a block the dense ``d_ff`` MLP in a model with
+    experts (the leading ``first_k_dense`` layers)."""
     ks = split_keys(key, 4)
     if kind in ("G", "L"):
-        return {"norm1": init_norm(cfg), "attn": attn.init_attention(cfg, ks[0]),
-                "norm2": init_norm(cfg), **_ffn_init(cfg, ks[1])}
+        mixer = mla.init_mla(cfg, ks[0]) if cfg.is_mla \
+            else attn.init_attention(cfg, ks[0])
+        return {"norm1": init_norm(cfg), "attn": mixer,
+                "norm2": init_norm(cfg), **_ffn_init(cfg, ks[1], moe)}
     if kind == "C":
         return {"norm1": init_norm(cfg), "attn": attn.init_attention(cfg, ks[0]),
                 "norm_x": init_norm(cfg),
@@ -93,8 +98,11 @@ def apply_block(cfg: ModelConfig, kind: str, p: Params, x: jax.Array,
     if kind in ("G", "L", "C"):
         h = apply_norm(cfg, p["norm1"], x)
         window = cfg.sliding_window if kind == "L" else None
-        x = x + attn.attention_fwd(cfg, p["attn"], h, positions,
-                                   causal=True, window=window)
+        if cfg.is_mla:
+            x = x + mla.mla_fwd(cfg, p["attn"], h, positions)
+        else:
+            x = x + attn.attention_fwd(cfg, p["attn"], h, positions,
+                                       causal=True, window=window)
         if kind == "C":
             h = apply_norm(cfg, p["norm_x"], x)
             x = x + attn.attention_fwd(cfg, p["xattn"], h, positions,
@@ -127,6 +135,10 @@ def apply_block(cfg: ModelConfig, kind: str, p: Params, x: jax.Array,
 # ----------------------------------------------------------------------
 def init_block_cache(cfg: ModelConfig, kind: str, batch: int, seq_len: int,
                      ) -> Params:
+    if cfg.is_mla:
+        raise NotImplementedError(
+            f"{cfg.name}: latent attention (MLA) runs training and prefill "
+            f"only; it has no decode cache")
     if kind == "G":
         return attn.init_kv_cache(cfg, batch, seq_len)
     if kind == "L":

@@ -49,6 +49,13 @@ class ModelConfig:
     shared_expert_d_ff: int = 0        # fused shared-experts hidden dim
     capacity_factor: float = 1.25
     moe_group_size: int = 512
+    first_k_dense: int = 0             # leading layers with a dense d_ff MLP
+    norm_topk_prob: bool = True        # renormalize the top-k gates to sum 1
+    # --- multi-head latent attention (MLA; kv_lora_rank > 0) ---
+    kv_lora_rank: int = 0              # width of the compressed kv latent
+    qk_nope_head_dim: int = 0          # per-head q/k width without RoPE
+    qk_rope_head_dim: int = 0          # per-head q/k width with RoPE (k shared)
+    v_head_dim: int = 0                # per-head value width
     # --- recurrent (R/W blocks) ---
     rnn_width: int = 0                 # RG-LRU recurrence width (0 = d_model)
     conv1d_width: int = 4
@@ -82,12 +89,17 @@ class ModelConfig:
 
     @property
     def num_units(self) -> int:
-        return self.num_layers // len(self.layer_pattern)
+        return (self.num_layers - self.first_k_dense) // len(self.layer_pattern)
 
     @property
     def remainder_pattern(self) -> str:
         """Layers that do not fill a whole pattern unit (prefix order)."""
-        return self.layer_pattern[: self.num_layers % len(self.layer_pattern)]
+        rest = (self.num_layers - self.first_k_dense) % len(self.layer_pattern)
+        return self.layer_pattern[:rest]
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def is_subquadratic(self) -> bool:
@@ -105,6 +117,11 @@ class ModelConfig:
                              f"multiple of kv heads {self.kv_heads}")
         if self.num_experts and not self.experts_per_token:
             raise ValueError("MoE needs experts_per_token")
+        if not 0 <= self.first_k_dense < self.num_layers:
+            raise ValueError("first_k_dense must leave at least one layer")
+        if self.is_mla and not (self.qk_rope_head_dim
+                                and self.qk_nope_head_dim and self.v_head_dim):
+            raise ValueError("MLA needs qk_nope/qk_rope/v head dims")
         for ch in self.layer_pattern:
             if ch not in "GLRWC":
                 raise ValueError(f"unknown block kind {ch!r}")
@@ -133,6 +150,12 @@ class ModelConfig:
             encoder_seq=min(self.encoder_seq, 64) if self.encoder_seq else 0,
             encoder_d_model=min(self.encoder_d_model, d_model) if self.encoder_d_model else 0,
             num_image_tokens=min(self.num_image_tokens, 16),
+            first_k_dense=min(self.first_k_dense, num_layers - 1),
+            kv_lora_rank=min(self.kv_lora_rank, d_model // 4),
+            qk_nope_head_dim=min(self.qk_nope_head_dim, d_model // num_heads),
+            qk_rope_head_dim=min(self.qk_rope_head_dim,
+                                 d_model // (2 * num_heads)),
+            v_head_dim=min(self.v_head_dim, d_model // num_heads),
             moe_group_size=64,
             dtype=jnp.float32, logit_dtype=jnp.float32,
             # keep one block of each distinct kind so reduced variants

@@ -7,7 +7,9 @@ builds a (group, expert, capacity) dispatch/combine pair, and the
 expert FFNs run as a single batched einsum over the expert dimension.
 Dropped tokens (over capacity) fall through the residual connection,
 the standard capacity-factor behaviour.  Shared experts (qwen2-moe)
-are a plain dense MLP fused to ``shared_expert_d_ff``.
+are a plain dense MLP fused to ``shared_expert_d_ff``.  The top-k gates
+are the router's softmax probabilities, renormalized to sum to one
+unless ``norm_topk_prob`` is off (DeepSeek-V2 keeps them as they are).
 """
 from __future__ import annotations
 
@@ -64,8 +66,9 @@ def moe_mlp(cfg: ModelConfig, p: Params, x: jax.Array) -> tuple[jax.Array, jax.A
     logits = jnp.einsum("Ggd,dE->GgE", xg.astype(jnp.float32), p["router"])
     probs = jax.nn.softmax(logits, axis=-1)                       # (G,g,E)
     gate_vals, top_e = jax.lax.top_k(probs, k)                    # (G,g,k)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(
+            jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
 
     # position of each (token, choice) within its expert's capacity
     onehot = jax.nn.one_hot(top_e, E, dtype=jnp.int32)            # (G,g,k,E)

@@ -1,8 +1,9 @@
 """Decoder-only LM over arbitrary ``layer_pattern`` block sequences.
 
-Layers are executed as ``num_units`` repetitions of the pattern unit
-via ``lax.scan`` over stacked parameters (small HLO, fast multi-pod
-compiles) plus an unstacked remainder — so gemma3's 5:1 local:global,
+Layers are executed as ``first_k_dense`` leading blocks with a dense
+MLP (DeepSeek-V2's first layer), then ``num_units`` repetitions of the
+pattern unit via ``lax.scan`` over stacked parameters (small HLO, fast
+multi-pod compiles) plus an unstacked remainder — so gemma3's 5:1 local:global,
 recurrentgemma's 2:1 recurrent:attention and llama-vision's 4:1
 self:cross patterns all lower through the same code path.
 """
@@ -35,6 +36,11 @@ def init_lm(cfg: ModelConfig, key) -> Params:
                                 cfg.dtype, in_axis_size=cfg.d_model),
         "final_norm": init_norm(cfg),
     }
+    if cfg.first_k_dense:
+        dense_keys = split_keys(ks[3], cfg.first_k_dense)
+        for i in range(cfg.first_k_dense):
+            params[f"dense{i}"] = B.init_block(cfg, cfg.layer_pattern[0],
+                                               dense_keys[i], moe=False)
     if cfg.num_units > 0:
         unit_keys = jnp.stack(split_keys(ks[1], cfg.num_units))
         params["units"] = jax.vmap(lambda k: init_unit(cfg, k))(unit_keys)
@@ -85,6 +91,10 @@ def _final_hidden(cfg: ModelConfig, params: Params, tokens: jax.Array, *,
     x = emb[tokens]
     x = constrain(x, "batch", None, None)
     aux0 = jnp.zeros((), jnp.float32)
+    for i in range(cfg.first_k_dense):
+        x, a = B.apply_block(cfg, cfg.layer_pattern[0], ph(params[f"dense{i}"]),
+                             x, positions, encoder_out)
+        aux0 = aux0 + a
 
     def unit_body(carry, unit_params):
         x, aux = carry

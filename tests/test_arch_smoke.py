@@ -3,6 +3,7 @@ each assigned config (2 layers, d_model<=512, <=4 experts) runs one
 forward and one train step on CPU with shape + finiteness asserts."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import ARCH_IDS, get_config
@@ -105,6 +106,7 @@ def test_full_configs_match_assignment():
         "recurrentgemma-2b": (26, 2560, 10, 1, 7680, 256000),
         "llama-3.2-vision-90b": (100, 8192, 64, 8, 28672, 128256),
         "qwen1.5-32b": (64, 5120, 40, 40, 27392, 152064),
+        "deepseek-v2-lite": (27, 2048, 16, 16, 10944, 102400),
     }
     for arch, (L, d, H, K, ff, V) in expect.items():
         cfg = get_config(arch)
@@ -120,6 +122,49 @@ def test_moe_counts():
     assert moe.shared_expert_d_ff == 4 * 1408
     grok = get_config("grok-1-314b")
     assert grok.num_experts == 8 and grok.experts_per_token == 2
+    ds = get_config("deepseek-v2-lite")
+    assert (ds.num_experts, ds.experts_per_token, ds.moe_d_ff) == (64, 6, 1408)
+    assert ds.shared_expert_d_ff == 2 * 1408 and ds.first_k_dense == 1
+    assert not ds.norm_topk_prob
+    assert (ds.kv_lora_rank, ds.qk_nope_head_dim, ds.qk_rope_head_dim,
+            ds.v_head_dim) == (512, 128, 64, 128)
+
+
+def _matrix_params(params) -> int:
+    """Parameters of a model pytree, norm scales excepted (the cost
+    model counts matrices only)."""
+    return sum(x.size for path, x in
+               jax.tree_util.tree_leaves_with_path(params)
+               if not any("norm" in str(k) for k in path))
+
+
+@pytest.mark.parametrize("over", [{}, {"num_layers": 4, "num_experts": 8}])
+def test_deepseek_param_count_matches_cost_model(over):
+    """The reduced model's real parameters are what
+    :func:`repro.core.archcost.param_counts` counts: MLA's four
+    projections, the leading dense layer, routed and shared experts,
+    the router, embedding and head."""
+    from repro.core.archcost import param_counts
+    cfg = get_config("deepseek-v2-lite").reduced(**over)
+    assert cfg.first_k_dense == 1 and cfg.is_mla
+    params = T.init_lm(cfg, jax.random.PRNGKey(3))
+    assert "dense0" in params and "moe" in params["units"]["b0"]
+    assert _matrix_params(params) == param_counts(cfg)[0]
+
+
+def test_latent_attention_is_causal():
+    from repro.models.mla import init_mla, mla_fwd
+    cfg = get_config("deepseek-v2-lite").reduced()
+    p = init_mla(cfg, jax.random.PRNGKey(4))
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, S, cfg.d_model))
+    pos = jnp.arange(S)[None]
+    x2 = x.at[:, S // 2:].set(jax.random.normal(jax.random.PRNGKey(6),
+                                                (1, S - S // 2, cfg.d_model)))
+    y, y2 = mla_fwd(cfg, p, x, pos), mla_fwd(cfg, p, x2, pos)
+    assert y.shape == x.shape
+    np.testing.assert_allclose(y[:, :S // 2], y2[:, :S // 2], rtol=1e-5,
+                               atol=1e-6)
+    assert not np.allclose(y[:, S // 2:], y2[:, S // 2:])
 
 
 def test_param_scale_sanity():
@@ -130,6 +175,7 @@ def test_param_scale_sanity():
         "grok-1-314b": 314e9, "rwkv6-1.6b": 1.6e9,
         "recurrentgemma-2b": 2.7e9, "llama-3.2-vision-90b": 90e9,
         "qwen1.5-32b": 32e9, "qwen2-moe-a2.7b": 14e9,
+        "deepseek-v2-lite": 15.7e9,
     }
     for arch, want in approx.items():
         n, _ = param_counts(get_config(arch))

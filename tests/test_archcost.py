@@ -2,7 +2,7 @@
 import pytest
 
 from repro.configs import SHAPES, get_config
-from repro.core.archcost import param_counts, step_cost
+from repro.core.archcost import block_cost_table, param_counts, step_cost
 
 
 def test_dense_model_flops_is_6nd():
@@ -50,3 +50,22 @@ def test_ssm_long_decode_constant_state():
     c500 = step_cost(cfg, SHAPES["long_500k"])
     # state is seq-length independent; hbm ~ params + small state
     assert c500.hbm_bytes < 1.2 * c500.param_bytes
+
+
+def test_deepseek_v2_lite_counts():
+    """Matrices only: 15 706 357 760 parameters, 2 661 023 744 active
+    (6 of 64 routed experts, both shared ones, every MLA projection)."""
+    cfg = get_config("deepseek-v2-lite")
+    assert param_counts(cfg) == (15_706_357_760, 2_661_023_744)
+    blocks = block_cost_table(cfg, 4096)
+    assert len(blocks) == 1 + 27 + 1                 # embed, blocks, head
+    mla = 2048 * 16 * 192 + 2048 * 576 + 512 * 16 * 256 + 16 * 128 * 2048
+    assert mla == 13_762_560
+    assert blocks[1].params == mla + 3 * 2048 * 10944   # the dense layer
+    assert blocks[1].routed_params == 0
+    routed = 64 * 3 * 2048 * 1408
+    assert all(b.routed_params == routed for b in blocks[2:-1])
+    # causal MLA: S*S*H*(qk_nope + qk_rope + v) a sequence, forward
+    S = 4096
+    assert blocks[1].flops_fwd == 2.0 * blocks[1].active_params * S \
+        + S * S * 16 * (128 + 64 + 128)

@@ -2,7 +2,8 @@
 
 The TPU compiler is installed alongside jax, so each test lowers a
 kernel of the main path at its real width and compiles it for one chip
-of a described ``v5e:2x2`` topology: the f64 frontier-grid sweep kernel,
+of a described ``v5e:2x2`` topology: the f64 sweep kernel on the
+frontier grid and, with its expert-parallel terms, on DeepSeek-V2-Lite's,
 the Pallas flash attention (forward and backward) at qwen1.5-4b width,
 ``wkv6`` at rwkv6-1.6b width and ``rglru`` at recurrentgemma-2b width.
 A compile refuses what the chip's compiler would refuse — an op the
@@ -70,23 +71,60 @@ def test_frontier_sweep_kernel_f64(one_chip):
     is the packed buffer the host fetches in one transfer: the two
     float32 words of each of the six emulated float64 columns, each
     padded to whole tiles of 1 024."""
-    from repro.core.batched_jax import (_NUMERIC_COLS, JaxGridEvaluator,
-                                        _columns_jax)
+    from repro.core.batched_jax import _NUMERIC_COLS, JaxGridEvaluator
     from repro.core.scenarios import frontier_grid
 
-    jev = JaxGridEvaluator(frontier_grid())
-    with jax.enable_x64(True):
-        args = [jax.tree_util.tree_map(lambda a: _spec(a, one_chip), t)
-                for t in (jev._tables, jev._pflags, jev._kcodes,
-                          jev._scodes, jev._ucodes)]
-        compiled = _columns_jax.lower(
-            *args, tl_overlaps=jev._tl_overlaps,
-            coll_codes=jev._coll_codes, shards=1).compile()
-        out = compiled.out_info
+    compiled = _sweep_kernel(JaxGridEvaluator(frontier_grid()), one_chip)
+    out = compiled.out_info
     assert isinstance(out, jax.ShapeDtypeStruct)
     assert out.shape == (1, 2 * len(_NUMERIC_COLS) * 52_224)   # 51 tiles
     assert out.dtype == np.float32
     assert "f64[" in compiled.as_text()
+
+
+def _sweep_kernel(jev, sharding):
+    """The jitted sweep kernel of ``jev`` compiled for ``sharding``, its
+    outputs packed as on one TPU."""
+    from repro.core.batched_jax import _columns_jax
+
+    with jax.enable_x64(True):
+        args = [jax.tree_util.tree_map(lambda a: _spec(a, sharding), t)
+                for t in (jev._tables, jev._pflags, jev._kcodes,
+                          jev._scodes, jev._ucodes)]
+        return _columns_jax.lower(
+            *args, tl_overlaps=jev._tl_overlaps,
+            coll_codes=jev._coll_codes, shards=1).compile()
+
+
+def test_dsv2_lite_ep_sweep_kernel_f64(one_chip):
+    """The kernel with its expert-parallel terms, over the 80 640
+    scenarios of DeepSeek-V2-Lite under data x expert parallelism (EP
+    sizes 1-64 on 64-512 workers): the all-to-all suffix tables, the
+    expert-group coefficients and the per-pair bucket tables all have
+    an emulation in float64."""
+    from repro.core.batched_jax import _NUMERIC_COLS, JaxGridEvaluator
+    from repro.core.hardware import COLLECTIVE_ALGORITHMS
+    from repro.core.scenarios import FRONTIER_POLICIES, ScenarioGrid
+
+    links = tuple(f"{base}@bw{bw:g}@lat{lat:g}"
+                  for base in ("10gbe", "ib-100g", "ib-100g-fused", "ib-200g")
+                  for bw in (0.5, 1, 2, 4) for lat in (0.25, 1, 4))
+    grid = ScenarioGrid(workloads=("llm:deepseek-v2-lite",),
+                        clusters=("v100-nvlink-ib", "tpu-v5e-pod"),
+                        worker_counts=(64, 128, 256, 512),
+                        ep_sizes=(1, 2, 4, 8, 16, 32, 64),
+                        policies=FRONTIER_POLICIES,
+                        collectives=COLLECTIVE_ALGORITHMS, interconnects=links)
+    jev = JaxGridEvaluator(grid)
+    assert "we" in jev._kcodes
+    compiled = _sweep_kernel(jev, one_chip)
+    out = compiled.out_info
+    assert out.shape == (1, 2 * len(_NUMERIC_COLS) * 80_896)   # 79 tiles
+    assert out.dtype == np.float32
+    mem = compiled.memory_analysis()
+    print(f"dsv2_lite_ep kernel: args {mem.argument_size_in_bytes} B, "
+          f"outputs {mem.output_size_in_bytes} B, temps "
+          f"{mem.temp_size_in_bytes} B")
 
 
 def test_frontier_sweep_kernel_packs_on_four_chips_in_place(topo):

@@ -231,3 +231,61 @@ def test_the_scenario_list_path_records_the_same_device_spans(recorder):
     plain = BJ.eval_scenarios_table_jax(scenarios)
     for k in table:
         assert np.array_equal(np.asarray(table[k]), np.asarray(plain[k])), k
+
+
+# ----------------------------------------------------------------------
+# Expert parallelism: its span and counters fire only where some point
+# has ep > 1.
+# ----------------------------------------------------------------------
+def _moe_grid(ep_sizes) -> ScenarioGrid:
+    return ScenarioGrid(workloads=("llm:qwen2-moe-a2.7b",),
+                        clusters=("v100-nvlink-ib",), worker_counts=(4, 8),
+                        ep_sizes=ep_sizes,
+                        policies=("tensorflow", "bucketed-25mb"),
+                        collectives=("ring", "hierarchical"))
+
+
+@pytest.mark.parametrize("ep_sizes", [(1,), (1, 2, 4)])
+def test_ep_span_and_counters_fire_only_with_ep_above_one(recorder,
+                                                          ep_sizes):
+    grid = _moe_grid(ep_sizes)
+    sweep(grid, backend="jax")
+    got = obs.snapshot()
+    by, counters = _by_name(got["spans"]), got["counters"]
+    jev = BJ.jax_grid_evaluator(grid)
+    kernel_points = len(jev._kcodes["n"])
+    assert counters["sweep.kernel_points"] == kernel_points
+    if ep_sizes == (1,):
+        assert "sweep.build.ep" not in by
+        assert "sweep.ep_h2d_bytes" not in counters
+        assert counters["sweep.ep_points"] == 0
+        return
+    [ep] = by["sweep.build.ep"]
+    assert ep.parent == "sweep.build" and _inside(ep, by["sweep.build"][0])
+    ep_host = [v for k, v in jev._tables.items() if k.startswith("ep_")] \
+        + [jev._kcodes["we"], jev._kcodes["ep"]]
+    assert counters["sweep.ep_h2d_bytes"] == sum(x.nbytes for x in ep_host)
+    assert 0 < counters["sweep.ep_h2d_bytes"] < counters["sweep.h2d_bytes"]
+    assert counters["sweep.ep_points"] * 3 == kernel_points * 2
+
+
+def test_ep_is_live_on_six_of_seven_kernel_points_of_the_cell(recorder):
+    """The grid of the benchmark cell ``dsv2_lite_ep.sweep`` (80 640
+    scenarios): EP sizes 1-64 over 64-512 workers, two clusters."""
+    from repro.core.hardware import COLLECTIVE_ALGORITHMS
+    from repro.core.scenarios import FRONTIER_POLICIES
+
+    links = tuple(f"{base}@bw{bw:g}@lat{lat:g}"
+                  for base in ("10gbe", "ib-100g", "ib-100g-fused", "ib-200g")
+                  for bw in (0.5, 1, 2, 4) for lat in (0.25, 1, 4))
+    grid = ScenarioGrid(workloads=("llm:deepseek-v2-lite",),
+                        clusters=("v100-nvlink-ib", "tpu-v5e-pod"),
+                        worker_counts=(64, 128, 256, 512),
+                        ep_sizes=(1, 2, 4, 8, 16, 32, 64),
+                        policies=FRONTIER_POLICIES,
+                        collectives=COLLECTIVE_ALGORITHMS, interconnects=links)
+    assert len(grid) == 80_640
+    sweep(grid, backend="jax")
+    counters = obs.snapshot()["counters"]
+    assert counters["sweep.kernel_points"] == 8_064
+    assert counters["sweep.ep_points"] * 7 == counters["sweep.kernel_points"] * 6

@@ -67,7 +67,7 @@ class TestSimulatorInvariants:
 class TestInputSpecs:
     def test_matrix_size(self):
         m = dryrun_matrix()
-        assert len(m) == 33          # 10*3 + 3 long_500k
+        assert len(m) == 36          # 11*3 + 3 long_500k
         assert ("internlm2-20b", "long_500k") not in m
         assert ("rwkv6-1.6b", "long_500k") in m
 
