@@ -14,10 +14,11 @@ whole code vectors (no ``vmap`` round trip, no per-point closures):
 * tier 2 mirrors :func:`repro.core.batched._policy_select` — the same
   ``where``/``maximum`` equation select over ``(S,)`` vectors;
 * the composition is one ``jit``-compiled function whose array inputs
-  (axis tables, code vectors) are ordinary pytree arguments — same
-  shapes, same compilation, fresh numbers every call — and whose one
-  output packs the numeric result columns into a single buffer, so
-  ``backend="jax"`` end-to-end cost is the kernel, one device-to-host
+  (axis tables, code vectors) arrive packed in two host buffers, a
+  float64 one and an int32 one — same shapes, same compilation, fresh
+  numbers every call — and whose one output packs the numeric result
+  columns into a single buffer, so ``backend="jax"`` end-to-end cost is
+  two host-to-device transfers, the kernel, one device-to-host
   transfer and host label gathers.
 
 There is no parallel formula implementation to keep in lockstep: the
@@ -416,18 +417,110 @@ _columns_dict = jax.jit(_columns, static_argnames=("tl_overlaps",
 #: the packed buffer starts on a tile, so no row is shifted to place it.
 _TILE = 1024
 
+#: The kernel's five dict arguments, in order; the names its errors use.
+_ARG_NAMES = ("tables", "pflags", "kcodes", "scodes", "ucodes")
+
+_INT32 = np.iinfo(np.int32)
+
+
+def _packed_args(args: tuple) -> tuple:
+    """The kernel's arguments (``JaxGridEvaluator._args``: five dicts,
+    then ``tl_overlaps`` and ``coll_codes``) as :func:`_columns_jax`
+    takes them: ``(f64, i32, live, layout, tl_overlaps, coll_codes)``.
+
+    Every NumPy leaf is copied into one of two flat host buffers: float
+    leaves into ``f64``, integer and bool leaves into ``i32``.  The
+    codes are indices and counts below a few thousand, exact in int32;
+    a leaf with a value outside int32 raises ``ValueError`` naming it.
+    Any other leaf — a device array, such as the codes
+    :func:`_shard_codes` placed over a mesh, or a tracer — passes as it
+    is, in ``live``.  ``layout`` (hashable, a static argument) holds per
+    dict, per key, the leaf's kind (``"f"``, ``"i"``, ``"b"`` for bool,
+    ``"a"`` for a leaf of ``live``), its offset in its buffer (for
+    ``"a"``, its index in ``live``) and its shape: it depends only on
+    the keys and shapes, so a same-shaped grid reuses the
+    executable."""
+    *dicts, tl_overlaps, coll_codes = args
+    floats, ints, live, layout = [], [], [], []
+    nf = ni = 0
+    for name, group in zip(_ARG_NAMES, dicts):
+        entries = []
+        for key, x in group.items():
+            if not isinstance(x, np.ndarray):
+                entries.append((key, "a", len(live), None))
+                live.append(x)
+            elif x.dtype.kind == "f":
+                entries.append((key, "f", nf, x.shape))
+                floats.append(x)
+                nf += x.size
+            else:
+                if x.dtype.kind != "b" and x.size and (
+                        x.min() < _INT32.min or x.max() > _INT32.max):
+                    raise ValueError(
+                        f"{name}[{key!r}] holds integers outside int32 "
+                        f"({x.min()}..{x.max()}); the kernel's codes are "
+                        f"int32")
+                entries.append((key, "b" if x.dtype.kind == "b" else "i",
+                                ni, x.shape))
+                ints.append(x)
+                ni += x.size
+        layout.append(tuple(entries))
+    f64 = np.empty(nf, dtype=np.float64)
+    i32 = np.empty(ni, dtype=np.int32)
+    for buf, leaves in ((f64, floats), (i32, ints)):
+        at = 0
+        for x in leaves:
+            buf[at:at + x.size] = x.ravel()
+            at += x.size
+    return f64, i32, tuple(live), tuple(layout), tl_overlaps, coll_codes
+
+
+def _unpacked_args(f64, i32, live: tuple, layout: tuple) -> tuple:
+    """The five dicts of :func:`_packed_args` again, traced: static
+    slices of the two buffers, reshaped; bools as ``!= 0``; the integer
+    codes stay int32."""
+    def leaf(kind, at, shape):
+        if kind == "a":
+            return live[at]
+        buf = f64 if kind == "f" else i32
+        x = buf[at:at + math.prod(shape)].reshape(shape)
+        return x != 0 if kind == "b" else x
+
+    return tuple({key: leaf(kind, at, shape)
+                  for key, kind, at, shape in group} for group in layout)
+
+
+def _host_h2d(f64: np.ndarray, i32: np.ndarray, layout: tuple) -> None:
+    """Counters ``sweep.h2d_arrays``/``sweep.h2d_bytes``: the two host
+    buffers of :func:`_packed_args`, and ``sweep.ep_h2d_bytes``: the
+    bytes of the expert-parallel tables and codes in them (only where
+    there are any)."""
+    obs.count("sweep.h2d_arrays", 2)
+    obs.count("sweep.h2d_bytes", f64.nbytes + i32.nbytes)
+    tables, _, kcodes, _, _ = layout
+    if any(key == "we" for key, *_ in kcodes):
+        ep = [e for e in tables if e[0].startswith("ep_")] \
+            + [e for e in kcodes if e[0] in ("we", "ep")]
+        obs.count("sweep.ep_h2d_bytes", sum(
+            math.prod(shape) * (8 if kind == "f" else 4)
+            for _, kind, _, shape in ep if kind != "a"))
+
 
 @functools.partial(jax.jit,
-                   static_argnames=("tl_overlaps", "coll_codes", "shards"))
-def _columns_jax(tables: dict, pflags: dict, kcodes: dict, scodes: dict,
-                 ucodes: dict, tl_overlaps: tuple, coll_codes: tuple,
-                 shards: int = 0):
+                   static_argnames=("layout", "tl_overlaps", "coll_codes",
+                                    "shards"))
+def _columns_jax(f64, i32, live: tuple, layout: tuple, tl_overlaps: tuple,
+                 coll_codes: tuple, shards: int = 0):
     """:func:`_columns` as one compiled function whose one output packs
     the :data:`_NUMERIC_COLS` into a single buffer, so the host fetches
-    a sweep in one transfer (:func:`_host_columns`).  Compilation is
-    keyed by array shapes/dtypes and the static arguments — re-running a
-    grid (or any same-shaped grid) with fresh numbers reuses the
-    executable.
+    a sweep in one transfer (:func:`_host_columns`).  Its inputs are
+    packed too (:func:`_packed_args`): the float64 buffer of the
+    tables, the int32 buffer of the flags and codes, and the leaves
+    already on the device, unpacked here by static slices, so a sweep
+    places two host buffers on the device, not one per leaf.
+    Compilation is keyed by the buffers' lengths, ``layout`` and the
+    other static arguments — re-running a grid (or any same-shaped
+    grid) with fresh numbers reuses the executable.
 
     With ``shards == 0`` the buffer is the ``(6, S)`` float64 stack.
     With ``shards = n`` (for a device that emulates float64, the TPU,
@@ -439,7 +532,7 @@ def _columns_jax(tables: dict, pflags: dict, kcodes: dict, scodes: dict,
     between devices, and no word row is laid out anew: stacking the
     columns as rows of a 2-D float32 array cost 0.3 ms more of a
     10 ms kernel on a TPU v5e."""
-    cols = _columns(tables, pflags, kcodes, scodes, ucodes, tl_overlaps,
+    cols = _columns(*_unpacked_args(f64, i32, live, layout), tl_overlaps,
                     coll_codes)
     if not shards:
         return jnp.stack([cols[k] for k in _NUMERIC_COLS])
@@ -503,36 +596,37 @@ def _host_columns(args: tuple, size: int) -> dict[str, np.ndarray]:
     rows of each numeric column in host float64 arrays: views of one
     ``(6, S)`` host array, fetched in one transfer.
 
+    What crosses to the device per call is two host buffers
+    (:func:`_packed_args`): the float tables as one float64 buffer, the
+    flags and integer codes as one int32 buffer.  Each transfer has a
+    fixed cost, paid once per leaf otherwise (62 leaves for the
+    frontier grid); and the codes, most of the bytes, are int32 because
+    the TPU has no native 64-bit integer (it emulates one as a pair of
+    32-bit words) while int32 holds every code exactly.  Codes already
+    on the device (a mesh's) stay there.
+
     The device path splits into three spans: ``sweep.columns.call``
-    (flattening the arguments, placing every host array on the device,
-    the launch, and the start of the copy of the packed output to the
-    host), ``sweep.columns.wait`` (the device work the host cannot
-    hide, waited for only while the recorder is on) and
+    (packing the two buffers, placing them on the device, the launch,
+    and the start of the copy of the packed output to the host),
+    ``sweep.columns.wait`` (the device work the host cannot hide,
+    waited for only while the recorder is on) and
     ``sweep.columns.fetch`` (the end of the copy and the columns taken
     from it; with the recorder off it also does the waiting).  The
-    counters ``sweep.h2d_arrays``/``sweep.h2d_bytes`` count the NumPy
-    leaves of the arguments, each of which the call copies to the
-    device, and ``sweep.ep_h2d_bytes`` the bytes of those that are
-    expert-parallel tables and codes (only where there are any);
-    ``sweep.d2h_arrays``/``sweep.d2h_bytes`` the device arrays and
-    bytes the fetch brings to the host."""
+    counters ``sweep.h2d_arrays``/``sweep.h2d_bytes`` count the host
+    buffers the call places and their bytes, and
+    ``sweep.ep_h2d_bytes`` the bytes of the expert-parallel tables and
+    codes in them (:func:`_host_h2d`); ``sweep.d2h_arrays``/
+    ``sweep.d2h_bytes`` the device arrays and bytes the fetch brings to
+    the host."""
     with obs.span("sweep.columns"), jax.enable_x64(True):
-        if obs.enabled():
-            host = [x for x in jax.tree_util.tree_leaves(args)
-                    if isinstance(x, np.ndarray)]
-            obs.count("sweep.h2d_arrays", len(host))
-            obs.count("sweep.h2d_bytes", sum(x.nbytes for x in host))
-            if "we" in args[2]:
-                ep = [v for k, v in args[0].items() if k.startswith("ep_")] \
-                    + [args[2]["we"], args[2]["ep"]]
-                obs.count("sweep.ep_h2d_bytes", sum(
-                    x.nbytes for x in ep if isinstance(x, np.ndarray)))
         pi = args[3]["pi"]
         shards = _shards(pi) if _f64_words() else 0
         with obs.span("sweep.columns.call"):
-            out = _columns_jax(*args, shards=shards)
+            packed = _packed_args(args)
+            out = _columns_jax(*packed, shards=shards)
             out.copy_to_host_async()
         if obs.enabled():
+            _host_h2d(packed[0], packed[1], packed[3])
             with obs.span("sweep.columns.wait"):
                 out.block_until_ready()
         with obs.span("sweep.columns.fetch"):

@@ -293,8 +293,8 @@ SHARDED_SCRIPT = textwrap.dedent("""
     S = len(jev)
     got = jev.columns()
     with jax.enable_x64(True):
-        packed = BJ._columns_jax(*jev._args())
-        words = BJ._columns_jax(*jev._args(), shards=4)
+        packed = BJ._columns_jax(*BJ._packed_args(jev._args()))
+        words = BJ._columns_jax(*BJ._packed_args(jev._args()), shards=4)
         ref = BJ._columns_dict(*jev._args())
         want = {k: np.asarray(ref[k])[:S] for k in BJ._NUMERIC_COLS}
     BJ._f64_words = lambda: True        # the TPU's packing, on the CPU
@@ -391,6 +391,120 @@ class TestPackedFetch:
         assert np.all(lo[~np.isfinite(x)] == 0)
         back = hi.astype(np.float64) + lo
         assert np.array_equal(back.view(np.uint64), x.view(np.uint64))
+
+
+def _links(bw=(0.5, 1, 2, 4), lat=(0.25, 1, 4)) -> tuple:
+    return tuple(f"{base}@bw{b:g}@lat{a:g}"
+                 for base in ("10gbe", "ib-100g", "ib-100g-fused", "ib-200g")
+                 for b in bw for a in lat)
+
+
+def _dsv2_lite_ep_grid() -> ScenarioGrid:
+    """The grid of the benchmark cell ``dsv2_lite_ep.sweep``."""
+    from repro.core.hardware import COLLECTIVE_ALGORITHMS
+    from repro.core.scenarios import FRONTIER_POLICIES
+
+    return ScenarioGrid(workloads=("llm:deepseek-v2-lite",),
+                        clusters=("v100-nvlink-ib", "tpu-v5e-pod"),
+                        worker_counts=(64, 128, 256, 512),
+                        ep_sizes=(1, 2, 4, 8, 16, 32, 64),
+                        policies=FRONTIER_POLICIES,
+                        collectives=COLLECTIVE_ALGORITHMS,
+                        interconnects=_links())
+
+
+def _moe_grid(ep_sizes) -> ScenarioGrid:
+    return ScenarioGrid(workloads=("llm:qwen2-moe-a2.7b",),
+                        clusters=("v100-nvlink-ib",), worker_counts=(4, 8),
+                        ep_sizes=ep_sizes,
+                        policies=("tensorflow", "bucketed-25mb"),
+                        collectives=("ring", "hierarchical"))
+
+
+#: Heterogeneous workers, stragglers, K-of-N sync and faults together.
+HET_FAULT_GRID = dataclasses.replace(
+    MC_GRID, faults=(None, "fail:0.1@restart1.5x16"))
+
+PACKED_GRIDS = {
+    "frontier": frontier_grid,
+    "dsv2_lite_ep": _dsv2_lite_ep_grid,
+    "moe_ep1": lambda: _moe_grid((1,)),
+    "moe_ep124": lambda: _moe_grid((1, 2, 4)),
+    "het_straggler_fault_kofn": lambda: HET_FAULT_GRID,
+    "mixed": mixed_grid,
+}
+
+
+class TestPackedInputs:
+    """The kernel's host inputs cross to the device as two packed
+    buffers (float64 tables, int32 flags and codes), and the columns
+    are bit for bit those of the dict-valued kernel on the dicts."""
+
+    @pytest.mark.parametrize("name", list(PACKED_GRIDS))
+    def test_packed_call_is_bit_identical_to_the_dict_kernel(self, name):
+        jev = BJ.JaxGridEvaluator(PACKED_GRIDS[name]())
+        f64, i32, live, layout, *_ = BJ._packed_args(jev._args())
+        assert f64.dtype == np.float64 and i32.dtype == np.int32
+        assert live == ()
+        kinds = {kind for group in layout for _, kind, _, _ in group}
+        assert kinds == {"f", "i", "b"}
+        assert ("we" in jev._kcodes) == (name in ("dsv2_lite_ep",
+                                                  "moe_ep124"))
+        got = jev.columns()
+        _assert_bits_equal(got, _per_column(jev._args(), len(jev)))
+
+    def test_scenario_list_is_bit_identical_to_the_dict_kernel(
+            self, monkeypatch):
+        scenarios = list(HET_FAULT_GRID)[::5] + list(_moe_grid((1, 2, 4)))
+        got = BJ.eval_scenarios_table_jax(scenarios, seed=3)
+        monkeypatch.setattr(BJ, "_host_columns", _per_column)
+        want = BJ.eval_scenarios_table_jax(scenarios, seed=3)
+        _assert_bits_equal(got, want)
+
+    def test_the_unpacked_dicts_are_the_arguments(self):
+        import jax
+
+        jev = BJ.JaxGridEvaluator(_moe_grid((1, 2, 4)))
+        args = jev._args()
+        f64, i32, live, layout, *_ = BJ._packed_args(args)
+        with jax.enable_x64(True):
+            back = jax.jit(BJ._unpacked_args, static_argnums=3)(
+                f64, i32, live, layout)
+        for want, got in zip(args[:5], back):
+            assert want.keys() == got.keys()
+            for k, v in want.items():
+                g = np.asarray(got[k])
+                assert g.shape == v.shape, k
+                assert g.dtype == (np.float64 if v.dtype.kind == "f"
+                                   else bool if v.dtype.kind == "b"
+                                   else np.int32), k
+                assert np.array_equal(g, v), k
+
+    def test_a_same_shaped_grid_reuses_the_executable(self):
+        """A fresh frontier of the same shape (other link factors) hits
+        the executable the first one compiled: the layout depends only
+        on keys and shapes."""
+        first = frontier_grid()
+        other = dataclasses.replace(first, interconnects=_links(
+            bw=(0.75, 1.5, 3, 6), lat=(0.5, 2, 8)))
+        BJ.JaxGridEvaluator(first).columns()
+        size = BJ._columns_jax._cache_size()
+        a = BJ._packed_args(BJ.JaxGridEvaluator(first)._args())
+        b = BJ._packed_args(BJ.JaxGridEvaluator(other)._args())
+        assert a[3] == b[3] and not np.array_equal(a[0], b[0])
+        BJ.JaxGridEvaluator(other).columns()
+        assert BJ._columns_jax._cache_size() == size
+
+    @pytest.mark.parametrize("value", [2**31, -2**31 - 1])
+    def test_an_integer_beyond_int32_raises_naming_the_leaf(self, value):
+        jev = BJ.JaxGridEvaluator(default_grid())
+        tables, pflags, kcodes, *rest = jev._args()
+        n = kcodes["n"].copy()
+        n[3] = value
+        with pytest.raises(ValueError, match=r"kcodes\['n'\].*int32"):
+            BJ._packed_args((tables, pflags, {**kcodes, "n": n}, *rest))
+        n[3] = 2**31 - 1                            # the largest that fits
+        BJ._packed_args((tables, pflags, {**kcodes, "n": n}, *rest))
 
 
 class TestBackendRouting:

@@ -82,18 +82,30 @@ def test_frontier_sweep_kernel_f64(one_chip):
     assert "f64[" in compiled.as_text()
 
 
-def _sweep_kernel(jev, sharding):
+def _sweep_kernel(jev, sharding, *, codes=None, shards=1):
     """The jitted sweep kernel of ``jev`` compiled for ``sharding``, its
-    outputs packed as on one TPU."""
-    from repro.core.batched_jax import _columns_jax
+    outputs packed as on one TPU (``shards`` 1).  Its inputs are packed
+    as the host packs them: every leaf into the float64 or the int32
+    buffer, placed with ``sharding``, except the codes given a sharding
+    in ``codes``, which pass as device arrays of that sharding, as a
+    mesh's do.  The module keeps its name, ``jit__columns_jax``, which
+    the benchmark's device readers look for."""
+    import re
 
+    from repro.core.batched_jax import _columns_jax, _packed_args
+
+    tables, pflags, kcodes, scodes, ucodes, *static = jev._args()
+    if codes is not None:
+        kcodes, scodes = ({k: _spec(v, codes) for k, v in d.items()}
+                          for d in (kcodes, scodes))
     with jax.enable_x64(True):
-        args = [jax.tree_util.tree_map(lambda a: _spec(a, sharding), t)
-                for t in (jev._tables, jev._pflags, jev._kcodes,
-                          jev._scodes, jev._ucodes)]
-        return _columns_jax.lower(
-            *args, tl_overlaps=jev._tl_overlaps,
-            coll_codes=jev._coll_codes, shards=1).compile()
+        f64, i32, live, layout, tl_overlaps, coll_codes = _packed_args(
+            (tables, pflags, kcodes, scodes, ucodes, *static))
+        lowered = _columns_jax.lower(
+            _spec(f64, sharding), _spec(i32, sharding), live, layout,
+            tl_overlaps, coll_codes, shards=shards)
+        assert re.search(r"module @jit__columns_jax\b", lowered.as_text())
+        return lowered.compile()
 
 
 def test_dsv2_lite_ep_sweep_kernel_f64(one_chip):
@@ -129,28 +141,22 @@ def test_dsv2_lite_ep_sweep_kernel_f64(one_chip):
 
 def test_frontier_sweep_kernel_packs_on_four_chips_in_place(topo):
     """On a 2x2 mesh, the scenario axis sharded as ``JaxGridEvaluator``
-    shards it, each chip packs the words of its own block: no all-gather,
-    all-to-all or collective-permute moves the packed buffer."""
+    shards it — the codes split over ``data`` as device arrays, the two
+    packed host buffers replicated — each chip packs the words of its
+    own block: no all-gather, all-to-all or collective-permute moves the
+    packed buffer."""
     import re
 
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-    from repro.core.batched_jax import (_NUMERIC_COLS, JaxGridEvaluator,
-                                        _columns_jax)
+    from repro.core.batched_jax import _NUMERIC_COLS, JaxGridEvaluator
     from repro.core.scenarios import frontier_grid
 
     mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
     rep = NamedSharding(mesh, PartitionSpec())
     split = NamedSharding(mesh, PartitionSpec("data"))
-    jev = JaxGridEvaluator(frontier_grid())
-    with jax.enable_x64(True):
-        args = [jax.tree_util.tree_map(lambda a: _spec(a, sh), t)
-                for t, sh in ((jev._tables, rep), (jev._pflags, rep),
-                              (jev._kcodes, split), (jev._scodes, split),
-                              (jev._ucodes, rep))]
-        compiled = _columns_jax.lower(
-            *args, tl_overlaps=jev._tl_overlaps,
-            coll_codes=jev._coll_codes, shards=4).compile()
+    compiled = _sweep_kernel(JaxGridEvaluator(frontier_grid()), rep,
+                             codes=split, shards=4)
     out = compiled.out_info
     assert out.shape == (4, 2 * len(_NUMERIC_COLS) * 13_312)  # 13 tiles
     assert out.sharding.spec == PartitionSpec("data")

@@ -154,15 +154,20 @@ def test_a_second_sweep_of_the_same_grid_builds_nothing(recorder):
 
 
 def test_h2d_counters_are_the_numpy_leaves_of_the_call(recorder):
+    """What crosses to the device is two packed host buffers, float64
+    and int32, holding every NumPy leaf of the call."""
     grid = _grid((4, 8))
     sweep(grid, backend="jax")
     counters = obs.snapshot()["counters"]
     args = BJ.jax_grid_evaluator(grid)._args()
     host = [x for x in jax.tree_util.tree_leaves(args)
             if isinstance(x, np.ndarray)]
-    assert len(host) > 0
-    assert counters["sweep.h2d_arrays"] == len(host)
-    assert counters["sweep.h2d_bytes"] == sum(x.nbytes for x in host)
+    f64, i32, live, *_ = BJ._packed_args(args)
+    assert live == () and len(host) > 2
+    assert f64.size + i32.size == sum(x.size for x in host)
+    assert counters["sweep.h2d_arrays"] == 2
+    assert counters["sweep.h2d_bytes"] == f64.nbytes + i32.nbytes
+    assert counters["sweep.h2d_bytes"] == 8 * f64.size + 4 * i32.size
 
 
 def test_d2h_counters_are_one_packed_buffer(recorder):
@@ -196,8 +201,11 @@ def test_grad_iteration_time_equals_the_gradient_through_the_packed_kernel():
     with jax.enable_x64(True):
         p = {k: jax.numpy.asarray(v)
              for k, v in BJ.default_params(grid).items()}
-        want = jax.grad(
-            lambda q: BJ._columns_jax(*jev._args(q))[row, :S].sum())(p)
+        def total(q):           # the params pass through as live leaves
+            return BJ._columns_jax(*BJ._packed_args(jev._args(q)))[
+                row, :S].sum()
+
+        want = jax.grad(total)(p)
     assert got.keys() == want.keys()
     assert any(np.abs(got[k]).max() > 0 for k in ("intra_bw", "inter_bw"))
     for k in got:
@@ -264,7 +272,8 @@ def test_ep_span_and_counters_fire_only_with_ep_above_one(recorder,
     assert ep.parent == "sweep.build" and _inside(ep, by["sweep.build"][0])
     ep_host = [v for k, v in jev._tables.items() if k.startswith("ep_")] \
         + [jev._kcodes["we"], jev._kcodes["ep"]]
-    assert counters["sweep.ep_h2d_bytes"] == sum(x.nbytes for x in ep_host)
+    assert counters["sweep.ep_h2d_bytes"] == sum(       # as packed
+        x.size * (8 if x.dtype.kind == "f" else 4) for x in ep_host)
     assert 0 < counters["sweep.ep_h2d_bytes"] < counters["sweep.h2d_bytes"]
     assert counters["sweep.ep_points"] * 3 == kernel_points * 2
 
