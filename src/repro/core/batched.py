@@ -42,13 +42,18 @@ mixed and frontier grids).  This module is the throughput engine
 :func:`repro.core.sweep.sweep` routes every batched-eligible scenario
 through.
 
-:func:`grid_evaluator` memoizes the prepared *structure* of a grid
-(axis tables, code vectors, label lists) keyed by grid value and
-resolved table identity — numeric results are recomputed on every
-:meth:`GridEvaluator.run`, never cached.
+Both front ends — :class:`GridEvaluator` for grids,
+:func:`scenario_points` for scenario lists — build one
+:class:`KernelPoints`, the axis tables and per-point codes every kernel
+takes.  :func:`grid_evaluator` memoizes the prepared *structure* of a
+grid (kernel points, label lists and, once built, the jax backend's
+evaluator) keyed by grid value and resolved table identity — numeric
+results are recomputed on every :meth:`GridEvaluator.run`, never
+cached.
 """
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
 from typing import Sequence
 
@@ -320,6 +325,73 @@ def _policy_axis(names: Sequence[str]) -> _PolicyAxis:
         tl_specs=list(specs))
 
 
+def _het_multipliers(wtab: dict[str, np.ndarray], hk: np.ndarray,
+                     synck: np.ndarray):
+    """``(tmul, bwmul, latmul)``: the slowest-worker multipliers of the
+    points whose padded worker-table rows are ``hk``
+    (:func:`repro.core.analytical.worker_bottleneck`), the compute one
+    the ``synck``-th order statistic of the per-worker rates where some
+    point has a partial-sync threshold."""
+    tm, bm, lm = analytical.worker_bottleneck(
+        wtab["inv_speed"], wtab["bw_mult"], wtab["lat_mult"])
+    if bool((synck != 0).any()):
+        nrow = wtab["n"][hk]
+        tmul = analytical.kth_order_statistic(
+            wtab["inv_speed"][hk], nrow,
+            analytical.effective_sync_k(synck, nrow))
+    else:
+        tmul = tm[hk]
+    return tmul, bm[hk], lm[hk]
+
+
+@dataclass
+class KernelPoints:
+    """The kernel points of a batch, as both front ends build them
+    (:class:`GridEvaluator` ``.points``, :func:`scenario_points`) and
+    every kernel takes them: the shared axis tables, and one entry per
+    point of each code vector.
+
+    ``w``/``c`` index ``wax``/``cax``; ``hk`` is the point's row of the
+    padded worker table ``wtab`` (:func:`repro.core.het.worker_table_rows`)
+    and ``synck`` its normalized sync threshold (``0`` = full sync).
+    ``tmul``/``bwmul``/``latmul`` are the slowest-worker multipliers, all
+    ``None`` on a homogeneous batch, so the kernel's fast path stays
+    literally untouched.  ``epx`` and each point's ``(workload, ep)``
+    pair row ``we`` and group size ``ep`` are ``None`` on a batch
+    without ``ep > 1``.  A new mechanism adds its codes here, filled by
+    both front ends."""
+
+    wax: _WorkloadAxis
+    cax: _ClusterAxis
+    pax: _PolicyAxis
+    wtab: dict[str, np.ndarray]
+    epx: _EPAxis | None
+    w: np.ndarray
+    c: np.ndarray
+    coll: np.ndarray
+    n: np.ndarray
+    batch: np.ndarray
+    hk: np.ndarray
+    synck: np.ndarray
+    tmul: np.ndarray | None
+    bwmul: np.ndarray | None
+    latmul: np.ndarray | None
+    we: np.ndarray | None
+    ep: np.ndarray | None
+
+    def take(self, idx) -> "KernelPoints":
+        """The points ``idx`` (an index array or a slice): every code
+        vector gathered, the tables shared, ``None`` codes kept."""
+        return dataclasses.replace(self, **{
+            f: getattr(self, f)[idx] for f in _POINT_CODES
+            if getattr(self, f) is not None})
+
+
+#: The per-point fields of :class:`KernelPoints`.
+_POINT_CODES = ("w", "c", "coll", "n", "batch", "hk", "synck", "tmul",
+                "bwmul", "latmul", "we", "ep")
+
+
 # ----------------------------------------------------------------------
 # Tier 1: the affine kernel — policy-independent cost terms.
 # ----------------------------------------------------------------------
@@ -406,12 +478,9 @@ def _collective_coeffs(cax: _ClusterAxis, cidx: np.ndarray,
     return per_byte, per_message
 
 
-def _compute_row_map(wax: _WorkloadAxis, cax: _ClusterAxis,
-                     widx: np.ndarray, cidx: np.ndarray,
-                     batch: np.ndarray,
-                     tmul: np.ndarray | None = None):
-    """``(uw, uc, ubatch, ut, uk)``: the unique *compute rows* of a
-    point set and the point -> row map.  ``t_f``/``t_b`` (and
+def _compute_row_map(points: KernelPoints):
+    """``(uw, uc, ubatch, ut, uk)``: the unique *compute rows* of
+    ``points`` and the point -> row map.  ``t_f``/``t_b`` (and
     everything derived from them: prefix/suffix sums, ``comp``) depend
     only on ``(workload, device rate, batch)`` — on a product grid that
     is a tiny set (workloads x devices, not x interconnects x workers x
@@ -424,7 +493,8 @@ def _compute_row_map(wax: _WorkloadAxis, cax: _ClusterAxis,
     per-unique-row ``ut`` column (``None`` when not given).  A constant
     ``tmul`` contributes one key level and leaves the row set (and the
     homogeneous path) unchanged."""
-    urate, rinv = np.unique(cax.rate[cidx], return_inverse=True)
+    widx, cidx, batch, tmul = points.w, points.c, points.batch, points.tmul
+    urate, rinv = np.unique(points.cax.rate[cidx], return_inverse=True)
     ubv, binv = np.unique(batch, return_inverse=True)
     key = (widx * len(ubv) + binv) * len(urate) + rinv
     if tmul is not None:
@@ -435,17 +505,8 @@ def _compute_row_map(wax: _WorkloadAxis, cax: _ClusterAxis,
     return widx[rep], cidx[rep], batch[rep], ut, uk
 
 
-def _kernel_cols(wax: _WorkloadAxis, cax: _ClusterAxis,
-                 widx: np.ndarray, cidx: np.ndarray, coll: np.ndarray,
-                 n: np.ndarray, batch: np.ndarray,
-                 tl_specs: Sequence[tuple[float, bool]] = (),
-                 chunk: int = KERNEL_CHUNK,
-                 tmul: np.ndarray | None = None,
-                 bwmul: np.ndarray | None = None,
-                 latmul: np.ndarray | None = None,
-                 epx: _EPAxis | None = None,
-                 we: np.ndarray | None = None,
-                 ep: np.ndarray | None = None) -> dict[str, np.ndarray]:
+def _kernel_cols(points: KernelPoints,
+                 chunk: int = KERNEL_CHUNK) -> dict[str, np.ndarray]:
     """Policy-independent terms for every kernel point, reduced over
     the layer axis: ``(K,)`` vectors of ``io_h2d``, ``t_h2d``, ``comp``
     (= sum t_f + sum t_b), ``sum_c``, ``tc_no``, ``t_u``, plus the
@@ -464,7 +525,7 @@ def _kernel_cols(wax: _WorkloadAxis, cax: _ClusterAxis,
     masked max for the WFBP residual, built ``chunk`` points at a time
     so huge grids stay in bounded memory.
 
-    ``tl_specs`` (from :attr:`_PolicyAxis.tl_specs`) adds one
+    ``points.pax.tl_specs`` (:attr:`_PolicyAxis.tl_specs`) adds one
     bucket-timeline residual column ``tl<i>`` per unique
     ``(bucket_bytes, overlap_comm)`` pair, through the same affine
     collapse: bucket structure from the shared
@@ -476,7 +537,7 @@ def _kernel_cols(wax: _WorkloadAxis, cax: _ClusterAxis,
     :func:`repro.core.bucketsim.timeline_residual` makespan, never
     materializing a per-point duration matrix.
 
-    ``tmul``/``bwmul``/``latmul`` (all ``(K,)`` or ``None``) are the
+    ``points.tmul``/``bwmul``/``latmul`` (all ``(K,)`` or ``None``) are the
     slowest-worker bottleneck multipliers of the heterogeneity engine —
     per-worker vectors already reduced by
     :func:`repro.core.analytical.worker_bottleneck` (and, on the Monte
@@ -489,8 +550,8 @@ def _kernel_cols(wax: _WorkloadAxis, cax: _ClusterAxis,
     HBM-bandwidth-bound, not compute-rate-bound, so neither is scaled.
     All-ones multipliers are bit-identity (IEEE ``x * 1.0 == x``).
 
-    Expert parallelism (``epx`` with the per-point pair codes ``we``
-    and group sizes ``ep``, all ``None`` for a batch without it) adds
+    Expert parallelism (``points.epx`` with the per-point pair codes
+    ``we`` and group sizes ``ep``, all ``None`` for a batch without it) adds
     two more affine pairs per point.  The all-to-alls: four a layer, at
     ``alltoall_coeffs`` over the EP group times ``batch`` times the
     layer's bytes per sample, two of them in the backward pass — so
@@ -502,7 +563,9 @@ def _kernel_cols(wax: _WorkloadAxis, cax: _ClusterAxis,
     ``dcum``/``dcnt``, in ``sum_c``, the WFBP candidates and the bucket
     durations; ``t_u`` reads the pair's per-device parameter bytes.
     """
-    K = len(widx)
+    wax, cax, epx = points.wax, points.cax, points.epx
+    tl_specs = points.pax.tl_specs
+    K = len(points.w)
     # Per-workload layer tables: inclusive payload/count prefix sums
     # (forward order) for the affine WFBP residual, plus the bucket
     # structure + suffix tables per timeline spec — all O(W x L), built
@@ -523,16 +586,14 @@ def _kernel_cols(wax: _WorkloadAxis, cax: _ClusterAxis,
         out[f"tl{i}"] = np.empty(K)
     for lo in range(0, K, chunk):
         sl = slice(lo, lo + chunk)
-        w, c = widx[sl], cidx[sl]
-        nn, cl = n[sl], coll[sl]
-        batch_f = np.where(batch[sl] > 0, batch[sl],
+        p = points.take(sl)
+        w, c, nn, cl = p.w, p.c, p.n, p.coll
+        batch_f = np.where(p.batch > 0, p.batch,
                            wax.batch_default[w]).astype(np.float64)
         n_f = nn.astype(np.float64)
 
         # compute costs: (U, L) on the unique compute rows only
-        uw, uc, ub, ut, uk = _compute_row_map(
-            wax, cax, w, c, batch[sl],
-            None if tmul is None else tmul[sl])
+        uw, uc, ub, ut, uk = _compute_row_map(p)
         ubatch_f = np.where(ub > 0, ub,
                             wax.batch_default[uw]).astype(np.float64)
         tfa = wax.flops[uw] * ubatch_f[:, None] / cax.rate[uc][:, None]
@@ -552,11 +613,10 @@ def _kernel_cols(wax: _WorkloadAxis, cax: _ClusterAxis,
         total_b = total_b_u[uk]
 
         # per-point affine collective coefficients
-        bm = None if bwmul is None else bwmul[sl]
-        lm = None if latmul is None else latmul[sl]
+        bm, lm = p.bwmul, p.latmul
         per_byte, per_message = _collective_coeffs(cax, c, cl, nn, bm, lm)
         if epx is not None:
-            pe, ep_k = we[sl], ep[sl]
+            pe, ep_k = p.we, p.ep
             e_byte, e_msg = _collective_coeffs(
                 cax, c, cl, nn // ep_k, bm, lm,
                 gpn=np.maximum(cax.gpn[c] // ep_k, 1))
@@ -761,34 +821,21 @@ def select_to_columns(cols: dict[str, np.ndarray],
 # Failure-model Monte Carlo: per-draw kernel evaluation, reduced to
 # tails (straggler jitter, K-of-N sync, fault injection).
 # ----------------------------------------------------------------------
-def _apply_mc_tails(wax: _WorkloadAxis, cax: _ClusterAxis, pax: _PolicyAxis,
-                    widx: np.ndarray, cidx: np.ndarray, coll: np.ndarray,
-                    n: np.ndarray, batch: np.ndarray, polidx: np.ndarray,
-                    hks: np.ndarray, wtab: dict[str, np.ndarray],
-                    bwmul: np.ndarray | None, latmul: np.ndarray | None,
-                    st_specs: Sequence, stidx: np.ndarray,
+def _apply_mc_tails(points: KernelPoints, codes: dict[str, np.ndarray],
+                    specs: tuple[Sequence, Sequence],
                     cols: dict[str, np.ndarray], seed: int,
-                    active: np.ndarray | None = None,
-                    synck: np.ndarray | None = None,
-                    ft_specs: Sequence = (None,),
-                    fidx: np.ndarray | None = None,
-                    epx: _EPAxis | None = None,
-                    we: np.ndarray | None = None,
-                    ep: np.ndarray | None = None) -> None:
+                    active: np.ndarray | None = None) -> None:
     """Attach ``t_mean_s``/``t_p95_s``/``t_p99_s`` to a
     :func:`_policy_select` output in place.
 
-    Every input array is per-*row*: ``widx``/``cidx``/``coll``/``n``/
-    ``batch`` locate the row's kernel point, ``polidx`` its policy,
-    ``hks`` its padded worker-table row in ``wtab``
-    (:func:`repro.core.het.worker_table_rows`), ``stidx`` its spec in
-    ``st_specs`` (parsed :class:`repro.core.het.StragglerSpec` or
-    ``None``), ``bwmul``/``latmul`` its deterministic slowest-link
-    multipliers, ``synck`` its normalized sync threshold (``0`` = full
-    sync) and ``fidx`` its spec in ``ft_specs`` (parsed
-    :class:`repro.core.het.FaultSpec` or ``None``); ``epx``/``we``/
-    ``ep`` its expert-parallel pair and group size, as
-    :func:`_kernel_cols` takes them.  Deterministic rows
+    Every input is per-*row*: ``points`` holds each row's kernel point
+    (a front end's points gathered at the rows, :meth:`KernelPoints.take`)
+    with its worker-table row ``hk``, its deterministic slowest-link
+    multipliers and its sync threshold ``synck``; ``codes`` holds its
+    policy ``pi``, its straggler spec ``sti`` and its fault spec ``fli``,
+    indices into ``points.pax`` and into ``specs = (st_specs,
+    ft_specs)`` (parsed :class:`repro.core.het.StragglerSpec` /
+    :class:`repro.core.het.FaultSpec` or ``None``).  Deterministic rows
     (no stochastic spec) keep the point-mass default — tails equal to
     ``iteration_time_s``, bit-exact.
 
@@ -828,10 +875,9 @@ def _apply_mc_tails(wax: _WorkloadAxis, cax: _ClusterAxis, pax: _PolicyAxis,
     cols["t_mean_s"] = t_iter.copy()
     cols["t_p95_s"] = t_iter.copy()
     cols["t_p99_s"] = t_iter.copy()
-    if synck is None:
-        synck = np.zeros(len(t_iter), dtype=np.int64)
-    if fidx is None:
-        fidx = np.zeros(len(t_iter), dtype=np.int64)
+    st_specs, ft_specs = specs
+    pax, wtab, hks, synck = points.pax, points.wtab, points.hk, points.synck
+    polidx, stidx, fidx = codes["pi"], codes["sti"], codes["fli"]
     for si, st in enumerate(st_specs):
         st_live = st is not None and not st.is_deterministic
         for fi, ft in enumerate(ft_specs):
@@ -847,10 +893,12 @@ def _apply_mc_tails(wax: _WorkloadAxis, cax: _ClusterAxis, pax: _PolicyAxis,
             # one MC evaluation per unique (kernel point, policy,
             # worker table, sync_k) tuple — rows sharing all four see
             # identical draws
-            key = np.stack([widx[rows], cidx[rows], coll[rows], n[rows],
-                            batch[rows], polidx[rows], hks[rows],
+            key = np.stack([points.w[rows], points.c[rows],
+                            points.coll[rows], points.n[rows],
+                            points.batch[rows], polidx[rows], hks[rows],
                             synck[rows]]
-                           + ([] if epx is None else [we[rows]]), axis=1)
+                           + ([] if points.epx is None
+                              else [points.we[rows]]), axis=1)
             _, rep, uinv = np.unique(key, axis=0, return_index=True,
                                      return_inverse=True)
             urows = rows[rep]
@@ -884,14 +932,8 @@ def _apply_mc_tails(wax: _WorkloadAxis, cax: _ClusterAxis, pax: _PolicyAxis,
                 pt = urows[lo:lo + blk]
                 m = len(pt)
                 rp = np.repeat(pt, D)
-                kc = _kernel_cols(
-                    wax, cax, widx[rp], cidx[rp], coll[rp], n[rp],
-                    batch[rp], tl_specs=pax.tl_specs,
-                    tmul=tmuls[lo:lo + m].ravel(),
-                    bwmul=None if bwmul is None else bwmul[rp],
-                    latmul=None if latmul is None else latmul[rp],
-                    epx=epx, we=None if epx is None else we[rp],
-                    ep=None if epx is None else ep[rp])
+                kc = _kernel_cols(dataclasses.replace(
+                    points.take(rp), tmul=tmuls[lo:lo + m].ravel()))
                 ti = _policy_select(
                     pax, polidx[rp], kc, kidx=None,
                     chain_extra=None if pens is None
@@ -948,10 +990,9 @@ class GridEvaluator:
         self.n_scenarios = (nW * nC * nK * nE * nP * nA * nI * nH * nT
                             * nQ * nF)
 
-        self._wax = _workload_axis(grid.workloads)
+        wax = _workload_axis(grid.workloads)
         pairs = [(c, ic) for c in grid.clusters for ic in grid.interconnects]
-        self._cax = _cluster_axis(pairs)
-        self._pax = _policy_axis(grid.policies)
+        pax = _policy_axis(grid.policies)
 
         # Kernel grid: the scenario product with the policy, straggler
         # and fault axes dropped — order (workloads, clusters, workers,
@@ -962,36 +1003,25 @@ class GridEvaluator:
         # bottleneck multipliers, and the sync_k axis does too — it
         # picks *which* order statistic of the per-worker rates gates
         # the iteration.  O(K) int vectors; every per-*scenario*
-        # quantity is derived per chunk instead (see _scenario_codes),
+        # quantity is derived per chunk instead (see scenario_codes),
         # so preparation stays O(axes + K) however large the scenario
         # product is.
         kw, kc, kk, ke, ka, ki, kh, kq = _axis_codes(
             (nW, nC, nK, nE, nA, nI, nH, nQ))
-        self._kwidx = kw
-        self._kcidx = kc * nI + ki              # (cluster, interconnect) pair
-        self._kcoll = np.array(
-            [_COLLECTIVE_CODE[c] for c in grid.collectives],
-            dtype=np.int64)[ka]
-        self._kn = np.array([int(k) for k in grid.worker_counts],
-                            dtype=np.int64)[kk]
-        self._kbatch = np.full(len(kw), grid.batch_per_gpu or 0,
-                               dtype=np.int64)
-        self._khk = kh * nK + kk                # (het profile, n) pair row
+        kbatch = np.full(len(kw), grid.batch_per_gpu or 0, dtype=np.int64)
+        khk = kh * nK + kk                      # (het profile, n) pair row
         sk_values = np.array(
             [normalize_sync_k(k) for k in grid.sync_ks], dtype=np.int64)
-        self._ksynck = sk_values[kq]            # 0 = full sync
-        _check_batch_locked(self._wax, kw, self._kbatch)
+        ksynck = sk_values[kq]                  # 0 = full sync
+        _check_batch_locked(wax, kw, kbatch)
 
         # Expert parallelism: one table row per (workload, ep) pair,
         # built only when some point has ep > 1 (else the kernel runs
         # exactly as without the axis).
         self._ep_values = np.array([int(e) for e in grid.ep_sizes],
                                    dtype=np.int64)
-        self._kep = self._ep_values[ke]
-        self._kwe = kw * nE + ke                # (workload, ep) pair row
-        self._epx = _ep_axis(self._wax, np.repeat(np.arange(nW), nE),
-                             np.tile(self._ep_values, nW),
-                             self._pax.tl_specs) \
+        epx = _ep_axis(wax, np.repeat(np.arange(nW), nE),
+                       np.tile(self._ep_values, nW), pax.tl_specs) \
             if bool((self._ep_values > 1).any()) else None
 
         # Heterogeneity: one padded per-worker table row per (profile,
@@ -1003,38 +1033,36 @@ class GridEvaluator:
         # rate vector is 1.0; a partial-sync threshold only changes the
         # *deterministic* kernel point when workers actually differ.
         profiles = [het_mod.parse_het_profile(h) for h in grid.het_profiles]
-        self._wtab = het_mod.worker_table_rows(
+        wtab = het_mod.worker_table_rows(
             [(prof, int(n)) for prof in profiles
              for n in grid.worker_counts])
-        self._any_het = any(p is not None for p in profiles)
-        self._any_synck = bool((sk_values != 0).any())
-        if self._any_het:
-            tm, bm, lm = analytical.worker_bottleneck(
-                self._wtab["inv_speed"], self._wtab["bw_mult"],
-                self._wtab["lat_mult"])
-            self._kbwmul = bm[self._khk]
-            self._klatmul = lm[self._khk]
-            if self._any_synck:
-                nrow = self._wtab["n"][self._khk]
-                self._ktmul = analytical.kth_order_statistic(
-                    self._wtab["inv_speed"][self._khk], nrow,
-                    analytical.effective_sync_k(self._ksynck, nrow))
-            else:
-                self._ktmul = tm[self._khk]
-        else:
-            self._ktmul = self._kbwmul = self._klatmul = None
-        self._st_specs = [het_mod.parse_straggler(s)
-                          for s in grid.stragglers]
-        self._ft_specs = [het_mod.parse_fault(f) for f in grid.faults]
-        self._any_mc = (
-            any(s is not None and not s.is_deterministic
-                for s in self._st_specs)
-            or any(f is not None and not f.is_deterministic
-                   for f in self._ft_specs))
+        tmul, bwmul, latmul = _het_multipliers(wtab, khk, ksynck) \
+            if any(p is not None for p in profiles) else (None,) * 3
+        self.points = KernelPoints(
+            wax=wax, cax=_cluster_axis(pairs), pax=pax, wtab=wtab, epx=epx,
+            w=kw, c=kc * nI + ki,               # (cluster, interconnect) pair
+            coll=np.array([_COLLECTIVE_CODE[c] for c in grid.collectives],
+                          dtype=np.int64)[ka],
+            n=np.array([int(k) for k in grid.worker_counts],
+                       dtype=np.int64)[kk],
+            batch=kbatch, hk=khk, synck=ksynck,
+            tmul=tmul, bwmul=bwmul, latmul=latmul,
+            we=None if epx is None else kw * nE + ke,   # (workload, ep) row
+            ep=None if epx is None else self._ep_values[ke])
+        #: ``(st_specs, ft_specs)``: the parsed straggler and fault specs
+        #: the scenario codes ``sti``/``fli`` index.
+        self.specs = ([het_mod.parse_straggler(s) for s in grid.stragglers],
+                      [het_mod.parse_fault(f) for f in grid.faults])
+        self.any_mc = any(x is not None and not x.is_deterministic
+                          for x in self.specs[0] + self.specs[1])
+        #: The :class:`repro.core.batched_jax.JaxGridEvaluator` built on
+        #: this structure once the jax backend has used it: it lives as
+        #: long as this evaluator's structure-memo entry.
+        self.jax = None
 
         per_policy = self.n_scenarios // nP if nP else 0
-        self.n_fast = per_policy * int(self._pax.has_fast.sum())
-        self.n_timeline = per_policy * int(self._pax.has_tl.sum())
+        self.n_fast = per_policy * int(pax.has_fast.sum())
+        self.n_timeline = per_policy * int(pax.has_tl.sum())
         self.all_batched = \
             self.n_fast + self.n_timeline == self.n_scenarios
 
@@ -1063,14 +1091,7 @@ class GridEvaluator:
     def __len__(self) -> int:
         return self.n_scenarios
 
-    def _ep_kwargs(self, k: np.ndarray | slice = slice(None)) -> dict:
-        """The kernel's expert-parallel arguments for kernel points
-        ``k`` (none on a grid without ``ep > 1``)."""
-        if self._epx is None:
-            return {}
-        return {"epx": self._epx, "we": self._kwe[k], "ep": self._kep[k]}
-
-    def _scenario_codes(self, lo: int, hi: int) -> dict[str, np.ndarray]:
+    def scenario_codes(self, lo: int, hi: int) -> dict[str, np.ndarray]:
         """Axis codes, the kernel-point map and the fast mask for flat
         scenario indices ``[lo, hi)``, derived arithmetically from the
         expand() order (rightmost axis fastest) — O(chunk) work and
@@ -1102,9 +1123,10 @@ class GridEvaluator:
         return {"wi": wi, "ci": ci, "ki": ki, "ei": ei, "pi": pi, "ai": ai,
                 "ii": ii, "hi": hp, "sti": sti, "ski": ski, "fli": fli,
                 "kidx": kidx,
-                "batched": self._pax.has_fast[pi] | self._pax.has_tl[pi]}
+                "batched": (self.points.pax.has_fast[pi]
+                            | self.points.pax.has_tl[pi])}
 
-    def _label_columns(self, codes: dict[str, np.ndarray]) -> dict:
+    def label_columns(self, codes: dict[str, np.ndarray]) -> dict:
         return {
             "workload": self._wl_values[codes["wi"]],
             "cluster": self._cl_values[codes["ci"]],
@@ -1126,35 +1148,20 @@ class GridEvaluator:
         Monte Carlo pass (:func:`_apply_mc_tails`) on stochastic rows.
         Simulator-fallback rows are excluded — their tails come from
         the per-draw oracle in :mod:`repro.core.sweep`."""
-        if not self._any_mc:
+        if not self.any_mc:
             t_iter = np.asarray(cols["iteration_time_s"])
             cols["t_mean_s"] = t_iter
             cols["t_p95_s"] = t_iter
             cols["t_p99_s"] = t_iter
             return
-        k = codes["kidx"]
-        _apply_mc_tails(
-            self._wax, self._cax, self._pax,
-            self._kwidx[k], self._kcidx[k], self._kcoll[k], self._kn[k],
-            self._kbatch[k], codes["pi"], self._khk[k], self._wtab,
-            None if self._kbwmul is None else self._kbwmul[k],
-            None if self._klatmul is None else self._klatmul[k],
-            self._st_specs, codes["sti"], cols, seed,
-            active=codes["batched"], synck=self._ksynck[k],
-            ft_specs=self._ft_specs, fidx=codes["fli"],
-            **self._ep_kwargs(k))
+        _apply_mc_tails(self.points.take(codes["kidx"]), codes, self.specs,
+                        cols, seed, active=codes["batched"])
 
     def run(self, seed: int = 0) -> "GridRun":
         """Evaluate the kernel grid (fresh numbers every call) and
         return the per-run table materializer.  ``seed`` keys the
         straggler Monte Carlo draws (ignored on deterministic grids)."""
-        return GridRun(self, _kernel_cols(
-            self._wax, self._cax, self._kwidx, self._kcidx,
-            self._kcoll, self._kn, self._kbatch,
-            tl_specs=self._pax.tl_specs,
-            tmul=self._ktmul, bwmul=self._kbwmul, latmul=self._klatmul,
-            **self._ep_kwargs()),
-            seed=seed)
+        return GridRun(self, _kernel_cols(self.points), seed=seed)
 
     def run_span(self, lo: int, hi: int, seed: int = 0):
         """Evaluate just the flat scenario indices ``[lo, hi)`` —
@@ -1164,19 +1171,12 @@ class GridEvaluator:
         per-row batched mask (``False`` rows carry tier-2 placeholder
         numbers the caller must overwrite with the simulator — see
         :mod:`repro.core.parallel`)."""
-        codes = self._scenario_codes(lo, hi)
+        codes = self.scenario_codes(lo, hi)
         uk, inv = np.unique(codes["kidx"], return_inverse=True)
-        kc = _kernel_cols(
-            self._wax, self._cax, self._kwidx[uk], self._kcidx[uk],
-            self._kcoll[uk], self._kn[uk], self._kbatch[uk],
-            tl_specs=self._pax.tl_specs,
-            tmul=None if self._ktmul is None else self._ktmul[uk],
-            bwmul=None if self._kbwmul is None else self._kbwmul[uk],
-            latmul=None if self._klatmul is None else self._klatmul[uk],
-            **self._ep_kwargs(uk))
-        cols = _policy_select(self._pax, codes["pi"], kc, inv)
+        kc = _kernel_cols(self.points.take(uk))
+        cols = _policy_select(self.points.pax, codes["pi"], kc, inv)
         self._apply_tails(codes, cols, seed)
-        return (select_to_columns(cols, self._label_columns(codes)),
+        return (select_to_columns(cols, self.label_columns(codes)),
                 codes["batched"])
 
     def scenario_at(self, i: int) -> Scenario:
@@ -1207,8 +1207,9 @@ class GridRun:
         kernel-only surface the throughput benchmark times and the jax
         backend's differential gate compares against."""
         ev = self._ev
-        codes = ev._scenario_codes(lo, hi)
-        cols = _policy_select(ev._pax, codes["pi"], self._kc, codes["kidx"])
+        codes = ev.scenario_codes(lo, hi)
+        cols = _policy_select(ev.points.pax, codes["pi"], self._kc,
+                              codes["kidx"])
         ev._apply_tails(codes, cols, self._seed)
         cols["method"] = METHOD_LABELS[cols.pop("method_code")].tolist()
         return cols
@@ -1222,10 +1223,11 @@ class GridRun:
         policy needs the simulator) that the caller overwrites via
         :func:`repro.core.resulttable.fill_rows`."""
         ev = self._ev
-        codes = ev._scenario_codes(lo, hi)
-        cols = _policy_select(ev._pax, codes["pi"], self._kc, codes["kidx"])
+        codes = ev.scenario_codes(lo, hi)
+        cols = _policy_select(ev.points.pax, codes["pi"], self._kc,
+                              codes["kidx"])
         ev._apply_tails(codes, cols, self._seed)
-        return (select_to_columns(cols, ev._label_columns(codes)),
+        return (select_to_columns(cols, ev.label_columns(codes)),
                 codes["batched"])
 
     def rows_slice(self, lo: int, hi: int) -> list[dict | None]:
@@ -1243,89 +1245,100 @@ class GridRun:
 #: Structure memo: prepared evaluators keyed by grid value + the
 #: identity of the resolved workload tables (holding the tables alive
 #: keeps the ids stable; a re-resolved table — e.g. an on-disk trace
-#: whose mtime changed — misses the memo and rebuilds).
-_EVALUATOR_MEMO: dict = {}
+#: whose mtime changed — misses the memo and rebuilds).  One memo for
+#: both backends: the jax backend's evaluator lives in its entry's
+#: :attr:`GridEvaluator.jax`.
+_STRUCTURE_MEMO: dict = {}
 _MEMO_LIMIT = 64
 
 
-def grid_evaluator(grid: ScenarioGrid) -> GridEvaluator:
-    """Memoized :class:`GridEvaluator` for ``grid`` (falls back to a
-    fresh instance when the grid isn't hashable, e.g. list-valued
-    axes)."""
+def _memo_key(grid: ScenarioGrid):
+    """``(key, tables)``: ``grid``'s structure-memo key and the resolved
+    workload tables it names; ``key`` is ``None`` where the grid is not
+    hashable (e.g. list-valued axes)."""
     try:
         tables = tuple(resolve_workload(w) for w in grid.workloads)
         key = (grid, tuple(id(t) for t in tables))
         hash(key)
     except TypeError:
+        return None, ()
+    return key, tables
+
+
+def grid_evaluator(grid: ScenarioGrid) -> GridEvaluator:
+    """Memoized :class:`GridEvaluator` for ``grid`` (falls back to a
+    fresh instance when the grid isn't hashable)."""
+    key, tables = _memo_key(grid)
+    if key is None:
         return GridEvaluator(grid)
-    hit = _EVALUATOR_MEMO.get(key)
+    hit = _STRUCTURE_MEMO.get(key)
     if hit is not None:
         return hit[0]
-    if len(_EVALUATOR_MEMO) >= _MEMO_LIMIT:
-        _EVALUATOR_MEMO.clear()
+    if len(_STRUCTURE_MEMO) >= _MEMO_LIMIT:
+        _STRUCTURE_MEMO.clear()
     ev = GridEvaluator(grid)
-    _EVALUATOR_MEMO[key] = (ev, tables)
+    _STRUCTURE_MEMO[key] = (ev, tables)
     return ev
 
 
-def evaluator_cached(grid: ScenarioGrid) -> bool:
-    """True when :func:`grid_evaluator` would hit the structure memo —
-    a pure probe (nothing is built, no entry is added), which is how
-    the sweep service (:mod:`repro.core.service`) accounts cache
-    hit/miss rates without perturbing the cache it is measuring."""
+def cached_evaluator(grid: ScenarioGrid) -> GridEvaluator | None:
+    """The memoized :class:`GridEvaluator` of ``grid``, or ``None`` —
+    a pure probe (nothing is built, no entry is added): how the jax
+    backend finds its evaluator, and how the sweep service
+    (:mod:`repro.core.service`) accounts cache hit/miss rates without
+    perturbing the cache it is measuring."""
     try:
-        tables = tuple(resolve_workload(w) for w in grid.workloads)
-        key = (grid, tuple(id(t) for t in tables))
-        hash(key)
-    except (TypeError, ValueError):
-        return False
-    return key in _EVALUATOR_MEMO
+        key, _ = _memo_key(grid)
+    except ValueError:
+        return None
+    hit = None if key is None else _STRUCTURE_MEMO.get(key)
+    return None if hit is None else hit[0]
 
 
 # ----------------------------------------------------------------------
 # Scenario-list front end (arbitrary iterables, already validated).
 # ----------------------------------------------------------------------
-def scenario_axes(scenarios: Sequence[Scenario]):
-    """One Python pass over a scenario list: resolve the unique
-    workload/cluster-pair/policy axes and the per-scenario code
-    vectors.  Returns ``(wax, cax, pax, widx, cidx, polidx, coll, n,
-    batch)`` — the inputs of the two-tier kernel with the identity
-    scenario -> kernel-point map.  Shared by :func:`eval_scenarios`
-    and the jax backend's list front end
-    (:func:`repro.core.batched_jax.eval_scenarios_jax`), raising
-    ``ValueError`` if any scenario's policy has neither a closed nor a
-    bucket-timeline form.
+def scenario_points(scenarios: Sequence[Scenario]):
+    """One Python pass over a scenario list: ``(points, codes, specs)``,
+    the list front end's :attr:`GridEvaluator.points`,
+    :meth:`GridEvaluator.scenario_codes` (``pi``/``sti``/``fli``) and
+    :attr:`GridEvaluator.specs`, with the identity scenario ->
+    kernel-point map.  The axes are the list's unique workloads,
+    ``(cluster, interconnect)`` pairs, policies, ``(het, n_workers)``
+    worker-table rows, straggler and fault specs and, where some
+    scenario has ``ep_size > 1``, ``(workload, ep)`` pairs.  Shared by
+    :func:`eval_scenarios_table` and the jax backend's list front end
+    (:func:`repro.core.batched_jax.eval_scenarios_table_jax`), so both
+    backends agree on structure; raises ``ValueError`` if any
+    scenario's policy has neither a closed nor a bucket-timeline form.
     """
-    wl_key: dict[str, int] = {}
-    pair_key: dict[tuple[str, str | None], int] = {}
-    pol_key: dict[str, int] = {}
-    widx = np.empty(len(scenarios), dtype=np.int64)
-    cidx = np.empty(len(scenarios), dtype=np.int64)
-    polidx = np.empty(len(scenarios), dtype=np.int64)
-    coll = np.empty(len(scenarios), dtype=np.int64)
-    n = np.empty(len(scenarios), dtype=np.int64)
-    batch = np.empty(len(scenarios), dtype=np.int64)
+    keys: dict[str, dict] = {k: {} for k in ("w", "c", "p", "h", "s", "f")}
+
+    def code(axis: str, value) -> int:
+        d = keys[axis]
+        i = d.get(value)
+        if i is None:
+            i = d[value] = len(d)
+        return i
+
+    S = len(scenarios)
+    widx, cidx, polidx, coll, n, batch, hks, stidx, fidx, synck, ep = (
+        np.empty(S, dtype=np.int64) for _ in range(11))
     for i, s in enumerate(scenarios):
-        wi = wl_key.get(s.workload)
-        if wi is None:
-            wi = wl_key[s.workload] = len(wl_key)
-        widx[i] = wi
-        pk = (s.cluster, s.interconnect)
-        ci = pair_key.get(pk)
-        if ci is None:
-            ci = pair_key[pk] = len(pair_key)
-        cidx[i] = ci
-        pi = pol_key.get(s.policy)
-        if pi is None:
-            pi = pol_key[s.policy] = len(pol_key)
-        polidx[i] = pi
+        widx[i] = code("w", s.workload)
+        cidx[i] = code("c", (s.cluster, s.interconnect))
+        polidx[i] = code("p", s.policy)
         coll[i] = _COLLECTIVE_CODE[s.collective]
         n[i] = s.n_workers
         batch[i] = s.batch_per_gpu or 0
-    wax = _workload_axis(list(wl_key))
+        hks[i] = code("h", (het_mod.normalize_het(s.het), int(s.n_workers)))
+        stidx[i] = code("s", het_mod.normalize_straggler(s.straggler))
+        fidx[i] = code("f", het_mod.normalize_fault(s.faults))
+        synck[i] = normalize_sync_k(s.sync_k)
+        ep[i] = s.ep_size
+    wax = _workload_axis(list(keys["w"]))
     _check_batch_locked(wax, widx, batch)
-    cax = _cluster_axis(list(pair_key))
-    pax = _policy_axis(list(pol_key))
+    pax = _policy_axis(list(keys["p"]))
     batched_ok = pax.has_fast | pax.has_tl
     if not bool(batched_ok[polidx].all()):
         bad = [pax.names[int(p)]
@@ -1333,86 +1346,24 @@ def scenario_axes(scenarios: Sequence[Scenario]):
         raise ValueError(f"policies with neither a closed form nor a "
                          f"bucket-timeline form cannot take the batched "
                          f"path: {bad}")
-    return wax, cax, pax, widx, cidx, polidx, coll, n, batch
-
-
-def scenario_ep_axes(scenarios: Sequence[Scenario], wax: _WorkloadAxis,
-                     widx: np.ndarray, pax: _PolicyAxis) -> dict:
-    """The kernel's expert-parallel arguments for a scenario list (the
-    :meth:`GridEvaluator._ep_kwargs` of the list front end): the
-    :class:`_EPAxis` over the list's unique ``(workload, ep)`` pairs and
-    each scenario's pair row and group size — none when every scenario
-    has ``ep_size == 1``.  Shared with the jax list front end."""
-    ep = np.array([s.ep_size for s in scenarios], dtype=np.int64)
-    if not bool((ep > 1).any()):
-        return {}
-    pairs, we = np.unique(np.stack([widx, ep], axis=1), axis=0,
-                          return_inverse=True)
-    return {"epx": _ep_axis(wax, pairs[:, 0], pairs[:, 1], pax.tl_specs),
-            "we": we.reshape(-1), "ep": ep}
-
-
-def scenario_het_axes(scenarios: Sequence[Scenario]):
-    """One Python pass over a scenario list: the heterogeneity /
-    failure-model structure the kernel and the Monte Carlo pass need.
-    Returns ``(hks, wtab, tmul, bwmul, latmul, st_specs, stidx, synck,
-    ft_specs, fidx)`` — per-scenario rows into a padded worker table
-    over the unique ``(het, n_workers)`` pairs, the reduced bottleneck
-    multiplier vectors (``None`` when every scenario is homogeneous,
-    keeping the kernel's fast path untouched; the compute multiplier is
-    the ``sync_k``-th order statistic when a partial-sync threshold is
-    present), the unique parsed straggler specs with the per-scenario
-    index, the normalized per-scenario sync thresholds (``0`` = full
-    sync) and the unique parsed fault specs with the per-scenario
-    index.  Shared with the jax list front end so both backends agree
-    on structure."""
-    pair_key: dict[tuple[str, int], int] = {}
-    st_key: dict[str, int] = {}
-    fl_key: dict[str, int] = {}
-    hks = np.empty(len(scenarios), dtype=np.int64)
-    stidx = np.empty(len(scenarios), dtype=np.int64)
-    fidx = np.empty(len(scenarios), dtype=np.int64)
-    synck = np.empty(len(scenarios), dtype=np.int64)
-    any_het = False
-    for i, s in enumerate(scenarios):
-        hspec = het_mod.normalize_het(s.het)
-        pk = (hspec, int(s.n_workers))
-        j = pair_key.get(pk)
-        if j is None:
-            j = pair_key[pk] = len(pair_key)
-        hks[i] = j
-        if hspec != "none":
-            any_het = True
-        sk = het_mod.normalize_straggler(s.straggler)
-        si = st_key.get(sk)
-        if si is None:
-            si = st_key[sk] = len(st_key)
-        stidx[i] = si
-        fl = het_mod.normalize_fault(s.faults)
-        fi = fl_key.get(fl)
-        if fi is None:
-            fi = fl_key[fl] = len(fl_key)
-        fidx[i] = fi
-        synck[i] = normalize_sync_k(s.sync_k)
     wtab = het_mod.worker_table_rows(
-        [(het_mod.parse_het_profile(h), n) for h, n in pair_key])
-    if any_het:
-        tm, bm, lm = analytical.worker_bottleneck(
-            wtab["inv_speed"], wtab["bw_mult"], wtab["lat_mult"])
-        bwmul, latmul = bm[hks], lm[hks]
-        if bool((synck != 0).any()):
-            nrow = wtab["n"][hks]
-            tmul = analytical.kth_order_statistic(
-                wtab["inv_speed"][hks], nrow,
-                analytical.effective_sync_k(synck, nrow))
-        else:
-            tmul = tm[hks]
-    else:
-        tmul = bwmul = latmul = None
-    st_specs = [het_mod.parse_straggler(s) for s in st_key]
-    ft_specs = [het_mod.parse_fault(f) for f in fl_key]
-    return (hks, wtab, tmul, bwmul, latmul, st_specs, stidx,
-            synck, ft_specs, fidx)
+        [(het_mod.parse_het_profile(h), k) for h, k in keys["h"]])
+    tmul, bwmul, latmul = _het_multipliers(wtab, hks, synck) \
+        if any(h != "none" for h, _ in keys["h"]) else (None,) * 3
+    epx = we = None
+    if bool((ep > 1).any()):
+        pairs, we = np.unique(np.stack([widx, ep], axis=1), axis=0,
+                              return_inverse=True)
+        epx = _ep_axis(wax, pairs[:, 0], pairs[:, 1], pax.tl_specs)
+        we = we.reshape(-1)
+    points = KernelPoints(
+        wax=wax, cax=_cluster_axis(list(keys["c"])), pax=pax, wtab=wtab,
+        epx=epx, w=widx, c=cidx, coll=coll, n=n, batch=batch, hk=hks,
+        synck=synck, tmul=tmul, bwmul=bwmul, latmul=latmul, we=we,
+        ep=None if epx is None else ep)
+    specs = ([het_mod.parse_straggler(x) for x in keys["s"]],
+             [het_mod.parse_fault(x) for x in keys["f"]])
+    return points, {"pi": polidx, "sti": stidx, "fli": fidx}, specs
 
 
 def scenario_labels(scenarios: Sequence[Scenario]) -> dict[str, np.ndarray]:
@@ -1457,19 +1408,10 @@ def eval_scenarios_table(scenarios: Sequence[Scenario],
     Raises ``ValueError`` if any scenario's policy has neither form —
     callers (:func:`repro.core.sweep.sweep`) partition first.
     """
-    wax, cax, pax, widx, cidx, polidx, coll, n, batch = \
-        scenario_axes(scenarios)
-    (hks, wtab, tmul, bwmul, latmul, st_specs, stidx,
-     synck, ft_specs, fidx) = scenario_het_axes(scenarios)
-    epkw = scenario_ep_axes(scenarios, wax, widx, pax)
-    kc = _kernel_cols(wax, cax, widx, cidx, coll, n, batch,
-                      tl_specs=pax.tl_specs,
-                      tmul=tmul, bwmul=bwmul, latmul=latmul, **epkw)
-    cols = _policy_select(pax, polidx, kc, kidx=None)
-    _apply_mc_tails(wax, cax, pax, widx, cidx, coll, n, batch, polidx,
-                    hks, wtab, bwmul, latmul, st_specs, stidx,
-                    cols, seed, synck=synck, ft_specs=ft_specs, fidx=fidx,
-                    **epkw)
+    points, codes, specs = scenario_points(scenarios)
+    cols = _policy_select(points.pax, codes["pi"], _kernel_cols(points),
+                          kidx=None)
+    _apply_mc_tails(points, codes, specs, cols, seed)
     return select_to_columns(cols, scenario_labels(scenarios))
 
 

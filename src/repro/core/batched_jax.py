@@ -92,24 +92,30 @@ _NUMERIC_COLS = ("batch", "iteration_time_s", "samples_per_sec",
 # Structure extraction: axis tables -> one flat dict of arrays (a jit
 # pytree argument), prefix/suffix and bucket structure included.
 # ----------------------------------------------------------------------
-def _axes_tables(wax, cax, pax, wtab) -> tuple[dict, dict]:
-    """``(tables, pflags)`` array dicts from the NumPy engine's axis
-    dataclasses — the jit kernel's pytree inputs, including the
-    per-workload prefix tables the affine formulation gathers
-    (``cumgrad``/``cumcount`` and their totals) and the bucket suffix
-    tables per timeline spec.  ``bucket_bytes`` rides along purely as
-    a differentiation input: the partition structure (``bt<i>_*``) is
-    discrete and prebuilt, which is exactly the piecewise-constant
-    dependence documented in the module docstring.
+def _kernel_args(points: batched.KernelPoints) -> tuple:
+    """``(tables, pflags, kcodes, ucodes, tl_overlaps, coll_codes)``:
+    the jit kernel's arguments for a :class:`repro.core.batched.KernelPoints`,
+    all but the scenario codes.  ``tables`` holds the axis tables as
+    arrays, including the per-workload prefix tables the affine
+    formulation gathers (``cumgrad``/``cumcount`` and their totals), the
+    bucket suffix tables per timeline spec and, on a batch with some
+    ``ep > 1``, the expert-parallel tables as ``ep_*`` entries (with the
+    codes ``we``/``ep`` in ``kcodes``).  ``bucket_bytes`` rides along
+    purely as a differentiation input: the partition structure
+    (``bt<i>_*``) is discrete and prebuilt, which is exactly the
+    piecewise-constant dependence documented in the module docstring.
 
-    ``wtab`` is the padded per-worker table
+    ``w_inv``/``w_bw``/``w_lat`` are the padded per-worker table
     (:func:`repro.core.het.worker_table_rows`) over the unique
     ``(het profile, n_workers)`` pairs: the kernel reduces the gathered
     rows with :func:`repro.core.analytical.worker_bottleneck` *inside*
     the jit, so the het link derating shards and differentiates with
     everything else.  On all-homogeneous inputs every row is ones (the
     pads are neutral) and the reduction multiplies by exactly 1.0 —
-    bit-identity, same contract as the NumPy kernel's ``None`` path."""
+    bit-identity, same contract as the NumPy kernel's ``None`` path.
+    ``ucodes`` are the unique compute rows
+    (:func:`repro.core.batched._compute_row_map`)."""
+    wax, cax, pax, wtab = points.wax, points.cax, points.pax, points.wtab
     grad = wax.grad_bytes
     comm_mask = (grad > 0).astype(np.float64)
     cumgrad = np.cumsum(grad, axis=1)
@@ -144,21 +150,33 @@ def _axes_tables(wax, cax, pax, wtab) -> tuple[dict, dict]:
               "overlap_comm": pax.overlap_comm,
               "h2d_early": pax.h2d_early,
               "tl_spec": pax.tl_spec}
-    return tables, pflags
+    uw, uc, ub, ut, uk = batched._compute_row_map(points)
+    kcodes = {"w": points.w, "c": points.c, "coll": points.coll,
+              "n": points.n, "batch": points.batch, "uk": uk,
+              "hk": points.hk}
+    epx = points.epx
+    if epx is not None:
+        tables.update({"ep_dcum": epx.dcum, "ep_dcnt": epx.dcnt,
+                       "ep_ecum": epx.ecum, "ep_ecnt": epx.ecnt,
+                       "ep_param_bytes": epx.param_bytes,
+                       "ep_a2a_suf": epx.a2a_suf,
+                       "ep_a2a_sufc": epx.a2a_sufc})
+        for i, b in enumerate(epx.buckets):
+            for name, x in zip(("release", "mask", "sd", "sdc", "se", "sec"),
+                               b):
+                tables[f"ep_bt{i}_{name}"] = x
+        kcodes.update(we=points.we, ep=points.ep)
+    ucodes = {"w": uw, "c": uc, "batch": ub,
+              "tmul": np.ones(len(uw)) if ut is None else ut}
+    tl_overlaps = tuple(bool(ov) for _, ov in pax.tl_specs)
+    coll_codes = tuple(int(x) for x in np.unique(points.coll)) or (0,)
+    return tables, pflags, kcodes, ucodes, tl_overlaps, coll_codes
 
 
-def _ep_tables(epx) -> dict:
-    """The expert-parallel tables of a :class:`repro.core.batched._EPAxis`
-    as ``ep_*`` entries of the kernel's ``tables`` argument."""
-    tables = {"ep_dcum": epx.dcum, "ep_dcnt": epx.dcnt,
-              "ep_ecum": epx.ecum, "ep_ecnt": epx.ecnt,
-              "ep_param_bytes": epx.param_bytes,
-              "ep_a2a_suf": epx.a2a_suf, "ep_a2a_sufc": epx.a2a_sufc}
-    for i, b in enumerate(epx.buckets):
-        for name, x in zip(("release", "mask", "sd", "sdc", "se", "sec"), b):
-            tables[f"ep_bt{i}_{name}"] = x
-    return tables
-
+def _point_counts(points: batched.KernelPoints) -> tuple[int, int]:
+    """``(kernel points, those with ep > 1)`` of ``points``."""
+    ep = 0 if points.ep is None else int((points.ep > 1).sum())
+    return len(points.n), ep
 
 
 # ----------------------------------------------------------------------
@@ -689,34 +707,22 @@ class JaxGridEvaluator:
 
     def __init__(self, grid: ScenarioGrid, *, mesh=None):
         ev = grid_evaluator(grid)
+        pax = ev.points.pax
         if not ev.all_batched:
             bad = [name for name, f, t in zip(
-                ev._pax.names, ev._pax.has_fast, ev._pax.has_tl)
+                pax.names, pax.has_fast, pax.has_tl)
                 if not (bool(f) or bool(t))]
             raise ValueError(
                 f"backend='jax' evaluates closed-form and bucket-timeline "
                 f"policies only; {bad} need the event-driven simulator. "
                 f"Use backend='numpy' for grids containing them.")
         self.ev = ev
-        self._tables, self._pflags = _axes_tables(ev._wax, ev._cax,
-                                                  ev._pax, ev._wtab)
-        self._tl_overlaps = tuple(bool(ov) for _, ov in ev._pax.tl_specs)
-        self._coll_codes = tuple(int(x) for x in np.unique(ev._kcoll)) or (0,)
-        uw, uc, ub, ut, uk = batched._compute_row_map(
-            ev._wax, ev._cax, ev._kwidx, ev._kcidx, ev._kbatch, ev._ktmul)
-        kcodes = {"w": ev._kwidx, "c": ev._kcidx, "coll": ev._kcoll,
-                  "n": ev._kn, "batch": ev._kbatch, "uk": uk,
-                  "hk": ev._khk}
-        ep = ev._ep_kwargs()
-        if ep:
-            self._tables.update(_ep_tables(ep["epx"]))
-            kcodes.update(we=ep["we"], ep=ep["ep"])
-        self._points = (len(ev._kn), int((ev._kep > 1).sum()))
-        self._ucodes = {"w": uw, "c": uc, "batch": ub,
-                        "tmul": np.ones(len(uw)) if ut is None else ut}
+        (self._tables, self._pflags, kcodes, self._ucodes,
+         self._tl_overlaps, self._coll_codes) = _kernel_args(ev.points)
+        self._points = _point_counts(ev.points)
         S = len(ev)
         if S:
-            sc = ev._scenario_codes(0, S)
+            sc = ev.scenario_codes(0, S)
             scodes = {"pi": sc["pi"], "kidx": sc["kidx"]}
         else:
             scodes = {"pi": np.empty(0, dtype=np.int64),
@@ -774,18 +780,10 @@ class JaxGridEvaluator:
         equal ``iteration_time_s`` bit-exactly."""
         cols = self.columns(params)
         ev = self.ev
-        if ev._any_mc and len(ev):
-            codes = ev._scenario_codes(0, len(ev))
-            k = codes["kidx"]
-            batched._apply_mc_tails(
-                ev._wax, ev._cax, ev._pax, ev._kwidx[k], ev._kcidx[k],
-                ev._kcoll[k], ev._kn[k], ev._kbatch[k], codes["pi"],
-                ev._khk[k], ev._wtab,
-                None if ev._kbwmul is None else ev._kbwmul[k],
-                None if ev._klatmul is None else ev._klatmul[k],
-                ev._st_specs, codes["sti"], cols, seed,
-                synck=ev._ksynck[k], ft_specs=ev._ft_specs,
-                fidx=codes["fli"], **ev._ep_kwargs(k))
+        if ev.any_mc and len(ev):
+            codes = ev.scenario_codes(0, len(ev))
+            batched._apply_mc_tails(ev.points.take(codes["kidx"]), codes,
+                                    ev.specs, cols, seed)
         else:
             t_iter = cols["iteration_time_s"]
             cols["t_mean_s"] = t_iter
@@ -796,7 +794,7 @@ class JaxGridEvaluator:
     def method_labels(self, pi: np.ndarray) -> list[str]:
         """Per-row evaluation-path labels (``all_batched`` holds, so
         only the two batched labels occur)."""
-        return METHOD_LABELS[self.ev._pax.tier[pi]].tolist()
+        return METHOD_LABELS[self.ev.points.pax.tier[pi]].tolist()
 
 
 class JaxGridRun:
@@ -817,7 +815,7 @@ class JaxGridRun:
         ev = self._jev.ev
         out = {k: v[lo:hi] for k, v in self._cols.items()}
         out["method"] = self._jev.method_labels(
-            ev._scenario_codes(lo, hi)["pi"])
+            ev.scenario_codes(lo, hi)["pi"])
         return out
 
     def table_slice(self, lo: int, hi: int):
@@ -826,10 +824,10 @@ class JaxGridRun:
         :meth:`repro.core.batched.GridRun.table_slice` (the ``batched``
         mask is all-true by construction)."""
         ev = self._jev.ev
-        codes = ev._scenario_codes(lo, hi)
+        codes = ev.scenario_codes(lo, hi)
         cols = {k: v[lo:hi] for k, v in self._cols.items()}
-        cols["method_code"] = ev._pax.tier[codes["pi"]]
-        return (batched.select_to_columns(cols, ev._label_columns(codes)),
+        cols["method_code"] = ev.points.pax.tier[codes["pi"]]
+        return (batched.select_to_columns(cols, ev.label_columns(codes)),
                 codes["batched"])
 
     def rows_slice(self, lo: int, hi: int) -> list[dict]:
@@ -837,56 +835,24 @@ class JaxGridRun:
         return rows_from_table(table)
 
 
-#: Structure memo, mirroring :func:`repro.core.batched.grid_evaluator`
-#: (separate because the jax evaluator also holds device-side codes).
-_JAX_MEMO: dict = {}
-_MEMO_LIMIT = 64
-
-
-def _build(grid: ScenarioGrid, mesh=None) -> JaxGridEvaluator:
-    """A fresh :class:`JaxGridEvaluator`, under the ``sweep.build`` span
-    and counted in ``sweep.builds``."""
-    with obs.span("sweep.build"):
-        obs.count("sweep.builds")
-        return JaxGridEvaluator(grid, mesh=mesh)
-
-
 def jax_grid_evaluator(grid: ScenarioGrid, *, mesh=None) -> JaxGridEvaluator:
-    """Memoized :class:`JaxGridEvaluator` (unsharded/auto mesh only —
-    explicit meshes always build fresh)."""
-    if mesh is not None:
-        return _build(grid, mesh)
-    try:
-        from repro.core.workloads import resolve_workload
-        tables = tuple(resolve_workload(w) for w in grid.workloads)
-        key = (grid, tuple(id(t) for t in tables))
-        hash(key)
-    except TypeError:
-        return _build(grid)
-    hit = _JAX_MEMO.get(key)
-    if hit is not None:
+    """Memoized :class:`JaxGridEvaluator`: the one held by ``grid``'s
+    entry of the structure memo (:func:`repro.core.batched.grid_evaluator`),
+    built on first use (unsharded/auto mesh only — explicit meshes always
+    build fresh).  A build, the NumPy structure's own on a memo miss
+    included, runs under the ``sweep.build`` span and counts 1 in
+    ``sweep.builds``; a hit counts 0."""
+    ev = None if mesh is not None else batched.cached_evaluator(grid)
+    if ev is not None and ev.jax is not None:
         # a hit counts too, as no build: 0 builds differ from no counter
         obs.count("sweep.builds", 0)
-        return hit[0]
-    if len(_JAX_MEMO) >= _MEMO_LIMIT:
-        _JAX_MEMO.clear()
-    jev = _build(grid)
-    _JAX_MEMO[key] = (jev, tables)
+        return ev.jax
+    with obs.span("sweep.build"):
+        obs.count("sweep.builds")
+        jev = JaxGridEvaluator(grid, mesh=mesh)
+    if mesh is None:
+        jev.ev.jax = jev
     return jev
-
-
-def jax_evaluator_cached(grid: ScenarioGrid) -> bool:
-    """True when :func:`jax_grid_evaluator` would hit the structure
-    memo — the jax twin of :func:`repro.core.batched.evaluator_cached`
-    (a pure probe; the sweep service's cache-hit accounting)."""
-    try:
-        from repro.core.workloads import resolve_workload
-        tables = tuple(resolve_workload(w) for w in grid.workloads)
-        key = (grid, tuple(id(t) for t in tables))
-        hash(key)
-    except (TypeError, ValueError):
-        return False
-    return key in _JAX_MEMO
 
 
 # ----------------------------------------------------------------------
@@ -897,49 +863,30 @@ def eval_scenarios_table_jax(
         seed: int = 0) -> dict[str, np.ndarray]:
     """Columnar result table (input order) for a list of
     batched-path-eligible scenarios, evaluated by the fused jit kernel
-    with the identity scenario -> kernel-point map; het/straggler
-    structure comes from the shared
-    :func:`repro.core.batched.scenario_het_axes` pass and the straggler
-    Monte Carlo tails from the shared host-side pass, exactly as on the
-    grid path — which is what makes a *concatenation* of several
-    queries' scenario lists bit-identical, column for column, to
-    sweeping each query's grid directly (the sweep service's coalescer
-    contract, pinned by ``tests/test_service.py``).  Raises
-    ``ValueError`` (via :func:`repro.core.batched.scenario_axes`) if
-    any scenario's policy has neither a closed nor a bucket-timeline
-    form."""
+    with the identity scenario -> kernel-point map; the kernel points
+    come from the shared :func:`repro.core.batched.scenario_points` pass
+    and the straggler Monte Carlo tails from the shared host-side pass,
+    exactly as on the grid path — which is what makes a
+    *concatenation* of several queries' scenario lists bit-identical,
+    column for column, to sweeping each query's grid directly (the
+    sweep service's coalescer contract, pinned by
+    ``tests/test_service.py``).  Raises ``ValueError`` (via
+    :func:`repro.core.batched.scenario_points`) if any scenario's
+    policy has neither a closed nor a bucket-timeline form."""
     from repro.core.resulttable import empty_table
 
     scenarios = list(scenarios)
     if not scenarios:
         return empty_table()
-    wax, cax, pax, widx, cidx, polidx, coll, n, batch = \
-        batched.scenario_axes(scenarios)
-    (hks, wtab, tmul, bwmul, latmul, st_specs, stidx,
-     synck, ft_specs, fidx) = batched.scenario_het_axes(scenarios)
-    tables, pflags = _axes_tables(wax, cax, pax, wtab)
-    tl_overlaps = tuple(bool(ov) for _, ov in pax.tl_specs)
+    points, codes, specs = batched.scenario_points(scenarios)
+    tables, pflags, kcodes, ucodes, *static = _kernel_args(points)
     S = len(scenarios)
-    uw, uc, ub, ut, uk = batched._compute_row_map(wax, cax, widx, cidx,
-                                                  batch, tmul)
-    kcodes = {"w": widx, "c": cidx, "coll": coll, "n": n, "batch": batch,
-              "uk": uk, "hk": hks}
-    epkw = batched.scenario_ep_axes(scenarios, wax, widx, pax)
-    if epkw:
-        tables.update(_ep_tables(epkw["epx"]))
-        kcodes.update(we=epkw["we"], ep=epkw["ep"])
-    ucodes = {"w": uw, "c": uc, "batch": ub,
-              "tmul": np.ones(len(uw)) if ut is None else ut}
-    scodes = {"pi": polidx, "kidx": np.arange(S, dtype=np.int64)}
-    coll_codes = tuple(int(x) for x in np.unique(coll)) or (0,)
-    _count_points(S, int((epkw["ep"] > 1).sum()) if epkw else 0)
-    cols = _host_columns((tables, pflags, kcodes, scodes, ucodes,
-                          tl_overlaps, coll_codes), S)
-    batched._apply_mc_tails(wax, cax, pax, widx, cidx, coll, n, batch,
-                            polidx, hks, wtab, bwmul, latmul, st_specs,
-                            stidx, cols, seed, synck=synck,
-                            ft_specs=ft_specs, fidx=fidx, **epkw)
-    cols["method_code"] = pax.tier[polidx]
+    scodes = {"pi": codes["pi"], "kidx": np.arange(S, dtype=np.int64)}
+    _count_points(*_point_counts(points))
+    cols = _host_columns((tables, pflags, kcodes, scodes, ucodes, *static),
+                         S)
+    batched._apply_mc_tails(points, codes, specs, cols, seed)
+    cols["method_code"] = points.pax.tier[codes["pi"]]
     return batched.select_to_columns(cols,
                                      batched.scenario_labels(scenarios))
 
@@ -1008,23 +955,22 @@ def numpy_iteration_times(grid: ScenarioGrid,
     :func:`grad_iteration_time` and the numeric side of the CI
     agreement gate."""
     ev = grid_evaluator(grid)
-    cax = ev._cax
-    tl_specs = list(ev._pax.tl_specs)
+    points = ev.points
     if params:
         link = {k: np.asarray(params[k], dtype=np.float64)
                 for k in ("intra_bw", "intra_lat", "inter_bw", "inter_lat")
                 if k in params}
         if link:
-            cax = dataclasses.replace(cax, **link)
+            points = dataclasses.replace(
+                points, cax=dataclasses.replace(points.cax, **link))
         if "bucket_bytes" in params:
             bb = np.asarray(params["bucket_bytes"], dtype=np.float64)
             tl_specs = [(float(bb[i]), ov)
-                        for i, (_, ov) in enumerate(tl_specs)]
-    kc = batched._kernel_cols(ev._wax, cax, ev._kwidx, ev._kcidx,
-                              ev._kcoll, ev._kn, ev._kbatch,
-                              tl_specs=tl_specs, tmul=ev._ktmul,
-                              bwmul=ev._kbwmul, latmul=ev._klatmul,
-                              **ev._ep_kwargs())
-    codes = ev._scenario_codes(0, len(ev))
-    return batched._policy_select(ev._pax, codes["pi"], kc,
+                        for i, (_, ov) in enumerate(points.pax.tl_specs)]
+            points = dataclasses.replace(
+                points, pax=dataclasses.replace(points.pax,
+                                                tl_specs=tl_specs))
+    kc = batched._kernel_cols(points)
+    codes = ev.scenario_codes(0, len(ev))
+    return batched._policy_select(points.pax, codes["pi"], kc,
                                   codes["kidx"])["iteration_time_s"]

@@ -27,14 +27,14 @@ before the kernel's actual millions-of-scenarios-per-second shows up.
   its grid on both backends (``np.array_equal`` per column, pinned by
   ``tests/test_service.py``).  A group of one routes through the
   memoized grid front end instead — same results, and the structure
-  memos (:func:`repro.core.batched.grid_evaluator` /
-  ``batched_jax._JAX_MEMO``) stay hot for repeated queries.
+  memo (:func:`repro.core.batched.grid_evaluator`, which holds both
+  backends' evaluators) stays hot for repeated queries.
 * **Process-lifetime caches.**  Workload tables
   (``repro.core.workloads._TABLES``), grid-structure memos and
   compiled jax executables live as long as the service; the service
   additionally memoizes grid expansions (the coalescer's Python-side
   cost).  Cache hit/miss rates are *probed* per query
-  (:func:`repro.core.batched.evaluator_cached`,
+  (:func:`repro.core.batched.cached_evaluator`,
   :func:`repro.core.workloads.workload_cached`) without perturbing the
   caches being measured.
 * **QoS telemetry** (:class:`ServiceStats`): per-query latency and
@@ -63,13 +63,13 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from queue import Empty, Queue
 
 import numpy as np
 
 from repro.core import analytical
-from repro.core.batched import eval_scenarios_table, evaluator_cached
+from repro.core.batched import cached_evaluator, eval_scenarios_table
 from repro.core.policies import get_policy
 from repro.core.resulttable import method_counts, slice_table, table_len
 from repro.core.scenarios import (BASE_GRIDS, GRID_SPEC_KEYS, Scenario,
@@ -453,17 +453,13 @@ class SweepService:
             self._eval_group([t], t.query.backend, t.query.seed)
 
     def _probe_structure(self, q: Query) -> str:
-        """Hit/miss probe of the grid-structure memo of the query's
-        backend, *before* evaluation touches it (the probe never
+        """Hit/miss probe of the grid-structure memo (one for both
+        backends), *before* evaluation touches it (the probe never
         builds or inserts anything).  The memo is exercised directly
         by singleton queries and by anyone re-sweeping the grid;
         coalesced groups rebuild scenario-list axes but share the
         memoized workload tables (probed at submit, pre-parse)."""
-        if q.backend == "jax":
-            from repro.core.batched_jax import jax_evaluator_cached
-            structure = jax_evaluator_cached(q.grid)
-        else:
-            structure = evaluator_cached(q.grid)
+        structure = cached_evaluator(q.grid) is not None
         self.stats.record_cache("grid_structure", structure)
         return "hit" if structure else "miss"
 

@@ -542,3 +542,77 @@ class TestInterconnectFilterNormalization:
         assert normalize_interconnect(s.interconnect) == "default"
         assert s.label().endswith("/default")
         assert _fast_eval(s)["interconnect"] == "default"
+
+
+#: A frontier slice, a het + sync_k + straggler grid and an EP grid.
+POINT_GRIDS = {
+    "frontier_slice": lambda: dataclasses.replace(
+        frontier_grid(), worker_counts=(4, 32),
+        interconnects=frontier_grid().interconnects[::6]),
+    "het_synck_straggler": lambda: ScenarioGrid(
+        workloads=("resnet50", "alexnet"), clusters=("v100-nvlink-ib",),
+        worker_counts=(4, 16),
+        policies=("tensorflow", "bucketed-4mb", "priority"),
+        het_profiles=(None, "het:1x0.5+3x1.0", "het:2x1.0@bw0.5"),
+        stragglers=("none", "lognormal:0.2x20"), sync_ks=(0, 2)),
+    "ep": lambda: ScenarioGrid(
+        workloads=("llm:qwen2-moe-a2.7b",), clusters=("v100-nvlink-ib",),
+        worker_counts=(4, 8), ep_sizes=(1, 2, 4),
+        het_profiles=(None, "het:1x0.5+3x1.0"),
+        policies=("tensorflow", "bucketed-25mb"),
+        collectives=("ring", "hierarchical")),
+}
+
+
+class TestKernelPoints:
+    """Both front ends build one :class:`KernelPoints` structure: the
+    list front end's points for ``grid.expand()`` are, value for value,
+    the grid's points gathered at each scenario's kernel point, and the
+    kernel gives both the same bits."""
+
+    @pytest.mark.parametrize("name", list(POINT_GRIDS))
+    def test_list_points_are_the_grid_points_taken_per_scenario(self, name):
+        from repro.core import batched as B
+
+        grid = POINT_GRIDS[name]()
+        ev = B.grid_evaluator(grid)
+        codes = ev.scenario_codes(0, len(ev))
+        taken = ev.points.take(codes["kidx"])
+        listed, lcodes, specs = B.scenario_points(grid.expand())
+        # values, not indices: the front ends may number het rows and
+        # (workload, ep) pairs in different orders
+        for f in ("coll", "n", "batch", "synck", "tmul", "bwmul", "latmul",
+                  "ep"):
+            a, b = getattr(taken, f), getattr(listed, f)
+            assert (a is None) == (b is None), f
+            if a is not None:
+                assert np.array_equal(a, b), f
+        assert (np.array(taken.wax.names)[taken.w]
+                == np.array(listed.wax.names)[listed.w]).all()
+        assert np.array_equal(taken.wtab["inv_speed"][taken.hk],
+                              listed.wtab["inv_speed"][listed.hk])
+        assert np.array_equal(ev.points.pax.names, listed.pax.names)
+        assert np.array_equal(codes["pi"], lcodes["pi"])
+        assert (taken.epx is None) == (listed.epx is None) == (name != "ep")
+        got, want = B._kernel_cols(listed), B._kernel_cols(taken)
+        assert got.keys() == want.keys()
+        for k in got:
+            assert np.array_equal(got[k].view(np.uint64),
+                                  want[k].view(np.uint64)), k
+
+    @pytest.mark.parametrize("name", list(POINT_GRIDS))
+    def test_one_memo_entry_serves_both_backends(self, name, monkeypatch):
+        from repro.core import batched as B
+        from repro.core import batched_jax as BJ
+
+        monkeypatch.setattr(B, "_STRUCTURE_MEMO", {})
+        grid = POINT_GRIDS[name]()
+        assert B.cached_evaluator(grid) is None
+        sweep(grid, backend="numpy")
+        ev = B.cached_evaluator(grid)
+        assert ev is not None and ev.jax is None
+        sweep(grid, backend="jax")
+        assert len(B._STRUCTURE_MEMO) == 1
+        assert B.cached_evaluator(grid) is ev
+        assert ev.jax is not None and ev.jax.ev is ev
+        assert BJ.jax_grid_evaluator(grid) is ev.jax

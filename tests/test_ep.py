@@ -134,7 +134,7 @@ def test_grids_without_ep_build_no_ep_structure():
 
     grid = frontier_grid()
     assert grid.ep_sizes == (1,)
-    assert grid_evaluator(grid)._epx is None
+    assert grid_evaluator(grid).points.epx is None
     jev = JaxGridEvaluator(grid)
     assert "we" not in jev._kcodes and "ep" not in jev._kcodes
     assert not any(k.startswith("ep_") for k in jev._tables)

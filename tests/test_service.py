@@ -190,7 +190,7 @@ class TestServiceAccounting:
     def test_cache_miss_then_hit(self, monkeypatch):
         # a private table memo so process history can't pre-warm it
         monkeypatch.setattr("repro.core.workloads._TABLES", {})
-        monkeypatch.setattr("repro.core.batched._EVALUATOR_MEMO", {})
+        monkeypatch.setattr("repro.core.batched._STRUCTURE_MEMO", {})
         spec = {"workloads": ["alexnet"], "workers": [2]}
         with SweepService(window_s=0.0) as svc:
             first = svc.query(dict(spec), timeout=120)
@@ -235,15 +235,21 @@ class TestServiceAccounting:
             assert ei.value.code == "bad-query"
 
     def test_dispatcher_survives_a_failing_batch(self, monkeypatch):
-        """An exception outside a group's evaluation (here the jax
-        structure probe) fails that batch's tickets with a structured
-        error, and the dispatcher goes on to answer the next query."""
-        import repro.core.batched_jax as bj
+        """An exception outside a group's evaluation (here the
+        structure probe, failing once) fails that batch's tickets with a
+        structured error, and the dispatcher goes on to answer the next
+        query."""
+        import repro.core.service as service_mod
+
+        probe, calls = service_mod.cached_evaluator, []
 
         def broken_probe(grid):
-            raise RuntimeError("structure probe exploded")
+            calls.append(grid)
+            if len(calls) == 1:
+                raise RuntimeError("structure probe exploded")
+            return probe(grid)
 
-        monkeypatch.setattr(bj, "jax_evaluator_cached", broken_probe)
+        monkeypatch.setattr(service_mod, "cached_evaluator", broken_probe)
         spec = {"workloads": ["resnet50"], "workers": [4], "seed": 7}
         with SweepService(window_s=0.0) as svc:
             with pytest.raises(QueryError) as ei:
