@@ -789,7 +789,9 @@ def select_to_columns(cols: dict[str, np.ndarray],
     The tail columns ``t_mean_s``/``t_p95_s``/``t_p99_s`` come from the
     straggler Monte Carlo pass when present; deterministic rows (no
     straggler spec, or zero jitter) default to ``iteration_time_s`` —
-    the distribution is a point mass there.
+    the distribution is a point mass there.  ``method`` is taken from
+    ``labels`` where the caller gathered it already (the jax grid path,
+    while its kernel runs), else gathered from ``cols["method_code"]``.
     """
     t_iter = np.asarray(cols["iteration_time_s"])
     return {
@@ -813,7 +815,8 @@ def select_to_columns(cols: dict[str, np.ndarray],
         "t_mean_s": np.asarray(cols.get("t_mean_s", t_iter)),
         "t_p95_s": np.asarray(cols.get("t_p95_s", t_iter)),
         "t_p99_s": np.asarray(cols.get("t_p99_s", t_iter)),
-        "method": METHOD_LABELS[np.asarray(cols["method_code"])],
+        "method": (labels["method"] if "method" in labels
+                   else METHOD_LABELS[np.asarray(cols["method_code"])]),
     }
 
 

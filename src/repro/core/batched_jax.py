@@ -58,10 +58,11 @@ CI agreement gate evaluate.
 """
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import functools
 import math
-from typing import Iterable, Sequence
+from typing import Callable, Iterable, Sequence
 
 import numpy as np
 
@@ -609,10 +610,14 @@ def _shards(codes: np.ndarray | jax.Array) -> int:
     return 1
 
 
-def _host_columns(args: tuple, size: int) -> dict[str, np.ndarray]:
+def _host_columns(args: tuple, size: int,
+                  meanwhile: Callable[[], None] | None = None
+                  ) -> dict[str, np.ndarray]:
     """One call of :func:`_columns_jax` on ``args``, as the first ``size``
     rows of each numeric column in host float64 arrays: views of one
-    ``(6, S)`` host array, fetched in one transfer.
+    ``(6, S)`` host array, fetched in one transfer.  ``meanwhile``, host
+    work that needs none of the columns, runs after the launch and
+    before the wait, so the device computes while the host works.
 
     What crosses to the device per call is two host buffers
     (:func:`_packed_args`): the float tables as one float64 buffer, the
@@ -626,8 +631,8 @@ def _host_columns(args: tuple, size: int) -> dict[str, np.ndarray]:
     The device path splits into three spans: ``sweep.columns.call``
     (packing the two buffers, placing them on the device, the launch,
     and the start of the copy of the packed output to the host),
-    ``sweep.columns.wait`` (the device work the host cannot hide,
-    waited for only while the recorder is on) and
+    ``sweep.columns.wait`` (the device work the host cannot hide, after
+    ``meanwhile``; waited for only while the recorder is on) and
     ``sweep.columns.fetch`` (the end of the copy and the columns taken
     from it; with the recorder off it also does the waiting).  The
     counters ``sweep.h2d_arrays``/``sweep.h2d_bytes`` count the host
@@ -643,6 +648,8 @@ def _host_columns(args: tuple, size: int) -> dict[str, np.ndarray]:
             packed = _packed_args(args)
             out = _columns_jax(*packed, shards=shards)
             out.copy_to_host_async()
+        if meanwhile is not None:
+            meanwhile()
         if obs.enabled():
             _host_h2d(packed[0], packed[1], packed[3])
             with obs.span("sweep.columns.wait"):
@@ -743,12 +750,15 @@ class JaxGridEvaluator:
     def columns(self, params: dict | None = None) -> dict[str, np.ndarray]:
         """All numeric result columns as host float64 ``(S,)`` arrays
         (blocks on the device computation).  ``params`` optionally
-        overrides the :data:`PARAM_KEYS` entries."""
+        overrides the :data:`PARAM_KEYS` entries.  Host work that a
+        :class:`JaxGridRun` hands it in :data:`_MEANWHILE` runs while
+        the device computes (:func:`_host_columns`; not on an empty grid,
+        which launches nothing)."""
         S = len(self.ev)
         if S == 0:
             return {k: np.empty(0) for k in _NUMERIC_COLS}
         _count_points(*self._points)
-        return _host_columns(self._args(params), S)
+        return _host_columns(self._args(params), S, _MEANWHILE.get())
 
     def _args(self, params: dict | None = None) -> tuple:
         """The kernel's arguments, ``params`` swapped into the tables."""
@@ -770,7 +780,8 @@ class JaxGridEvaluator:
         return _columns_dict(*self._args(params))
 
     def run(self, params: dict | None = None, seed: int = 0) -> "JaxGridRun":
-        """One evaluation: the jit kernel for the deterministic
+        """One evaluation, made when its first slice is asked for
+        (:class:`JaxGridRun`): the jit kernel for the deterministic
         columns, then the straggler Monte Carlo tail pass.  The MC
         orchestration (dedup, keyed draws, slowest-worker fold,
         ``np.quantile`` reduction) is the host-side pass *shared* with
@@ -778,18 +789,7 @@ class JaxGridEvaluator:
         which is what guarantees draw-for-draw agreement between the
         backends; deterministic grids skip it and the tail columns
         equal ``iteration_time_s`` bit-exactly."""
-        cols = self.columns(params)
-        ev = self.ev
-        if ev.any_mc and len(ev):
-            codes = ev.scenario_codes(0, len(ev))
-            batched._apply_mc_tails(ev.points.take(codes["kidx"]), codes,
-                                    ev.specs, cols, seed)
-        else:
-            t_iter = cols["iteration_time_s"]
-            cols["t_mean_s"] = t_iter
-            cols["t_p95_s"] = t_iter
-            cols["t_p99_s"] = t_iter
-        return JaxGridRun(self, cols)
+        return JaxGridRun(self, params, seed)
 
     def method_labels(self, pi: np.ndarray) -> list[str]:
         """Per-row evaluation-path labels (``all_batched`` holds, so
@@ -797,23 +797,75 @@ class JaxGridEvaluator:
         return METHOD_LABELS[self.ev.points.pax.tier[pi]].tolist()
 
 
+#: Host work for :meth:`JaxGridEvaluator.columns` to run while the
+#: kernel is in flight, set by :class:`JaxGridRun` around its call.  A
+#: context variable, not an argument, so that ``columns(params)`` keeps
+#: the signature by which callers wrap or replace it (the benchmark's
+#: timers and planted faults).
+_MEANWHILE: contextvars.ContextVar = contextvars.ContextVar(
+    "meanwhile", default=None)
+
+
 class JaxGridRun:
     """One evaluation of a grid on the jax backend: host-side numeric
     columns plus the shared structure, materializing columnar result
     tables chunk by chunk — the jax twin of
     :class:`repro.core.batched.GridRun` (no simulator rows:
-    simulator-only grids are rejected up front)."""
+    simulator-only grids are rejected up front).
 
-    def __init__(self, jev: JaxGridEvaluator, cols: dict[str, np.ndarray]):
+    The kernel runs when the first slice is asked for.  While it runs,
+    the host gathers that slice's labels: they depend on the grid
+    alone, so the device need not sit idle while they are gathered
+    after the fetch.  The span ``sweep.labels`` times the gathers, and
+    the counter ``sweep.labels_overlapped`` counts 1 for each
+    evaluation whose gathers ran while the kernel was in flight.  The
+    labels are held until :meth:`table_slice` takes them for that
+    slice, so no more than one slice of labels is held."""
+
+    def __init__(self, jev: JaxGridEvaluator, params: dict | None = None,
+                 seed: int = 0):
         self._jev = jev
-        self._cols = cols
+        self._params = params
+        self._seed = seed
+        self._cols: dict[str, np.ndarray] | None = None
+        # ((lo, hi), (labels, batched)) gathered while the kernel ran
+        self._gathered: tuple | None = None
 
     def __len__(self) -> int:
         return len(self._jev)
 
+    def _columns(self, lo: int, hi: int) -> dict[str, np.ndarray]:
+        """The numeric columns, the tails included; evaluated on the
+        first call, which gathers the labels of ``[lo, hi)`` meanwhile."""
+        if self._cols is not None:
+            return self._cols
+        ev = self._jev.ev
+
+        def gather():
+            with obs.span("sweep.labels"):
+                self._gathered = ((lo, hi), _slice_labels(ev, lo, hi))
+            obs.count("sweep.labels_overlapped")
+
+        token = _MEANWHILE.set(gather)
+        try:
+            cols = self._jev.columns(self._params)
+        finally:
+            _MEANWHILE.reset(token)
+        if ev.any_mc and len(ev):
+            codes = ev.scenario_codes(0, len(ev))
+            batched._apply_mc_tails(ev.points.take(codes["kidx"]), codes,
+                                    ev.specs, cols, self._seed)
+        else:
+            t_iter = cols["iteration_time_s"]
+            cols["t_mean_s"] = t_iter
+            cols["t_p95_s"] = t_iter
+            cols["t_p99_s"] = t_iter
+        self._cols = cols
+        return cols
+
     def columns_slice(self, lo: int, hi: int) -> dict[str, np.ndarray]:
         ev = self._jev.ev
-        out = {k: v[lo:hi] for k, v in self._cols.items()}
+        out = {k: v[lo:hi] for k, v in self._columns(lo, hi).items()}
         out["method"] = self._jev.method_labels(
             ev.scenario_codes(lo, hi)["pi"])
         return out
@@ -822,17 +874,29 @@ class JaxGridRun:
         """Columnar result table for flat scenario indices ``[lo, hi)``
         in grid order — the jax twin of
         :meth:`repro.core.batched.GridRun.table_slice` (the ``batched``
-        mask is all-true by construction)."""
-        ev = self._jev.ev
-        codes = ev.scenario_codes(lo, hi)
-        cols = {k: v[lo:hi] for k, v in self._cols.items()}
-        cols["method_code"] = ev.points.pax.tier[codes["pi"]]
-        return (batched.select_to_columns(cols, ev.label_columns(codes)),
-                codes["batched"])
+        mask is all-true by construction).  The labels gathered while
+        the kernel ran are taken for their slice, once; any other
+        slice's are gathered here."""
+        cols = {k: v[lo:hi] for k, v in self._columns(lo, hi).items()}
+        if self._gathered is not None and self._gathered[0] == (lo, hi):
+            (_, (labels, mask)), self._gathered = self._gathered, None
+        else:
+            labels, mask = _slice_labels(self._jev.ev, lo, hi)
+        return batched.select_to_columns(cols, labels), mask
 
     def rows_slice(self, lo: int, hi: int) -> list[dict]:
         table, _ = self.table_slice(lo, hi)
         return rows_from_table(table)
+
+
+def _slice_labels(ev: batched.GridEvaluator, lo: int, hi: int) -> tuple:
+    """``(labels, batched)`` of flat scenario indices ``[lo, hi)``: the
+    label columns, ``method`` among them, and the batched mask — all of
+    a table slice that does not come from the kernel."""
+    codes = ev.scenario_codes(lo, hi)
+    labels = ev.label_columns(codes)
+    labels["method"] = METHOD_LABELS[ev.points.pax.tier[codes["pi"]]]
+    return labels, codes["batched"]
 
 
 def jax_grid_evaluator(grid: ScenarioGrid, *, mesh=None) -> JaxGridEvaluator:

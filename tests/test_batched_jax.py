@@ -17,11 +17,12 @@ from hypothesis import given, settings
 from strategies import scenario_grids
 
 from repro.core import batched_jax as BJ
+from repro.core import batched
 from repro.core.batched import eval_scenarios, grid_evaluator
 from repro.core.policies import Policy
 from repro.core.scenarios import (Scenario, ScenarioGrid, default_grid,
                                   frontier_grid, mixed_grid, resolve_cluster)
-from repro.core.sweep import BACKENDS, iter_rows, stream, sweep
+from repro.core.sweep import BACKENDS, iter_rows, iter_tables, stream, sweep
 from repro.core.workloads import resolve_workload
 
 NUMERIC = ("iteration_time_s", "samples_per_sec", "speedup",
@@ -238,13 +239,15 @@ MC_GRID = ScenarioGrid(workloads=("resnet50",), clusters=("v100-nvlink-ib",),
                        sync_ks=(0, 2))
 
 
-def _per_column(args: tuple, size: int) -> dict:
+def _per_column(args: tuple, size: int, meanwhile=None) -> dict:
     """The fetch as it was before the packed buffer: the dict-valued
     kernel, one ``np.asarray`` copy per column."""
     import jax
 
     with jax.enable_x64(True):
         out = BJ._columns_dict(*args)
+        if meanwhile is not None:
+            meanwhile()
         return {k: np.asarray(out[k])[:size] for k in BJ._NUMERIC_COLS}
 
 
@@ -505,6 +508,79 @@ class TestPackedInputs:
             BJ._packed_args((tables, pflags, {**kcodes, "n": n}, *rest))
         n[3] = 2**31 - 1                            # the largest that fits
         BJ._packed_args((tables, pflags, {**kcodes, "n": n}, *rest))
+
+
+def _tables_fetch_first(jev, chunk: int, seed: int) -> list:
+    """A grid's ``(table, batched)`` slices in the order of work that
+    gathers every label after the fetch: the columns, the Monte Carlo
+    tails, then per slice the codes, the labels and ``method``."""
+    ev, S = jev.ev, len(jev)
+    cols = jev.columns()
+    if ev.any_mc:
+        codes = ev.scenario_codes(0, S)
+        batched._apply_mc_tails(ev.points.take(codes["kidx"]), codes,
+                                ev.specs, cols, seed)
+    out = []
+    for lo in range(0, S, chunk):
+        codes = ev.scenario_codes(lo, min(lo + chunk, S))
+        part = {k: v[lo:lo + chunk] for k, v in cols.items()}
+        part["method_code"] = ev.points.pax.tier[codes["pi"]]
+        out.append((batched.select_to_columns(part, ev.label_columns(codes)),
+                    codes["batched"]))
+    return out
+
+
+LABEL_GRIDS = {"frontier": frontier_grid, "dsv2_lite_ep": _dsv2_lite_ep_grid,
+               "monte_carlo": lambda: HET_FAULT_GRID}
+
+
+class TestLabelsUnderTheKernel:
+    """The labels of a grid's first table slice are gathered while the
+    kernel runs; every table is key for key and bit for bit the one the
+    fetch-first order of work gives."""
+
+    @pytest.mark.parametrize("route", ["whole", "chunked", "mesh"])
+    @pytest.mark.parametrize("name", list(LABEL_GRIDS))
+    def test_tables_are_those_of_the_fetch_first_order(self, name, route):
+        from repro.launch.mesh import make_dp_mesh
+
+        grid, seed = LABEL_GRIDS[name](), 7
+        S = len(grid)
+        chunk = {"whole": S, "chunked": S // 3 + 11, "mesh": S // 2 + 1}[route]
+        if route == "mesh":
+            jev = BJ.JaxGridEvaluator(grid, mesh=make_dp_mesh(1))
+            run = jev.run(seed=seed)
+            got = [run.table_slice(lo, min(lo + chunk, S))
+                   for lo in range(0, S, chunk)]
+        else:
+            jev = BJ.jax_grid_evaluator(grid)
+            got = [(t, None) for t in iter_tables(
+                grid, backend="jax", chunk=chunk, seed=seed)]
+        want = _tables_fetch_first(jev, chunk, seed)
+        assert len(got) == len(want) == -(-S // chunk)
+        for (table, mask), (ref, ref_mask) in zip(got, want):
+            assert list(table) == list(ref)
+            _assert_bits_equal(table, ref)
+            assert mask is None or np.array_equal(mask, ref_mask)
+        if route == "whole":
+            whole = sweep(grid, backend="jax", seed=seed).columns
+            _assert_bits_equal(whole, want[0][0])
+
+    def test_the_gathered_labels_serve_their_own_slice_once(
+            self, monkeypatch):
+        grid = dataclasses.replace(default_grid(), worker_counts=(2, 16))
+        jev = BJ.jax_grid_evaluator(grid)
+        want = [table for table, _ in _tables_fetch_first(jev, 10, 0)]
+        calls = []
+        gather = BJ._slice_labels
+        monkeypatch.setattr(BJ, "_slice_labels",
+                            lambda *a: calls.append(a[1:]) or gather(*a))
+        run = jev.run()
+        run.columns_slice(0, 10)            # evaluates, gathering [0, 10)
+        _assert_bits_equal(run.table_slice(10, 20)[0], want[1])
+        _assert_bits_equal(run.table_slice(0, 10)[0], want[0])
+        _assert_bits_equal(run.table_slice(0, 10)[0], want[0])
+        assert calls == [(0, 10), (10, 20), (0, 10)]
 
 
 class TestBackendRouting:

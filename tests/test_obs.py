@@ -11,10 +11,10 @@ import pytest
 from repro.core import batched_jax as BJ
 from repro.core import obs
 from repro.core.scenarios import ScenarioGrid
-from repro.core.sweep import sweep
+from repro.core.sweep import iter_tables, sweep
 
 SWEEP_SPANS = ("sweep", "sweep.build", "sweep.columns", "sweep.columns.call",
-               "sweep.columns.wait", "sweep.columns.fetch")
+               "sweep.columns.wait", "sweep.columns.fetch", "sweep.labels")
 
 
 def _grid(workers=(2, 8)) -> ScenarioGrid:
@@ -130,7 +130,8 @@ def test_a_sweep_records_each_span_once_inside_its_parent(recorder):
     parent = {"sweep.build": "sweep", "sweep.columns": "sweep",
               "sweep.columns.call": "sweep.columns",
               "sweep.columns.wait": "sweep.columns",
-              "sweep.columns.fetch": "sweep.columns"}
+              "sweep.columns.fetch": "sweep.columns",
+              "sweep.labels": "sweep.columns"}
     root = by["sweep"][0]
     for name, outer in parent.items():
         span = by[name][0]
@@ -138,7 +139,9 @@ def test_a_sweep_records_each_span_once_inside_its_parent(recorder):
         assert _inside(span, by[outer][0])
     call, wait, fetch = (by[f"sweep.columns.{p}"][0]
                          for p in ("call", "wait", "fetch"))
-    assert call.t1 <= wait.t0 and wait.t1 <= fetch.t0
+    labels, = by["sweep.labels"]
+    assert call.t1 <= labels.t0 and labels.t1 <= wait.t0
+    assert wait.t1 <= fetch.t0
     assert by["sweep.build"][0].t1 <= by["sweep.columns"][0].t0
 
 
@@ -232,13 +235,70 @@ def test_the_scenario_list_path_records_the_same_device_spans(recorder):
     table = BJ.eval_scenarios_table_jax(scenarios)
     got = obs.snapshot()
     names = [s.name for s in got["spans"]]
-    for name in SWEEP_SPANS[2:]:
-        assert names.count(name) == 1
+    for name in SWEEP_SPANS[2:]:        # a list has no grid labels to gather
+        assert names.count(name) == (name != "sweep.labels")
     assert got["counters"]["sweep.h2d_arrays"] > 0
+    assert "sweep.labels_overlapped" not in got["counters"]
     obs.disable()
     plain = BJ.eval_scenarios_table_jax(scenarios)
     for k in table:
         assert np.array_equal(np.asarray(table[k]), np.asarray(plain[k])), k
+
+
+# ----------------------------------------------------------------------
+# The labels of a grid sweep are gathered while its kernel runs.
+# ----------------------------------------------------------------------
+MC_GRID = ScenarioGrid(workloads=("resnet50",), clusters=("v100-nvlink-ib",),
+                       worker_counts=(4, 16),
+                       policies=("tensorflow", "bucketed-4mb"),
+                       stragglers=("none", "lognormal:0.2x100"))
+
+
+@pytest.mark.parametrize("route", ["whole", "chunked", "monte_carlo"])
+def test_labels_are_gathered_between_the_call_and_the_wait(recorder, route):
+    grid = MC_GRID if route == "monte_carlo" else _grid((2, 8))
+    chunk = 7 if route == "chunked" else None
+    for _ in range(3):
+        if chunk:
+            tables = list(iter_tables(grid, backend="jax", chunk=chunk))
+            assert len(tables) > 1
+        else:
+            sweep(grid, backend="jax", seed=4)
+    got = obs.snapshot()
+    by = _by_name(got["spans"])
+    labels = sorted(by["sweep.labels"], key=lambda s: s.t0)
+    assert len(labels) == 3
+    assert got["counters"]["sweep.labels_overlapped"] == 3
+    for span, cols, call, wait, fetch in zip(labels, *(
+            sorted(by[n], key=lambda s: s.t0) for n in SWEEP_SPANS[2:6])):
+        assert span.parent == "sweep.columns" and _inside(span, cols)
+        assert call.t1 <= span.t0 and span.t1 <= wait.t0
+        assert wait.t1 <= fetch.t0
+
+
+def test_with_the_recorder_off_the_labels_come_before_the_fetch(monkeypatch):
+    """The untimed path does the gathers while the kernel is in flight
+    too: after the launch, before the blocking fetch of its output."""
+    order = []
+    gather, unpack, launch = BJ._slice_labels, BJ._unpack, BJ._columns_jax
+
+    def logged(name, f):
+        def g(*a, **k):
+            order.append(name)
+            return f(*a, **k)
+        return g
+
+    monkeypatch.setattr(BJ, "_slice_labels", logged("labels", gather))
+    monkeypatch.setattr(BJ, "_unpack", logged("fetch", unpack))
+    monkeypatch.setattr(BJ, "_columns_jax", logged("launch", launch))
+    obs.disable()
+    grid = _grid((2, 4))
+    sweep(grid, backend="jax")
+    assert order == ["launch", "labels", "fetch"]
+    order.clear()
+    list(iter_tables(grid, backend="jax", chunk=5))
+    assert order[:3] == ["launch", "labels", "fetch"]
+    assert order[3:] == ["labels"] * (-(-len(grid) // 5) - 1)
 
 
 # ----------------------------------------------------------------------
